@@ -1,190 +1,28 @@
-"""Version shims for the jax surface apex_tpu depends on.
+"""The one place apex_tpu names the jax sharding surface it depends on.
 
-The repo is written against the modern public API (``jax.shard_map``
-with ``check_vma``, ``jax.typeof``); older jax releases still in the
-deployment fleet ship the same machinery under
-``jax.experimental.shard_map`` with the ``check_rep`` spelling and no
-``typeof``.  Every apex_tpu module (and the repo's tests/benches) goes
-through this shim instead of touching ``jax.shard_map`` directly — the
-trace-safety linter enforces it (rule APX501) so a new call site cannot
-silently reintroduce the version dependency.
+Every apex_tpu module (and the repo's tests/benches) imports
+``shard_map``, ``typeof``, ``axis_size``, ``axis_index``, ``pcast`` and
+``set_mesh`` from here instead of touching ``jax.shard_map`` directly —
+the trace-safety linter enforces it (rule APX501) — so the day jax
+renames one of them there is one line to change.  The names are plain
+aliases of the installed API: the repo supports the jax it is tested
+with and carries no branch for any other.
 """
 from __future__ import annotations
 
 import jax
 
 __all__ = ["shard_map", "typeof", "axis_size", "axis_index", "pcast",
-           "set_mesh", "psum_replicated", "HAS_NATIVE_SHARD_MAP",
-           "HAS_VMA"]
+           "set_mesh", "psum_replicated"]
 
-HAS_NATIVE_SHARD_MAP = hasattr(jax, "shard_map")
-
-if HAS_NATIVE_SHARD_MAP:
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    # Old shard_map's check_rep machinery predates a few primitives the
-    # repo traces through it; give the pass-through ones the standard
-    # "output replication = meet of inputs" rules so check_rep=True
-    # (which we forward — see shard_map below) does not reject them.
-    try:
-        from jax.experimental import shard_map as _sm_module
-        from jax._src.ad_checkpoint import name_p as _name_p
-
-        _sm_module.register_standard_check(_name_p)
-        _sm_module.register_standard_rewrite(_name_p)
-    except (ImportError, AttributeError):
-        pass  # registry spelling changed: only named-value traces lose
-
-    def shard_map(f=None, *, mesh=None, in_specs=None, out_specs=None,
-                  check_vma=None, check_rep=None, **kwargs):
-        """``jax.shard_map`` resolved on old jax from
-        ``jax.experimental.shard_map``.
-
-        ``check_vma`` is the modern name of ``check_rep`` and MUST be
-        forwarded, not dropped: replication tracking also drives the
-        transpose rule (with it off, old shard_map psums the cotangent
-        of every replicated input — grads w.r.t. replicated params come
-        back multiplied by the axis size).
-        """
-        if check_vma is None:
-            check_vma = True if check_rep is None else check_rep
-        if f is None:  # kwargs-first partial form: shard_map(mesh=...)(f)
-            def bind(g):
-                return shard_map(g, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=check_vma,
-                                 **kwargs)
-            return bind
-        return _exp_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=check_vma,
-                              **kwargs)
-
-
-if hasattr(jax.lax, "axis_size"):
-    axis_size = jax.lax.axis_size
-else:
-    def axis_size(axis_name):
-        """``jax.lax.axis_size`` fallback.  ``psum`` of a static 1 is
-        constant-folded to the bound axis size as a Python int on every
-        jax that lacks ``axis_size`` — no collective is emitted."""
-        return jax.lax.psum(1, axis_name)
-
-
-if hasattr(jax.lax, "pcast"):
-    pcast = jax.lax.pcast
-else:
-    def pcast(x, axis_name, *, to):
-        """``jax.lax.pcast`` fallback: identity.  Old jax has no
-        varying-mesh-axis types, so there is nothing to cast — callers
-        (e.g. ``parallel.distributed.make_varying``) lose only the
-        static vma annotation, not any math."""
-        del axis_name, to
-        return x
-
-
-if hasattr(jax, "shard_map"):  # vma-era jaxlib lowers this correctly
-    axis_index = jax.lax.axis_index
-else:
-    def axis_index(axis_name):
-        """``jax.lax.axis_index`` that never emits ``partition_id``.
-
-        Old jaxlib lowers ``axis_index`` under jit-of-shard_map to
-        ``stablehlo.partition_id``, which the CPU SPMD partitioner
-        rejects whenever the op escapes the manual region ("meaning is
-        ambiguous").  Deriving the index from a ``psum_scatter`` of an
-        iota uses only collectives every partitioner handles: the
-        scatter hands rank ``r`` element ``r`` of the cross-replica sum
-        ``n * arange(n)``.
-        """
-        import jax.numpy as jnp
-
-        n = axis_size(axis_name)
-        arr = jnp.arange(n, dtype=jnp.float32)
-        summed = jax.lax.psum_scatter(arr, axis_name,
-                                      scatter_dimension=0, tiled=True)
-        return (summed[0] / n).astype(jnp.int32)
-
-
-# The varying-mesh-axis type system (jax.lax.pvary et al.) changed the
-# reverse-mode semantics of collectives: with it, the cotangent of a
-# REPLICATED (unvarying) psum output seeds ONCE across the axis; without
-# it, psum's transpose is psum — the identical per-rank seeds of a
-# replicated loss get summed, scaling every upstream gradient by the
-# axis size.
-HAS_VMA = hasattr(jax.lax, "pvary")
-
-
-def psum_replicated(x, axis_name):
-    """``psum`` for the replicate-a-masked-buffer idiom (one rank holds
-    the data, the rest hold zeros; the psum hands every rank the full
-    value), safe to differentiate THROUGH inside ``shard_map``.
-
-    On vma-era jax this is plain ``jax.lax.psum``.  On old jax the
-    transpose of psum is psum, which multiplies the replicated
-    cotangent by the axis size (measured: pipeline-schedule grads came
-    back exactly ``num_stages``x); the ``custom_vjp`` pins the
-    mathematically-correct seed-once cotangent instead.
-    """
-    if HAS_VMA:
-        return jax.lax.psum(x, axis_name)
-
-    @jax.custom_vjp
-    def rep(v):
-        return jax.lax.psum(v, axis_name)
-
-    rep.defvjp(lambda v: (rep(v), None), lambda _, ct: (ct,))
-    return rep(x)
-
-
-def rewrite_trace_free(*operands) -> bool:
-    """Old-jax legality probe for Pallas calls inside ``shard_map``.
-
-    ``check_rep=True`` runs the body under the replication-rewrite
-    interpreter (``RewriteTrace``), which has no rule for
-    ``pallas_call``; ``check_rep=False`` (and plain jit) does not.  An
-    operand traced by a RewriteTrace therefore proves a Pallas call
-    here would fail.  Class-name sniffing on a private type is ugly,
-    but it is confined to this shim and only reachable on pre-vma jax.
-    """
-    return not any(
-        type(getattr(x, "_trace", None)).__name__ == "RewriteTrace"
-        for x in operands)
-
-
-if hasattr(jax, "set_mesh"):
-    set_mesh = jax.set_mesh
-else:
-    def set_mesh(mesh):
-        """``jax.set_mesh`` fallback: a ``Mesh`` is itself the context
-        manager that installs it as the ambient resource env on old
-        jax (``with mesh:``)."""
-        return mesh
-
-
-if hasattr(jax, "typeof"):
-    typeof = jax.typeof
-else:
-    class _AvalView:
-        """Forwarding proxy over an old-jax aval: old avals carry no
-        ``.vma``; callers (vma marking in pipeline schedules,
-        ``ops._context.in_manual_axis_context``) read it as "the set of
-        varying axes", for which the faithful old-jax answer is the
-        empty set — there is no varying-type system to vary in."""
-
-        __slots__ = ("_aval",)
-
-        def __init__(self, aval):
-            object.__setattr__(self, "_aval", aval)
-
-        def __getattr__(self, name):
-            if name == "vma":
-                return frozenset()
-            return getattr(object.__getattribute__(self, "_aval"), name)
-
-    def typeof(x):
-        """``jax.typeof`` fallback: the abstract value of ``x``, with a
-        ``.vma`` view (see :class:`_AvalView`)."""
-        from jax._src import core as _core
-
-        return _AvalView(_core.get_aval(x))
+shard_map = jax.shard_map
+typeof = jax.typeof
+set_mesh = jax.set_mesh
+axis_size = jax.lax.axis_size
+axis_index = jax.lax.axis_index
+pcast = jax.lax.pcast
+# The replicate-a-masked-buffer idiom (one rank holds the data, the rest
+# hold zeros): under jax's varying-axis types the cotangent of the
+# replicated psum output seeds once, so plain psum differentiates
+# correctly inside shard_map.
+psum_replicated = jax.lax.psum

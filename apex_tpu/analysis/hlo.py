@@ -77,7 +77,8 @@ COLLECTIVE_PRIMS = {"psum", "pmax", "pmin", "all_gather",
                     "pgather"}
 # jaxpr primitives XLA services from the host every execution.
 HOST_TRANSFER_PRIMS = {"pure_callback", "io_callback",
-                       "debug_callback", "infeed", "outfeed"}
+                       "debug_callback", "debug_print", "infeed",
+                       "outfeed"}
 # Low-precision source dtypes for the promotion rule.
 _LOWP = ("bfloat16", "float16")
 
@@ -104,9 +105,9 @@ _GROWTH_TOL = 0.10  # APX603/605 byte tolerance, both directions
 # ---------------------------------------------------------------------------
 
 def _core():
-    import jax
+    import jax.extend.core
 
-    return jax.core
+    return jax.extend.core
 
 
 def _sub_jaxprs(eqn) -> Iterator[Any]:
@@ -155,7 +156,8 @@ def _provenance(eqn, repo_root: Path) -> Tuple[str, int, str]:
     try:
         from jax._src import source_info_util
 
-        frames = list(source_info_util.user_frames(eqn.source_info))
+        frames = list(source_info_util.user_frames(
+            eqn.source_info.traceback))
     except Exception:  # apex-lint: disable=APX202 -- provenance is best-effort: a moved jax internal must degrade to "<unknown>", not kill the audit
         frames = []
     pick = None
@@ -171,7 +173,10 @@ def _provenance(eqn, repo_root: Path) -> Tuple[str, int, str]:
     fname = pick.file_name
     if fname.startswith(root):
         fname = str(Path(fname).relative_to(repo_root).as_posix())
-    return fname, int(pick.start_line), pick.function_name
+    # jax reports the qualified name; findings and baselines key on
+    # the bare function name
+    return (fname, int(pick.start_line),
+            pick.function_name.rsplit(".", 1)[-1])
 
 
 # ---------------------------------------------------------------------------
@@ -180,7 +185,7 @@ def _provenance(eqn, repo_root: Path) -> Tuple[str, int, str]:
 
 def peak_live_bytes(jaxpr) -> int:
     """Estimate the peak of live buffer bytes over one execution of
-    ``jaxpr`` (a ``jax.core.Jaxpr``; pass ``closed.jaxpr``).
+    ``jaxpr`` (a ``jax.extend.core.Jaxpr``; pass ``closed.jaxpr``).
 
     Linear-scan liveness: inputs and constants are live at entry, each
     equation allocates its outputs, and a buffer is freed after its
@@ -200,6 +205,8 @@ def _peak(jaxpr, memo: Dict[int, int]) -> int:
     cached = memo.get(id(jaxpr))
     if cached is not None:
         return cached
+    import jax
+
     core = _core()
     last_use: Dict[Any, int] = {}
     for idx, eqn in enumerate(jaxpr.eqns):
@@ -215,8 +222,12 @@ def _peak(jaxpr, memo: Dict[int, int]) -> int:
         if v not in last_use and v not in outset:
             live -= _aval_bytes(v.aval)
     for idx, eqn in enumerate(jaxpr.eqns):
-        outs = [o for o in eqn.outvars]
-        alloc = sum(_aval_bytes(o.aval) for o in outs)
+        # A drop-var is never written.  jax 0.9.0's backward shard_map
+        # carries one per residual input (that input's cotangent):
+        # pricing them as allocated-then-freed doubled the peak of the
+        # data- and expert-parallel train steps.
+        alloc = sum(_aval_bytes(o.aval) for o in eqn.outvars
+                    if not isinstance(o, jax.core.DropVar))
         inner_excess = 0
         for sub in _sub_jaxprs(eqn):
             io = sum(_aval_bytes(v.aval)
@@ -225,9 +236,6 @@ def _peak(jaxpr, memo: Dict[int, int]) -> int:
                                max(0, _peak(sub, memo) - io))
         live += alloc
         peak = max(peak, live + inner_excess)
-        for o in outs:  # drop-vars are dead on arrival
-            if isinstance(o, core.DropVar):
-                live -= _aval_bytes(o.aval)
         for v in {v for v in eqn.invars if isinstance(v, core.Var)}:
             if last_use.get(v) == idx and v not in outset:
                 live -= _aval_bytes(v.aval)

@@ -339,9 +339,9 @@ def _overlap_advisory(entry: str, eqns, idx, core_mod,
 
 
 def _jax_core():
-    import jax
+    import jax.extend.core
 
-    return jax.core
+    return jax.extend.core
 
 
 def _collective_census(jaxpr) -> Tuple[Dict[str, int],

@@ -57,14 +57,6 @@ def zero_adam_plan(world: int, num_groups: int = 1, *,
     right on its 1-device bench mesh, silently wrong on any real one.
     The boundary specs now derive from this plan
     (``plan.partition_spec``)."""
-    import jax
-
-    # pre-vma jax routes _compat.axis_index through ONE extra
-    # psum_scatter (the partition_id-free rank derivation); the budget
-    # prices the implementation as it actually lowers on this stack —
-    # a jax upgrade that drops the hop shows up as a reviewed
-    # baseline diff, not a silent under-budget
-    rank_hop = 0 if hasattr(jax, "shard_map") else 1
     return MeshPlan.build(
         axes=((axis_name, world, "zero"),),
         tensor_specs={
@@ -78,7 +70,7 @@ def zero_adam_plan(world: int, num_groups: int = 1, *,
         },
         # psum_scatter traces as the reduce_scatter primitive — the
         # census speaks jaxpr
-        collective_budget={"reduce_scatter": num_groups + rank_hop,
+        collective_budget={"reduce_scatter": num_groups,
                            "all_gather": num_groups})
 
 

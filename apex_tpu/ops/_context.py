@@ -13,10 +13,9 @@ def in_manual_axis_context(*operands) -> bool:
     still fuses it per shard.  Outside (plain jit / pjit / GSPMD) the
     Pallas kernels run.
 
-    The public ``jax.typeof(operand).vma`` type (via the
-    :mod:`apex_tpu._compat` shim — old jax has no ``typeof`` and its
-    avals carry no ``vma``) gives a fast positive (any varying operand
-    => manual context); the axis-env probe then decides the rest.  The axis env CANNOT be skipped even when every
+    The public ``jax.typeof(operand).vma`` type gives a fast positive
+    (any varying operand => manual context); the axis-env probe then
+    decides the rest.  The axis env CANNOT be skipped even when every
     operand is unvarying: ``pallas_call`` inside
     ``shard_map(check_vma=True)`` demands vma-typed out specs regardless
     of operand variance, so replicated inputs still need the fallback.

@@ -106,6 +106,37 @@ def _pos_mask(shape, page0, sl):
     return pos < sl
 
 
+def _row_scales(s_ref, pack, width):
+    """This page's per-row dequant factors as a (bs, 1) column — or,
+    packed, a (bs, width) array with each head's factor on its lane
+    half.  ``s_ref`` is the (1, 1, g, bs) block of the
+    (nb, hk, g, bs) scale view."""
+    if pack:
+        return _pack_lane_cols(s_ref[0, 0, 0, :][:, None],
+                               s_ref[0, 0, 1, :][:, None], width)
+    return s_ref[0, 0, 0, :][:, None]
+
+
+def _normalized(l_sc, acc, pack):
+    """acc / l with dead rows (l == 0: an inactive sequence or a
+    front-padding row) emitting exactly 0.  Packed, l is spread to the
+    lane halves first and the dead test runs on that float array —
+    Mosaic has no lane select over booleans."""
+    l = _pack_lane_cols(l_sc[:, :1], l_sc[:, 1:2], acc.shape[1]) \
+        if pack else l_sc[:, :1]
+    dead = l == 0.0
+    return jnp.where(dead, 0.0, acc[:] / jnp.where(dead, 1.0, l))
+
+
+def _scale_operand(scale, hk, g):
+    """(nb, h, bs) global-head-order scales viewed (nb, hk, g, bs): a
+    program's head group becomes a whole trailing (g, bs) block, which
+    the TPU lowering accepts where a size-g block on an axis of h is
+    refused."""
+    nb, _, bs = scale.shape
+    return scale.reshape(nb, hk, g, bs)
+
+
 def _decode_kernel(a, bs, pack, has_scale, *refs):
     """One (batch row, head group, page) program.  Scalar-prefetch refs
     lead: block tables (consumed by the index maps, unused here) and
@@ -131,25 +162,17 @@ def _decode_kernel(a, bs, pack, has_scale, *refs):
     # bucketed cost the ladder accounts for, the FLOPs are not paid)
     @pl.when(j * bs < sl)
     def _page():
-        q = q_ref[0]                                  # (1, dk)
+        q = q_ref[0, 0]                               # (1, dk)
         k = k_ref[0, 0]                               # (bs, dk)
         v = v_ref[0, 0]
         if has_scale:
             # int8 rows -> f32 in VMEM; per-row scales so history is
             # never requantized by an append.  Packed: each lane half
             # is one head's row, scaled by that head's factor.
-            if pack:
-                ks = _pack_lane_cols(ks_ref[0, 0, :][:, None],
-                                     ks_ref[0, 1, :][:, None],
-                                     k.shape[-1])
-                vs = _pack_lane_cols(vs_ref[0, 0, :][:, None],
-                                     vs_ref[0, 1, :][:, None],
-                                     v.shape[-1])
-            else:
-                ks = ks_ref[0, 0, :][:, None]
-                vs = vs_ref[0, 0, :][:, None]
-            k = k.astype(jnp.float32) * ks
-            v = v.astype(jnp.float32) * vs
+            k = k.astype(jnp.float32) * _row_scales(ks_ref, pack,
+                                                    k.shape[-1])
+            v = v.astype(jnp.float32) * _row_scales(vs_ref, pack,
+                                                    v.shape[-1])
         heads = _packed_scores(q, k) if pack \
             else (_dot(q, k, trans_b=True),)           # (1, bs) fp32
         mask = _pos_mask(heads[0].shape, j * bs, sl)
@@ -179,20 +202,7 @@ def _decode_kernel(a, bs, pack, has_scale, *refs):
 
     @pl.when(j == nj - 1)
     def _finish():
-        if pack:
-            l0 = l_sc[:, :1]
-            l1 = l_sc[:, 1:2]
-            sl0 = jnp.where(l0 == 0.0, 1.0, l0)   # inactive rows -> 0
-            sl1 = jnp.where(l1 == 0.0, 1.0, l1)
-            inv = _pack_lane_cols(1.0 / sl0, 1.0 / sl1, acc.shape[1])
-            dead = _pack_lane_cols(l0 == 0.0, l1 == 0.0, acc.shape[1])
-            o_ref[0] = jnp.where(dead, 0.0,
-                                 acc[:] * inv).astype(o_ref.dtype)
-            return
-        l = l_sc[:, :1]
-        safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = jnp.where(l == 0.0, 0.0,
-                             acc[:] / safe).astype(o_ref.dtype)
+        o_ref[0, 0] = _normalized(l_sc, acc, pack).astype(o_ref.dtype)
 
 
 def _decode_paged(q3, k_cache, v_cache, block_tables, seq_lens, scale,
@@ -207,9 +217,12 @@ def _decode_paged(q3, k_cache, v_cache, block_tables, seq_lens, scale,
     has_scale = k_scale is not None
     g = 2 if pack else 1
 
+    # q/o ride as (b, hk, 1, dk): the block's trailing (1, dk) is then
+    # the array's own — a block of 1 on the hk axis of (b, hk, dk) is
+    # refused by the TPU lowering
     def qo_spec():
-        return pl.BlockSpec((1, 1, dk),
-                            lambda b_, h_, j, bt, sl: (b_, h_, 0),
+        return pl.BlockSpec((1, 1, 1, dk),
+                            lambda b_, h_, j, bt, sl: (b_, h_, 0, 0),
                             memory_space=pltpu.VMEM)
 
     kv_spec = pl.BlockSpec(
@@ -217,15 +230,15 @@ def _decode_paged(q3, k_cache, v_cache, block_tables, seq_lens, scale,
         lambda b_, h_, j, bt, sl: (bt[b_, j], h_, 0, 0),
         memory_space=pltpu.VMEM)
     in_specs = [qo_spec(), kv_spec, kv_spec]
-    operands = [q3, k_cache, v_cache]
+    operands = [q3[:, :, None, :], k_cache, v_cache]
     if has_scale:
-        # scales keep GLOBAL head order (nb, h, bs); a packed program
-        # reads its pair as a size-2 block on the head axis
         sc_spec = pl.BlockSpec(
-            (1, g, bs), lambda b_, h_, j, bt, sl: (bt[b_, j], h_, 0),
+            (1, 1, g, bs),
+            lambda b_, h_, j, bt, sl: (bt[b_, j], h_, 0, 0),
             memory_space=pltpu.VMEM)
         in_specs += [sc_spec, sc_spec]
-        operands += [k_scale, v_scale]
+        operands += [_scale_operand(k_scale, hk, g),
+                     _scale_operand(v_scale, hk, g)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, hk, mp),
@@ -239,9 +252,9 @@ def _decode_paged(q3, k_cache, v_cache, block_tables, seq_lens, scale,
     return pl.pallas_call(
         functools.partial(_decode_kernel, a, bs, pack, has_scale),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hk, dk), q3.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, hk, 1, dk), q3.dtype),
         interpret=_interpret(),
-    )(block_tables, seq_lens, *operands)
+    )(block_tables, seq_lens, *operands)[:, :, 0, :]
 
 
 def flash_decode(q: jnp.ndarray, k_cache: jnp.ndarray,
@@ -323,18 +336,10 @@ def _decode_multi_kernel(a, bs, t, pack, has_scale, *refs):
         k = k_ref[0, 0]                               # (bs, dk)
         v = v_ref[0, 0]
         if has_scale:
-            if pack:
-                ks = _pack_lane_cols(ks_ref[0, 0, :][:, None],
-                                     ks_ref[0, 1, :][:, None],
-                                     k.shape[-1])
-                vs = _pack_lane_cols(vs_ref[0, 0, :][:, None],
-                                     vs_ref[0, 1, :][:, None],
-                                     v.shape[-1])
-            else:
-                ks = ks_ref[0, 0, :][:, None]
-                vs = vs_ref[0, 0, :][:, None]
-            k = k.astype(jnp.float32) * ks
-            v = v.astype(jnp.float32) * vs
+            k = k.astype(jnp.float32) * _row_scales(ks_ref, pack,
+                                                    k.shape[-1])
+            v = v.astype(jnp.float32) * _row_scales(vs_ref, pack,
+                                                    v.shape[-1])
         heads = _packed_scores(q, k) if pack \
             else (_dot(q, k, trans_b=True),)           # (t, bs) fp32
         # per-row causal mask: row r attends positions <= sl - t + r
@@ -366,20 +371,7 @@ def _decode_multi_kernel(a, bs, t, pack, has_scale, *refs):
 
     @pl.when(j == nj - 1)
     def _finish():
-        if pack:
-            l0 = l_sc[:, :1]
-            l1 = l_sc[:, 1:2]
-            sl0 = jnp.where(l0 == 0.0, 1.0, l0)
-            sl1 = jnp.where(l1 == 0.0, 1.0, l1)
-            inv = _pack_lane_cols(1.0 / sl0, 1.0 / sl1, acc.shape[1])
-            dead = _pack_lane_cols(l0 == 0.0, l1 == 0.0, acc.shape[1])
-            o_ref[0, 0] = jnp.where(dead, 0.0,
-                                    acc[:] * inv).astype(o_ref.dtype)
-            return
-        l = l_sc[:, :1]
-        safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = jnp.where(l == 0.0, 0.0,
-                                acc[:] / safe).astype(o_ref.dtype)
+        o_ref[0, 0] = _normalized(l_sc, acc, pack).astype(o_ref.dtype)
 
 
 def _decode_paged_multi(q4, k_cache, v_cache, block_tables, seq_lens,
@@ -407,10 +399,12 @@ def _decode_paged_multi(q4, k_cache, v_cache, block_tables, seq_lens,
     operands = [q4, k_cache, v_cache]
     if has_scale:
         sc_spec = pl.BlockSpec(
-            (1, g, bs), lambda b_, h_, j, bt, sl: (bt[b_, j], h_, 0),
+            (1, 1, g, bs),
+            lambda b_, h_, j, bt, sl: (bt[b_, j], h_, 0, 0),
             memory_space=pltpu.VMEM)
         in_specs += [sc_spec, sc_spec]
-        operands += [k_scale, v_scale]
+        operands += [_scale_operand(k_scale, hk, g),
+                     _scale_operand(v_scale, hk, g)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, hk, mp),

@@ -245,8 +245,8 @@ def _norm_finite_kernel(total_rows: int, block_rows: int, hyp_ref,
     rows = jax.lax.broadcasted_iota(jnp.int32, g.shape, 0) \
         + i * block_rows
     g = jnp.where(rows < total_rows, g, 0.0)
-    part_ref[0] = jnp.sum(g * g)
-    fin_ref[0] = jnp.all(jnp.isfinite(g)).astype(jnp.int32)
+    part_ref[i] = jnp.sum(g * g)
+    fin_ref[i] = jnp.all(jnp.isfinite(g)).astype(jnp.int32)
 
 
 def _norm_finite_pallas(buf: jnp.ndarray, inv: jnp.ndarray,
@@ -264,8 +264,9 @@ def _norm_finite_pallas(buf: jnp.ndarray, inv: jnp.ndarray,
         in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
                   pl.BlockSpec((block_rows, LANE), lambda i: (i, 0),
                                memory_space=pltpu.VMEM)],
-        out_specs=[pl.BlockSpec((1,), lambda i: (i,),
-                                memory_space=pltpu.SMEM)] * 2,
+        # whole (grid,) arrays in SMEM, one slot per program: the TPU
+        # lowering refuses a rank-1 block of 1
+        out_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)] * 2,
         out_shape=[jax.ShapeDtypeStruct((grid,), jnp.float32),
                    jax.ShapeDtypeStruct((grid,), jnp.int32)],
         interpret=fused_optim._interpret() if interpret is None

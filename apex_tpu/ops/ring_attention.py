@@ -30,8 +30,7 @@ from __future__ import annotations
 from typing import Optional
 
 import jax
-from .._compat import (HAS_VMA, axis_index, axis_size,
-                       rewrite_trace_free, typeof)
+from .._compat import axis_index, axis_size, typeof
 import jax.numpy as jnp
 
 _NEG = -1e30
@@ -48,11 +47,6 @@ def flash_legal_here(*operands) -> bool:
     kernel automatically: probed on the CPU mesh, a ``P('sp')`` operand
     shows ``vma={'sp'}`` under ``check_vma=True`` and ``vma=set()``
     under ``check_vma=False``."""
-    if not HAS_VMA:
-        # VMA types unavailable (older JAX): there pallas_call is
-        # rejected by the check_rep=True rewrite interpreter ("no
-        # replication rule"), so legality = not being under it.
-        return rewrite_trace_free(*operands)
     for x in operands:
         try:
             vma = getattr(typeof(x), "vma", None)

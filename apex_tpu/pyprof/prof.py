@@ -51,12 +51,21 @@ _DEVICE_SPECS = {
 
 
 def device_spec(device=None) -> DeviceSpec:
+    """The peak-rate row of ``device`` (default: ``jax.devices()[0]``).
+    The ``cpu`` row is a placeholder for host runs only; an accelerator
+    whose kind is not in the table raises ``KeyError`` — a rate computed
+    against another device's peak is wrong, not approximate."""
     d = device or jax.devices()[0]
-    kind = getattr(d, "device_kind", "cpu").lower()
+    if d.platform == "cpu":
+        return _DEVICE_SPECS["cpu"]
+    kind = d.device_kind.lower()
     for key, spec in _DEVICE_SPECS.items():
-        if key in kind:
+        if key != "cpu" and key in kind:
             return spec
-    return _DEVICE_SPECS["cpu"]
+    raise KeyError(
+        f"no peak-rate row for {d.platform} device kind "
+        f"{d.device_kind!r}: add it to pyprof.prof._DEVICE_SPECS "
+        f"with its source")
 
 
 # ---------------------------------------------------------------------------

@@ -503,7 +503,7 @@ def _build_dp8_train_step():
 
     from .._compat import shard_map
     from ..optimizers import fused_adam
-    from .standalone_gpt import GPTModel, gpt_loss
+    from .standalone_gpt import GPTModel, gpt_loss, unbox
 
     vocab, hidden, heads, layers, seq = 64, 32, 4, 2, 16
     batch = 16  # 2 per device
@@ -516,7 +516,9 @@ def _build_dp8_train_step():
     tokens = jax.random.randint(jax.random.fold_in(key, 1),
                                 (batch, seq), 0, vocab)
     labels = jnp.roll(tokens, -1, -1)
-    params = jax.jit(model.init)(key, tokens[:2])["params"]
+    # raw arrays: a boxed ("tensor", None) leaf makes flax constrain it
+    # to a `tensor` axis the data-only mesh inside shard_map lacks
+    params = unbox(jax.jit(model.init)(key, tokens[:2])["params"])
     tx = fused_adam(1e-3)
     opt_state = jax.jit(tx.init)(params)
     plan = _dp8_plan()
@@ -756,10 +758,10 @@ def _moe_ep8_plan():
                               num_experts=8, capacity_factor=4.0,
                               router="top2")
     # psum: the forward loss psum + its per-operand backward partials
-    # as this jax transposes them (measured 7 on the pre-vma stack
-    # with the fused routing front)
+    # as this jax transposes them (measured 3 on jax 0.9.0 with the
+    # fused routing front)
     return layer.mesh_plan(8).with_specs(
-        {r"^in1$": ("expert",)}, budget={"psum": 7})
+        {r"^in1$": ("expert",)}, budget={"psum": 3})
 
 
 register_entry_point(

@@ -189,8 +189,11 @@ def _chunked_expert_exchange(buf: jnp.ndarray,
     if chunks * cs != c:
         buf = jnp.pad(buf, ((0, 0), (0, chunks * cs - c), (0, 0)))
     n_shards = axis_size(axis_name)
-    piece = jax.ShapeDtypeStruct((e // n_shards, cs * n_shards, h),
-                                 buf.dtype)
+    # Type-only example of an arrived chunk, cut from buf so that it
+    # varies over the axes buf varies over: traced on an unvarying
+    # ShapeDtypeStruct the closure bakes in a pvary that the replay on
+    # the real (already varying) chunk rejects.
+    piece = buf[:, :cs].reshape(e // n_shards, cs * n_shards, h)
     closed, consts = jax.closure_convert(expert_fn, piece)
 
     def _disp(p):   # dispatch hop; also the transpose of _ret

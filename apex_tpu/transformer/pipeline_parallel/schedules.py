@@ -168,7 +168,7 @@ def pipeline_forward(stage_fn: Callable, stage_params: Any, microbatches: Any,
     (_, outputs), _ = jax.lax.scan(
         tick, (state0, outputs0), jnp.arange(num_micro + nstages - 1))
     # Only the last stage wrote non-zeros; psum replicates to every
-    # stage (seed-once VJP semantics on old jax — see _compat).
+    # stage.
     return jax.tree.map(lambda o: psum_replicated(o, axis_name), outputs)
 
 
@@ -329,8 +329,7 @@ def pipeline_forward_interleaved(stage_fn: Callable, chunk_params: Any,
 
     (_, outputs), _ = jax.lax.scan(
         tick, (state0, outputs0), jnp.arange(K + nstages))
-    # Only stage 0 collected; psum replicates across the axis
-    # (seed-once VJP semantics on old jax — see _compat).
+    # Only stage 0 collected; psum replicates across the axis.
     return jax.tree.map(lambda o: psum_replicated(o, axis_name), outputs)
 
 

@@ -5,11 +5,10 @@ driver amortizes per-step dispatch, but every *process* still pays the
 full XLA compile of each entry point it touches — minutes of apparent
 "wall" on a cold host that have nothing to do with the step being
 measured.  JAX's persistent compilation cache keys a lowered module to
-a disk entry; :func:`configure_compile_cache` points it at the
-``APEX_TPU_COMPILE_CACHE_DIR`` registry flag (or an explicit
-directory) and relaxes the min-size/min-compile-time floors so even
-smoke-sized programs are cached — exactly the programs CI and the
-drivers recompile most often.
+a disk entry; :func:`configure_compile_cache` decides ONCE where that
+cache lives (see its precedence) and relaxes the min-size/
+min-compile-time floors so even smoke-sized programs are cached —
+exactly the programs CI and the drivers recompile most often.
 
 One ``python -m apex_tpu.testing.entry_points --aot`` run per host
 pre-populates the cache for every registered entry point
@@ -32,51 +31,57 @@ logger = get_logger(__name__)
 _configured: Optional[str] = None
 
 
-def configure_compile_cache(directory: Optional[str] = None,
-                            ) -> Optional[str]:
-    """Wire jax's persistent compilation cache to ``directory`` (default:
-    the ``APEX_TPU_COMPILE_CACHE_DIR`` flag).  Returns the directory in
-    effect, or None when the flag is unset (no-op — callers wire this
-    unconditionally).  Idempotent; re-pointing at a different directory
-    logs and re-configures.
+# The cache key includes the directory's path, so the on-chip default is
+# one fixed place under the checkout (git-ignored), never a temp name.
+_CHECKOUT_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
-    The min-entry-size and min-compile-time floors are relaxed so the
-    smoke/test-tier programs (fast compiles, small modules) are cached
-    too — on a laptop-class CPU host those floors would exclude exactly
-    the programs whose cold-start this cache exists to kill.
+
+def configure_compile_cache() -> Optional[str]:
+    """Turn on jax's persistent compilation cache and return the
+    directory in effect (None: no cache).  In order:
+
+    1. ``JAX_COMPILATION_CACHE_DIR`` is set: jax's own setting stands —
+       no directory is set in code;
+    2. the ``APEX_TPU_COMPILE_CACHE_DIR`` flag;
+    3. on a TPU backend, ``<checkout>/.jax_cache`` — a cold chip run
+       then shares its compiles between phases and with the next run
+       from the same checkout;
+    4. otherwise none.  The platform condition is deliberate: tier-1
+       calls the smoke drivers hundreds of times on the CPU, and a
+       default cache there would fill the checkout with entries.
+
+    Idempotent.  The min-entry-size and min-compile-time floors are
+    relaxed so the smoke/test-tier programs (fast compiles, small
+    modules) are cached too.
     """
     global _configured
-    if directory is None:
-        directory = flag_str("APEX_TPU_COMPILE_CACHE_DIR")
+    import jax
+
+    from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")  # apex-lint: disable=APX301 -- jax's own variable, read only to stand aside for it; not an APEX_TPU flag
+    directory = from_env or flag_str("APEX_TPU_COMPILE_CACHE_DIR")
+    if not directory and jax.default_backend() == "tpu":
+        directory = _CHECKOUT_CACHE
     if not directory:
         return None
     if _configured == directory:
         return directory
-    import jax
-
-    os.makedirs(directory, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", directory)
+    if not from_env:
+        os.makedirs(directory, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", directory)
     for name, val in (
             ("jax_persistent_cache_min_compile_time_secs", 0.0),
             ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        if hasattr(jax.config, name):
-            jax.config.update(name, val)
+        jax.config.update(name, val)
     # jax initializes the cache AT MOST ONCE, on the first compile: if
     # any compile ran before this call (or the dir changed), the
     # latched no-cache/old-dir state silently wins and every later
     # config.update is a no-op.  Reset so the next compile re-reads
-    # the directory (verified against jax 0.4.37
-    # compilation_cache._initialize_cache).
-    try:
-        from jax.experimental.compilation_cache import (
-            compilation_cache as _cc)
+    # the directory.
+    from jax.experimental.compilation_cache import compilation_cache
 
-        _cc.reset_cache()
-    except (ImportError, AttributeError) as e:
-        logger.warning(
-            "compilation-cache reset unavailable (%s): the persistent "
-            "cache only takes effect if no compile preceded this "
-            "call", str(e)[:120])
+    compilation_cache.reset_cache()
     if _configured is not None:
         logger.info("compile cache re-pointed: %s -> %s", _configured,
                     directory)

@@ -291,6 +291,19 @@ class TestPeakMemory:
         closed = jax.make_jaxpr(chain)(jnp.ones((1024,), jnp.float32))
         assert hlo.peak_live_bytes(closed.jaxpr) == 2 * 4096
 
+    def test_dropped_outputs_are_not_priced(self):
+        # an output nobody reads is a drop-var and is never written:
+        # input + the one sorted operand that is kept, not both (jax
+        # 0.9.0's backward shard_map drops one output per residual)
+        def kept_half(x):
+            return jax.lax.sort((x, x), num_keys=1)[0]
+
+        closed = jax.make_jaxpr(kept_half)(jnp.ones((1024,), jnp.float32))
+        (eqn,) = closed.jaxpr.eqns
+        assert sum(isinstance(o, jax.core.DropVar)
+                   for o in eqn.outvars) == 1
+        assert hlo.peak_live_bytes(closed.jaxpr) == 2 * 4096
+
     def test_pjit_inner_peak_counted(self):
         # the same chain jitted: the walk must descend into the pjit
         # call and see the inner liveness, not price the call as one
