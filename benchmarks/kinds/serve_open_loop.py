@@ -26,6 +26,8 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import json
+import re
 import time
 from typing import List, Optional, Tuple
 
@@ -34,7 +36,7 @@ import numpy as np
 
 from ..common import CellResult, CompileCounter, check, say
 from ..stats import percentile
-from ..trace import load_trace
+from ..trace import load_trace, ops_in_runs, ops_matching, runs_matching
 from ..traffic import open_loop_schedule
 
 # The system computes in bf16, the reference in float32.  Random
@@ -45,6 +47,67 @@ from ..traffic import open_loop_schedule
 # position of ~0.5: a token taken from a wrong position, a stale cache
 # page or an int8 path lands a whole spread away.
 LOGIT_MARGIN = 0.05
+
+
+# the flash-decode kernel's call, as the trace names it: its output is
+# bf16[batch rung, packed heads, 1, head_dim] and its first operand the
+# block table, s32[batch rung, page rung]
+DECODE_CALL = r"= bf16\[(\d+),\d+,1,\d+\]\S* custom-call\("
+BLOCK_TABLE = DECODE_CALL + r"s32\[(\d+),(\d+)\]"
+
+
+def traced_grid(trace) -> dict:
+    """What the device ran while the profiler was on, from its trace:
+    the decode ticks, the batch dimension of their decode calls and the
+    slots of their block tables, summed -- what ``decode_grid``'s
+    ``ticks``, ``grid_rows`` and ``grid_pages``, taken from the
+    engine's own events, have to equal.  Of the first device that ran a
+    tick (under tensor parallelism each device runs every tick);
+    ``traced_grid_pages`` is None where a decode call does not show its
+    block table."""
+    for d in trace.devices:
+        runs = runs_matching(d, r"^jit_step\(", DECODE_CALL)
+        if not runs:
+            continue
+        calls = {}                    # the first decode call of each tick
+        for i, op in ops_in_runs(runs, ops_matching(d.ops, DECODE_CALL)):
+            calls.setdefault(i, op.name)
+        tables = [re.search(BLOCK_TABLE, name) for name in calls.values()]
+        return dict(
+            traced_ticks=len(runs),
+            traced_grid_rows=sum(int(re.search(DECODE_CALL, name)[1])
+                                 for name in calls.values()),
+            traced_grid_pages=sum(int(m[2]) * int(m[3]) for m in tables)
+            if all(tables) else None)
+    return dict(traced_ticks=0, traced_grid_rows=0, traced_grid_pages=0)
+
+
+def hold_grid_to_trace(faults, grid, trace) -> None:
+    """The fills stand on the engine's events: a run whose events and
+    device trace disagree on the ticks, the rows or the slots launched
+    is not correct.  (Not called where the trace has no device plane,
+    as on the CPU: there is nothing to hold the events to.)"""
+    seen = traced_grid(trace)
+    say(**seen)
+    for key in ("ticks", "grid_rows", "grid_pages"):
+        counted = (grid or {}).get(key, 0)
+        check(faults, seen["traced_" + key] in (None, counted),
+              f"decode_grid counts {key}={counted} where the device "
+              f"trace holds {seen['traced_' + key]}")
+
+
+class TickLog:
+    """Stands in for the engine's monitor while the profiler is on and
+    keeps the ``decode_step`` events: the rows and the rungs of each
+    tick as the engine itself launched it (``batch``, ``batch_bucket``,
+    ``pages_bucket``)."""
+
+    def __init__(self):
+        self.ticks: List[dict] = []
+
+    def event(self, kind, name, value=None, **attrs):
+        if name == "decode_step":
+            self.ticks.append(attrs)
 
 
 @dataclasses.dataclass
@@ -72,6 +135,8 @@ class Drive:
     decode_ticks: int
     compiles_in_window: int
     decode_shapes: Optional[dict]     # summed while the profiler ran
+    decode_grid: Optional[dict]       # likewise: live work against the
+    #                                   rungs each tick was launched on
     engine_steps: int
     due_in_window: int                # scheduled, submitted or not
 
@@ -94,6 +159,8 @@ def drive(job, traffic, *, seed, seconds, trace_dir=None,
     decode_ticks = engine_steps = 0
     trace_on = traced = False
     shapes = dict(live_pages=0, live_tokens=0, rows=0)
+    grid = dict(ticks=0, rows=0, grid_rows=0, live_pages=0, grid_pages=0)
+    log, monitor_was = TickLog(), engine.monitor
     compiles_at_open = None
     with CompileCounter() as compiles:
         while True:
@@ -109,10 +176,10 @@ def drive(job, traffic, *, seed, seconds, trace_dir=None,
                     and now >= close_at - min(traffic["trace_seconds"],
                                               seconds / 2):
                 jax.profiler.start_trace(trace_dir)
-                trace_on = True
+                trace_on, engine.monitor = True, log
             if trace_on and now >= close_at:
                 jax.profiler.stop_trace()
-                trace_on, traced = False, True
+                trace_on, traced, engine.monitor = False, True, monitor_was
             while pending and opened + pending[0].due_s <= now:
                 a = pending.popleft()
                 due = opened + a.due_s
@@ -142,11 +209,25 @@ def drive(job, traffic, *, seed, seconds, trace_dir=None,
                 if n > 0:
                     decode_ticks += 1
                     if trace_on:
+                        pages = 0
                         for q in engine.active.values():
                             kv = len(q.prompt) + len(q.out_tokens) - 1
                             shapes["live_tokens"] += kv
-                            shapes["live_pages"] += -(-kv // block)
+                            pages += -(-kv // block)
+                        shapes["live_pages"] += pages
                         shapes["rows"] += n
+                        # the live pages are the benchmark's count (the
+                        # tick's requests are still the active ones: the
+                        # finished leave at the next step's start); the
+                        # rows and the rungs are the engine's own word
+                        grid["live_pages"] += pages
+                        for tick in log.ticks:
+                            grid["ticks"] += 1
+                            grid["rows"] += tick["batch"]
+                            grid["grid_rows"] += tick["batch_bucket"]
+                            grid["grid_pages"] += tick["batch_bucket"] \
+                                * tick["pages_bucket"]
+                        log.ticks.clear()
             elif pending:
                 time.sleep(max(0.0, min(
                     opened + pending[0].due_s - now, 0.002)))
@@ -161,7 +242,8 @@ def drive(job, traffic, *, seed, seconds, trace_dir=None,
     return Drive(tracks, opened, closed, lateness, queue_mid, queue_close,
                  active_close, decode_ticks, compiles_in_window,
                  dict(shapes, **job.facts["decode_geometry"])
-                 if traced and shapes["rows"] else None, engine_steps,
+                 if traced and shapes["rows"] else None,
+                 grid if traced and grid["ticks"] else None, engine_steps,
                  sum(a.rid.startswith("req") for a in schedule))
 
 
@@ -265,6 +347,7 @@ def run(job, traffic, *, seed, seconds, trace_dir, platform,
     # every number of the [bench] lines is a fact too, so a later
     # per-layer metric can read one through ``engine_fact`` by name
     facts = dict(job.facts, decode_shapes=d.decode_shapes,
+                 decode_grid=d.decode_grid,
                  queue_wait_p90_ms=percentile(waits, 90),
                  **{k: v for k, v in m.items() if k.endswith(("_ms", "_s"))
                     and not isinstance(v, list)})
@@ -287,13 +370,17 @@ def run(job, traffic, *, seed, seconds, trace_dir, platform,
         queue_wait_p90_ms=_r(facts["queue_wait_p90_ms"]),
         lateness_p50_ms=_r(percentile(late, 50)),
         lateness_max_ms=_r(max(late, default=None)))
+    trace = load_trace(trace_dir) if trace_dir else None
+    if d.decode_grid:
+        say(decode_grid=json.dumps(d.decode_grid))
+    if trace is not None and any(dev.modules for dev in trace.devices):
+        hold_grid_to_trace(faults, d.decode_grid, trace)
     return CellResult(
         correct=not faults, attempted=d.due_in_window, failed=failed,
         end_to_end={k: m[k] for k in ("ttft_p90_ms", "itl_p95_ms",
                                       "serve_tokens_per_s")
                     if m[k] is not None},
-        window_opened_at=d.opened, facts=facts,
-        trace=load_trace(trace_dir) if trace_dir else None,
+        window_opened_at=d.opened, facts=facts, trace=trace,
         faults=faults)
 
 

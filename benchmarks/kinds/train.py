@@ -88,6 +88,10 @@ def run(job, traffic, *, seed, seconds, trace_dir, platform,
     check(faults, compiles_in_window == 0,
           f"{compiles_in_window} programs lowered inside the window")
     rate = steps * job.tokens_per_step / window_s
+    # of the host's window, for the [bench] line alone: a traced run's
+    # window holds the profiler's stalls, so the metric ``mfu_pct.train``
+    # is read from the trace (readers/step_mfu.py), not from here
+    mfu_pct = 100 * rate * job.flops_per_token / peaks["bf16_flops_per_s"]
     # one far-off run in ten was seen on the chip (PR 24): these two say
     # whether a run's steps were all a little slow or a few stalled
     took = sorted(b - a for a, b in zip(ends[-steps - 1:], ends[-steps:]))
@@ -104,13 +108,13 @@ def run(job, traffic, *, seed, seconds, trace_dir, platform,
         compiles_in_window=compiles_in_window,
         lower_compile_s=round(lower_compile_s, 1),
         flops_per_token=f"{job.flops_per_token:.4g}",
-        mfu_pct=round(100 * rate * job.flops_per_token
-                      / peaks["bf16_flops_per_s"], 2))
+        mfu_pct=round(mfu_pct, 2))
     return CellResult(
         correct=not faults, attempted=steps,
         failed=sum(1 for x in window_losses if not math.isfinite(x)),
         end_to_end={"train_tokens_per_s": rate},
         window_opened_at=opened,
-        facts=dict(job.facts),
+        facts=dict(job.facts,
+                   step_flops=job.tokens_per_step * job.flops_per_token),
         trace=load_trace(trace_dir) if trace_dir else None,
         faults=faults)

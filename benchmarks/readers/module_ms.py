@@ -3,7 +3,7 @@
 Parameters: ``module_pattern`` (regex on the XLA module's name) and,
 optionally, ``contains_op`` (regex on an op's HLO text: only runs that
 hold such an op count -- decode ticks and prefills share a module
-name)."""
+name) and ``scope_pattern`` (regex on the same op's scope)."""
 from ..trace import runs_matching
 
 
@@ -12,7 +12,8 @@ def read(trace, facts, params, peaks):
         return None
     runs = [m for d in trace.devices
             for m in runs_matching(d, params["module_pattern"],
-                                   params.get("contains_op"))]
+                                   params.get("contains_op"),
+                                   params.get("scope_pattern"))]
     if not runs:
         return None
     return 1e3 * sum(m.dur for m in runs) / len(runs)

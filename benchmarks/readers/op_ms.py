@@ -1,9 +1,9 @@
 """``op_ms``: device time of the ops matching ``op_pattern`` inside
-the runs of one program, per run, in milliseconds.  Parameters as
-``module_ms``, plus ``op_pattern``."""
-import re
-
-from ..trace import ops_in_runs, runs_matching
+the runs of one program, per run, in milliseconds.  Parameters:
+``module_pattern`` and optional ``contains_op`` as ``module_ms``, plus
+``op_pattern`` and, optionally, ``scope_pattern`` (regex on the op's
+scope; an op counts when both match)."""
+from ..trace import ops_in_runs, ops_matching, runs_matching
 
 
 def matching_op_seconds(trace, params):
@@ -14,8 +14,8 @@ def matching_op_seconds(trace, params):
         runs = runs_matching(d, params["module_pattern"],
                              params.get("contains_op"))
         n_runs += len(runs)
-        wanted = (op for op in d.ops
-                  if re.search(params["op_pattern"], op.name))
+        wanted = ops_matching(d.ops, params.get("op_pattern"),
+                              params.get("scope_pattern"))
         seconds += sum(op.dur for _, op in ops_in_runs(runs, wanted))
     return seconds, n_runs
 

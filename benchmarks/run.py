@@ -14,7 +14,8 @@ README.md for how a later PR adds to each.
 
 ``--trace 0`` prints the cell's end-to-end metrics; ``--trace 1`` is a
 run of its own with the profiler on for a short steady slice, and
-prints the cell's per-layer metrics and the ``breakdown``.  Without a
+prints the cell's per-layer metrics, the ``breakdown`` and, on
+``[bench]`` lines, the device time by the program's own scopes.  Without a
 TPU, or with a device that ``peaks.json`` has no row for, the command
 exits non-zero and prints no result line: it never falls back to the
 CPU.
@@ -126,7 +127,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     from apex_tpu.utils.compile_cache import configure_compile_cache
 
     from .common import say
-    from .trace import breakdown, busy_seconds
+    from .trace import breakdown, busy_seconds, scope_ms
 
     say(compile_cache=configure_compile_cache())
     say(workload=workload, kind=traffic["kind"], seed=seed,
@@ -170,10 +171,21 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
             device["busy_s"] = busy_seconds(result.trace)
             device["window_s"] = result.trace.window_s
             line["breakdown"] = breakdown(result.trace)
+            # where the time sits by the program's own scopes, and the
+            # three largest operations split the same way: an op kind
+            # and a shape alone do not say whose work a fusion is
+            say(scope_ms=json.dumps(_rounded(scope_ms(result.trace))))
+            say(scope_ms_of_top_ops=json.dumps({
+                name: _rounded(scope_ms(result.trace, top=4, op=name))
+                for name, _ in line["breakdown"]["device_ops"][:3]}))
         if result.facts.get("roofline_bound"):
             say(roofline_bound=json.dumps(result.facts["roofline_bound"]))
     line["device"] = device
     return line
+
+
+def _rounded(pairs):
+    return [[name, round(ms, 3)] for name, ms in pairs]
 
 
 def main(argv=None) -> int:

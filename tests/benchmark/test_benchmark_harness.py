@@ -18,10 +18,13 @@ import pytest
 
 from benchmarks import run as bench_run
 from benchmarks import stats, traffic
-from benchmarks.readers import engine_fact, idle, module_ms, op_ms, roofline
+from benchmarks.kinds import serve_open_loop
+from benchmarks.readers import (engine_fact, fact_ratio, idle, module_ms,
+                                op_ms, roofline, step_mfu)
 from benchmarks.rooflines import flash_attention, paged_decode
 from benchmarks.trace import (DeviceTrace, Event, breakdown, busy_seconds,
-                              make_trace, short_op_name)
+                              load_trace, make_trace, op_scopes, scope_ms,
+                              short_op_name, short_scope)
 
 ROOT = bench_run.ROOT
 BENCH = bench_run.load_json(ROOT, "BENCHMARK.json")
@@ -145,7 +148,8 @@ def test_peaks_table_holds_the_v5e_row_with_its_source():
 
 @pytest.mark.parametrize("cell,trace", [
     ("gpt2-345m.train", False), ("bert-large.train", True),
-    ("gpt2-345m.serve-chat", True), ("gpt2-345m.serve-chat-sat", False)])
+    ("gpt2-345m.serve-chat", True), ("gpt2-345m.serve-chat-sat", False),
+    ("gpt2-345m.serve-chat-sat", True)])
 def test_cell_runs_end_to_end_at_tiny_sizes(cell, trace, capsys):
     line = bench_run.run_cell(cell, 3000000019, 1.0, trace,
                               overrides=TINY[cell], require_tpu=False)
@@ -171,6 +175,21 @@ def test_cell_runs_end_to_end_at_tiny_sizes(cell, trace, capsys):
         assert "lateness_p50_ms=" in out and "requests_due=" in out
         due = int(re.search(r"requests_due=(\d+)", out).group(1))
         assert line["attempted"] == due == 6
+    if "serve" in cell and trace:
+        # counts of the engine's own state: a CPU run gives them rightly
+        grid = json.loads(re.search(r"decode_grid=(\{.*?\})", out).group(1))
+        assert 0 < grid["ticks"] <= grid["rows"] <= grid["grid_rows"]
+        assert grid["grid_rows"] == 4 * grid["ticks"]       # one rung: 4
+        assert 0 < grid["live_pages"] <= grid["grid_pages"]
+        assert grid["grid_pages"] == 8 * grid["grid_rows"]  # one rung: 8
+        fills = {name: m["value"] for name, m in line["metrics"].items()
+                 if "_fill_pct." in name}
+        assert set(fills) == {name for name in declared("per_layer", cell)
+                              if "_fill_pct." in name}
+        assert all(0 < v <= 100 for v in fills.values())
+        suffix = cell.rsplit("-", 1)[-1]                    # chat or sat
+        assert fills[f"page_fill_pct.{suffix}"] == pytest.approx(
+            100 * grid["live_pages"] / grid["grid_pages"])
 
 
 def test_a_request_the_engine_refuses_counts_as_failed(capsys):
@@ -205,11 +224,19 @@ PREFILL = "%step.9 = bf16[1,64,128]{2,1,0} custom-call(bf16[1,64,128] %q)"
 FUSION = "%fusion.2 = f32[8,8]{1,0} fusion(f32[8,8]{1,0} %a), kind=kLoop"
 
 
-def hand_trace():
+def events(rows):
+    """``[[name, start_ms, dur_ms(, scope)], ...]``, the form a
+    metric's ``example`` gives its events in."""
+    return [Event(r[0], r[1] * 1e-3, r[2] * 1e-3, *r[3:]) for r in rows]
+
+
+def hand_trace(example=None):
     """Two train steps of 10 ms (8 + 1 ms of ops, the attention op 4 ms
     of each), then a 6 ms decode tick (kernel 3 ms) and a 2 ms prefill,
-    under host spans that cover 0-40 ms."""
+    under host spans that cover 0-40 ms; with ``example`` (the key of a
+    ``layer_metrics`` file), its events besides."""
     ms = 1e-3
+    example = example or {}
     modules = [Event("jit__step(1)", 0 * ms, 10 * ms),
                Event("jit__step(1)", 12 * ms, 10 * ms),
                Event("jit_step(2)", 24 * ms, 6 * ms),
@@ -223,7 +250,47 @@ def hand_trace():
     spans = [Event("bench.step", 0.0, 1 * ms),
              Event("bench.loss_read", 1 * ms, 21 * ms),
              Event("bench.engine_step", 22.5 * ms, 17.5 * ms)]
-    return make_trace([DeviceTrace(modules, ops)], spans)
+    return make_trace(
+        [DeviceTrace(modules + events(example.get("modules", [])),
+                     ops + events(example.get("ops", [])))],
+        spans + events(example.get("spans", [])),
+        events(example.get("program_spans", [])))
+
+
+# what the kinds would have recorded beside the hand trace
+HAND_FACTS = {
+    "attention_shapes": dict(batch=2, seq=64, heads=2, head_dim=64,
+                             layers=2, causal=False),
+    "decode_shapes": dict(live_pages=10, live_tokens=70, rows=4,
+                          block_size=8, heads=2, head_dim=64, layers=2),
+    "queue_wait_p90_ms": 3.0, "ttft_p90_ms": 200.0}
+
+# PR 24's metrics carry no example: the shared hand trace and facts are
+# theirs, and each has to go on reading them
+NO_EXAMPLE = {
+    "device_idle_pct.train", "step_device_ms.train",
+    "attn_kernel_ms.train", "attn_roofline.train", "ttft_p90_ms.chat",
+    "queue_wait_p90_ms.chat", "decode_tick_ms.chat",
+    "decode_kernel_ms.chat", "decode_hbm_roofline.chat",
+    "device_idle_pct.chat", "decode_tick_ms.sat", "device_idle_pct.sat"}
+
+
+def read_declared(spec):
+    """One per-layer metric through its own reader, on its own: over the
+    shared hand trace and facts plus what its ``example`` adds.  The
+    reading has to be there, positive and, where the example says what
+    it comes to, that."""
+    example = spec.get("example", {})
+    value = bench_run.resolve(spec["reader"])(
+        hand_trace(example), {**HAND_FACTS, **example.get("facts", {})},
+        spec["params"], PEAKS)
+    assert value is not None and value > 0, \
+        f"{spec['name']} reads nothing: give layer_metrics/" \
+        f"{spec['name']}.json an `example` that it reads"
+    if "value" in example:
+        assert value == pytest.approx(example["value"], rel=1e-6), \
+            spec["name"]
+    return value
 
 
 def spec_params(name):
@@ -279,15 +346,49 @@ def test_roofline_reader_against_a_hand_count():
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_traced_run_returns_every_layer_metric_the_cell_declares(cell):
-    facts = {"attention_shapes": dict(batch=2, seq=64, heads=2,
-                                      head_dim=64, layers=2, causal=False),
-             "decode_shapes": dict(live_pages=10, live_tokens=70, rows=4,
-                                   block_size=8, heads=2, head_dim=64,
-                                   layers=2),
-             "queue_wait_p90_ms": 3.0, "ttft_p90_ms": 200.0}
-    got = bench_run.layer_metrics(BENCH, cell, hand_trace(), facts, PEAKS)
+    for name in sorted(declared("per_layer", cell)):
+        spec = bench_run.load_json(bench_run.HERE, "layer_metrics",
+                                   name + ".json")
+        assert "example" not in spec or name not in NO_EXAMPLE
+        read_declared(spec)
+    # and all at once through the harness, as a traced run reads them
+    examples = [bench_run.load_json(bench_run.HERE, "layer_metrics",
+                                    name + ".json").get("example", {})
+                for name in sorted(declared("per_layer", cell))]
+    facts = dict(HAND_FACTS)
+    for example in examples:
+        facts.update(example.get("facts", {}))
+    merged = {key: [row for example in examples
+                    for row in example.get(key, [])]
+              for key in ("modules", "ops", "spans", "program_spans")}
+    got = bench_run.layer_metrics(BENCH, cell, hand_trace(merged), facts,
+                                  PEAKS)
     assert set(got) == declared("per_layer", cell)
     assert all(v["value"] > 0 for v in got.values())
+
+
+def test_a_metric_with_an_example_reads_its_value_and_one_without_fails():
+    """What a later PR relies on: its metric's file brings the events
+    the metric reads, and no file that is here is edited for it."""
+    spec = {"name": "optimizer_ms.train",
+            "reader": "benchmarks.readers.op_ms:read",
+            "params": {"module_pattern": "^jit__step\\(",
+                       "scope_pattern": "/apex\\.optimizer/"}}
+    with pytest.raises(AssertionError, match="reads nothing"):
+        read_declared(spec)
+    sweep = ["%fusion.9 = bf16[4096]{0} fusion(bf16[4096]{0} %p)", 8.5,
+             0.25, "jit(_step)/apex.optimizer/mul"]
+    spec["example"] = {"ops": [sweep], "value": 0.125}    # over two runs
+    assert read_declared(spec) == pytest.approx(0.125)
+    spec["example"]["value"] = 0.25
+    with pytest.raises(AssertionError):
+        read_declared(spec)
+    # an example's facts stand beside the shared ones
+    spec = {"name": "fill", "reader": "benchmarks.readers.fact_ratio:read",
+            "params": {"fact": "grid", "num": "live", "den": "slots"},
+            "example": {"facts": {"grid": {"live": 3, "slots": 12}},
+                        "value": 25.0}}
+    assert read_declared(spec) == 25.0
 
 
 def test_breakdown_names_ops_and_idle_gaps():
@@ -301,6 +402,263 @@ def test_breakdown_names_ops_and_idle_gaps():
     assert b["idle_gaps"][0][0] == "bench.engine_step"
     assert b["idle_gaps"][0][1] == pytest.approx(6e-3)
     assert ["bench.loss_read", pytest.approx(2e-3)] in b["idle_gaps"]
+
+
+def test_attention_pattern_reads_the_kernels_under_either_name():
+    """Today the kernels are named after their flax scope
+    (``%self_attention.N``); a PR that gives the ``pallas_call`` a
+    ``name=`` may call them ``flash_attention_*``.  ``%flash_fwd.1``
+    (the name such a call gets from ``name="flash_fwd"``) reads
+    nothing."""
+    renamed = ["%flash_attention_bwd.3 = bf16[2,64,384]{2,1,0} "
+               "custom-call(bf16[2,64,384]{2,1,0} %x)", 8.25, 0.5]
+    unknown = ["%flash_fwd.1 = bf16[2,64,384]{2,1,0} custom-call("
+               "bf16[2,64,384]{2,1,0} %x)", 8.75, 0.125]
+    t = hand_trace({"ops": [renamed, unknown]})
+    assert op_ms.read(t, {}, spec_params("attn_kernel_ms.train"),
+                      PEAKS) == pytest.approx(4.25)         # (4+4+.5)/2
+    assert spec_params("attn_roofline.train")["op_pattern"] == \
+        spec_params("attn_kernel_ms.train")["op_pattern"]
+
+
+def test_fact_ratio_reader_on_hand_sums():
+    params = {"fact": "decode_grid", "num": "rows", "den": "grid_rows"}
+    grid = {"ticks": 3, "rows": 9, "grid_rows": 12, "live_pages": 20,
+            "grid_pages": 96}
+    assert fact_ratio.read(None, {"decode_grid": grid}, params,
+                           PEAKS) == pytest.approx(75.0)
+    assert fact_ratio.read(None, {"decode_grid": grid},
+                           spec_params("page_fill_pct.chat"),
+                           PEAKS) == pytest.approx(100 * 20 / 96)
+    assert fact_ratio.read(None, {}, params, PEAKS) is None
+    assert fact_ratio.read(None, {"decode_grid": None}, params,
+                           PEAKS) is None
+    assert fact_ratio.read(None, {"decode_grid": dict(grid, grid_rows=0)},
+                           params, PEAKS) is None
+
+
+def test_step_mfu_reader_takes_the_period_between_runs_in_the_window():
+    """Two train steps start 12 ms apart (10 ms busy each): the share is
+    of the period, idle gap and all, and of the device's clock."""
+    params = spec_params("mfu_pct.train")
+    flops = 0.012 * 197e12 / 4                               # a quarter
+    assert step_mfu.read(hand_trace(), {"step_flops": flops}, params,
+                         PEAKS) == pytest.approx(25.0)
+    # a run that started before the window (the profiler's late first
+    # step) and a stall after it do not enter
+    early = hand_trace({"modules": [["jit__step(1)", -30, 10.0]],
+                        "spans": [["bench.step", 60, 1.0]]})
+    assert early.window[0] == 0.0
+    assert step_mfu.read(early, {"step_flops": flops}, params,
+                         PEAKS) == pytest.approx(25.0)
+    third = hand_trace({"modules": [["jit__step(1)", 36, 10.0]]})
+    assert step_mfu.read(third, {"step_flops": flops}, params,
+                         PEAKS) == pytest.approx(100 * 12 / 18 / 4)
+    assert step_mfu.read(hand_trace(), {}, params, PEAKS) is None
+    assert step_mfu.read(None, {"step_flops": flops}, params, PEAKS) is None
+    one = make_trace([DeviceTrace([Event("jit__step(1)", 0.0, 0.01)], [])],
+                     [])
+    assert step_mfu.read(one, {"step_flops": flops}, params, PEAKS) is None
+
+
+def test_decode_grid_is_held_to_the_ticks_the_device_trace_holds():
+    """The engine's ``decode_step`` events give the rows and rungs; the
+    trace's decode calls (output ``bf16[bb,...]``, block table
+    ``s32[bb,pb]``) have to say the same."""
+    second = [["jit_step(2)", 60, 6.0]], [
+        [DECODE.replace("step.3", "step.4"), 60, 1.0],
+        [DECODE.replace("step.3", "step.5"), 62, 1.0]]    # two layers
+    t = hand_trace({"modules": second[0], "ops": second[1]})
+    assert serve_open_loop.traced_grid(t) == dict(
+        traced_ticks=2, traced_grid_rows=8, traced_grid_pages=64)
+    log = serve_open_loop.TickLog()
+    log.event("serving", "admit", value=1, rid="a")
+    log.event("serving", "decode_step", value=6.0, step=1, batch=3,
+              batch_bucket=4, pages_bucket=8)
+    assert log.ticks == [dict(step=1, batch=3, batch_bucket=4,
+                              pages_bucket=8)]
+    grid = dict(ticks=2, rows=5, grid_rows=8, live_pages=9, grid_pages=64)
+    faults = []
+    serve_open_loop.hold_grid_to_trace(faults, grid, t)
+    assert faults == []
+    for key in ("ticks", "grid_rows", "grid_pages"):
+        faults = []
+        serve_open_loop.hold_grid_to_trace(
+            faults, dict(grid, **{key: grid[key] + 1}), t)
+        assert len(faults) == 1 and key in faults[0]
+    faults = []
+    serve_open_loop.hold_grid_to_trace(faults, None, t)
+    assert len(faults) == 3
+    # a decode call that does not show its block table: rows alone
+    bare = hand_trace({"modules": second[0], "ops": [
+        ["%step.4 = bf16[4,1,1,128]{3,2,1,0} custom-call(%tables)", 60,
+         1.0]]})
+    assert serve_open_loop.traced_grid(bare)["traced_grid_pages"] is None
+    faults = []
+    serve_open_loop.hold_grid_to_trace(faults, dict(grid, grid_pages=1),
+                                       bare)
+    assert faults == []
+    assert serve_open_loop.traced_grid(make_trace([], [])) == dict(
+        traced_ticks=0, traced_grid_rows=0, traced_grid_pages=0)
+
+
+def test_cache_layout_pattern_counts_cache_shaped_ops_alone():
+    """Anchored on the cache's trailing dims: a fusion that makes the
+    decode kernel's 4-dim ``q`` is not cache layout."""
+    spec = bench_run.load_json(bench_run.HERE, "layer_metrics",
+                               "cache_layout_ms.chat.json")
+    pattern = spec["params"]["op_pattern"]
+    for hlo in ("%copy.203 = bf16[24,2049,8,16,128]{4,3,2,1,0:T(8,128)(2,1)}"
+                " copy(bf16[24,2049,8,16,128]{4,2,3,1,0} %fusion.49)",
+                "%slice.290 = bf16[1,2049,8,16,128]{4,2,3,1,0:T(8,128)(2,1)"
+                "S(1)} slice(bf16[24,2049,8,16,128]{4,2,3,1,0} %fusion.47)",
+                "%copy_bitcast_fusion.2 = bf16[2049,8,16,128]{3,2,1,0} "
+                "fusion(bf16[1,2049,8,16,128]{4,2,3,1,0} %slice.288)"):
+        assert re.search(pattern, hlo), hlo
+    for hlo in ("%fusion.77 = bf16[32,8,1,128]{3,2,1,0} fusion(bf16[32,16,64]"
+                "{2,1,0} %q), kind=kLoop",
+                "%step.116 = bf16[32,8,1,128]{3,2,1,0} custom-call(s32[32,64]"
+                "{1,0} %t, bf16[2049,8,16,128]{3,2,1,0} %copy_bitcast)",
+                "%fusion.5 = bf16[32,1024]{1,0} fusion(bf16[2049,8,16,128]"
+                "{3,2,1,0} %x), kind=kLoop"):
+        assert not re.search(pattern, hlo), hlo
+
+
+def test_scope_pattern_is_anded_with_the_op_pattern():
+    inside = "jit(_step)/apex.optimizer/mul"
+    head = "jit(_step)/transpose(jvp(GPT))/GPT/transformer/layer_3/mlp/dot"
+    t = hand_trace({"ops": [
+        [FUSION.replace("fusion.2", "fusion.5"), 8.0, 0.5, inside],
+        [FUSION.replace("fusion.2", "fusion.6"), 20.0, 0.25, inside],
+        [ATTN.replace(".7", ".8"), 20.25, 0.125, inside],
+        [FUSION.replace("fusion.2", "fusion.7"), 20.5, 0.25, head]]})
+    params = {"module_pattern": "^jit__step\\(", "op_pattern": " fusion\\(",
+              "scope_pattern": "/apex\\.optimizer/"}
+    assert op_ms.read(t, {}, params, PEAKS) == pytest.approx(0.375)
+    # no op_pattern: every op of the scope
+    del params["op_pattern"]
+    assert op_ms.read(t, {}, params, PEAKS) == pytest.approx(0.4375)
+    # absent, the readers behave as before
+    assert op_ms.read(t, {}, {"module_pattern": "^jit__step\\(",
+                              "op_pattern": " fusion\\("},
+                      PEAKS) == pytest.approx((12 + 1.0) / 2)
+    # module_ms: runs that hold an op of the pattern and of the scope
+    assert module_ms.read(t, {}, {"module_pattern": "^jit_",
+                                  "contains_op": " custom-call\\(",
+                                  "scope_pattern": "apex"},
+                          PEAKS) == pytest.approx(10.0)
+    shapes = dict(batch=2, seq=64, heads=2, head_dim=64, layers=2,
+                  causal=True)
+    scoped = dict(spec_params("attn_roofline.train"),
+                  scope_pattern="apex")
+    got = roofline.read(t, {"attention_shapes": shapes}, scoped, PEAKS)
+    flops, nbytes = flash_attention.train_step(**shapes)
+    assert got == pytest.approx(
+        100 * 2 * max(flops / 197e12, nbytes / 819e9) / 0.125e-3)
+
+
+def test_scope_ms_adds_every_layer_up_under_one_name():
+    assert short_scope("jit(_step)/jvp(GPT)/GPT.h/transformer/layer_7/mlp/"
+                       "dense_h_to_4h/dot_general") == \
+        "jvp(GPT)/GPT.h/transformer/layer_N/mlp/dense_h_to_4h"
+    assert short_scope("jit(step)/pallas_call") == "pallas_call"
+    assert short_scope("cache.k") == "cache.k"
+    assert short_scope("") == "(none)"
+    mlp = "jit(_step)/jvp(GPT)/transformer/layer_%d/mlp/dot_general"
+    t = hand_trace({"ops": [
+        [FUSION.replace("fusion.2", "fusion.5"), 5.0, 2.0, mlp % 0],
+        [FUSION.replace("fusion.2", "fusion.6"), 16.0, 1.0, mlp % 11]]})
+    got = scope_ms(t)
+    # per run of the busiest program (jit__step: two runs)
+    assert got[0] == ("(none)", pytest.approx(28.0 / 2))
+    assert got[1] == ("jvp(GPT)/transformer/layer_N/mlp",
+                      pytest.approx(3.0 / 2))
+    assert scope_ms(t, op="fusion f32[8,8]")[0] == \
+        ("(none)", pytest.approx(15.0 / 2))
+    assert scope_ms(make_trace([], [])) == []
+
+
+# --- the trace file itself ---------------------------------------------------------
+
+def _message(*fields):
+    """A protobuf message from ``(number, int | bytes)`` fields (ints
+    under 128 only: one-byte varints)."""
+    out = b""
+    for number, value in fields:
+        if isinstance(value, int):
+            out += bytes([number << 3, value])
+        else:
+            assert len(value) < 128 * 128
+            size = [len(value)] if len(value) < 128 \
+                else [len(value) & 0x7F | 0x80, len(value) >> 7]
+            out += bytes([number << 3 | 2, *size]) + value
+    return out
+
+
+def test_op_scopes_reads_the_event_metadata_of_device_planes(tmp_path):
+    def plane(name, ops):
+        stat_names = {1: b"hlo_category", 2: b"tf_op"}
+        return _message(
+            (2, name),
+            *[(5, _message((1, k), (2, _message((1, k), (2, v)))))
+              for k, v in stat_names.items()],
+            *[(4, _message((1, i), (2, _message(
+                (1, i), (2, hlo),
+                (5, _message((1, 1), (5, b"fusion"))),
+                *([(5, _message((1, 2), (5, scope)))] if scope else [])))))
+              for i, (hlo, scope) in enumerate(ops, 1)])
+    long = ("%fusion.9 = bf16[4096]{0} fusion(" + "x" * 200 + ")").encode()
+    keyed_only = _message((1, 77))      # map entries with no value
+    space = _message(
+        (1, plane(b"/device:TPU:0", [
+            (FUSION.encode(), b"jit(_step)/apex.optimizer/mul:"),
+            (long, b"jit(_step)/reduce_sum:"),
+            (ATTN.encode(), b"")]) + _message((4, keyed_only),
+                                              (5, keyed_only))),
+        (1, plane(b"/host:CPU", [(b"bench.step", b"not/an/op:")])))
+    path = tmp_path / "hand.xplane.pb"
+    path.write_bytes(space)
+    assert op_scopes(str(path)) == {
+        FUSION: "jit(_step)/apex.optimizer/mul",
+        long.decode(): "jit(_step)/reduce_sum"}
+
+
+def test_load_trace_keeps_the_programs_spans_apart_from_the_window(tmp_path):
+    """A CPU trace made here: an ``apex.*`` annotation (the program's)
+    nested in a ``bench.*`` one (the benchmark's)."""
+    import jax
+    import jax.numpy as jnp
+
+    jax.profiler.start_trace(str(tmp_path))
+    with jax.profiler.TraceAnnotation("bench.probe"):
+        with jax.profiler.TraceAnnotation("apex.probe"):
+            jnp.ones((8, 8)).sum().block_until_ready()
+        with jax.profiler.TraceAnnotation("other.probe"):
+            pass
+    jax.profiler.stop_trace()
+    t = load_trace(str(tmp_path))
+    assert [s.name for s in t.spans] == ["bench.probe"]
+    assert [s.name for s in t.program_spans] == ["apex.probe"]
+    outer, inner = t.spans[0], t.program_spans[0]
+    assert outer.start <= inner.start and inner.end <= outer.end
+    assert inner.dur > 0
+    assert t.window == (outer.start, outer.end)
+    assert t.devices == []                    # no TPU plane on the CPU
+    assert load_trace(str(tmp_path / "absent")) is None
+
+
+def test_idle_gaps_are_named_by_the_innermost_span_of_either_prefix():
+    fetch = ["apex.serve.decode.fetch", 34.0, 5.5]
+    early = ["apex.serve.schedule", 22.5, 1.0]    # over before the gap
+    b = breakdown(hand_trace({"program_spans": [fetch, early]}))
+    assert b["idle_gaps"][0] == ["apex.serve.decode.fetch",
+                                 pytest.approx(6e-3)]
+    # 22-24 ms: its middle, 23 ms, lies in both; the inner one names it
+    assert ["apex.serve.schedule", pytest.approx(2e-3)] in b["idle_gaps"]
+    assert ["bench.loss_read", pytest.approx(2e-3)] in b["idle_gaps"]
+    t = hand_trace({"program_spans": [fetch]})
+    assert t.window == hand_trace().window
+    assert idle.read(t, {}, {}, PEAKS) == pytest.approx(100 * 14 / 40)
 
 
 # --- the count functions against hand counts ---------------------------------------
