@@ -219,10 +219,21 @@ class AmpOptimizer:
         # Dispatch on the STATE's layout, not the constructor flag:
         # the auto pipeline decision is per-tree (init() applies the
         # packed-size cutoff), and a checkpoint-restored state must
-        # step the way it was built.
-        if isinstance(state.master_params, _pipeline.PackedMasters):
-            return self._apply_gradients_pipeline(
-                scaled_grads, state, params, loss_id, axis_names)
+        # step the way it was built.  Either way every op of the
+        # post-backward step carries the scope ``apex.optimizer`` in
+        # its name (metadata only: the compiled program is the same),
+        # which is how a device trace gives the optimizer's time.
+        packed = isinstance(state.master_params, _pipeline.PackedMasters)
+        apply = (self._apply_gradients_pipeline if packed
+                 else self._apply_gradients_per_leaf)
+        with jax.named_scope("apex.optimizer"):
+            return apply(scaled_grads, state, params, loss_id,
+                         axis_names)
+
+    def _apply_gradients_per_leaf(self, scaled_grads, state, params,
+                                  loss_id, axis_names):
+        """The per-stage post-backward step: unscale, finite check,
+        conditional update, master -> model cast."""
         scaler = state.scalers[loss_id]
         fused_capable = getattr(self.tx, "fused_step", None) is not None
         # Single-pass optimizers upcast per-leaf inside their update

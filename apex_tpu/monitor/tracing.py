@@ -7,8 +7,17 @@ the device — ROADMAP item 2's 84 TF/s-device / 33 TF/s-wall gap was a
 single opaque number.  This module is the instrument for that surgery,
 in four pieces:
 
-* :class:`SpanTracer` — near-zero-overhead ``span("name")`` context
-  manager / decorator with thread-and-process-aware monotonic timing.
+* :func:`span` — the program's ONE host-span primitive, called where
+  the work happens (``with span("apex.serve.decode.fetch"):``).  It
+  always opens a ``jax.profiler.TraceAnnotation``: inert unless a
+  profiler session is on, and then an event of the same ``.xplane.pb``
+  as the device plane, so a host phase and the device's ops share one
+  clock and an idle gap of the device can be put down to what the host
+  was doing.  Names are ``apex.<layer>.<phase>``
+  (docs/api/observability.md has the table).
+* :class:`SpanTracer` — what :func:`span` also records into when one
+  is installed (:func:`set_tracer`, :class:`TraceSession`):
+  thread-and-process-aware monotonic timing on the host's own clock.
   Spans drain as ``span`` events into the existing crash-safe JSONL
   sinks and export as Chrome trace-event JSON
   (:meth:`SpanTracer.chrome_trace`), so host spans load into Perfetto
@@ -18,7 +27,8 @@ in four pieces:
 * :class:`StepWaterfall` — per-step wall attribution over the
   canonical components ``data_load`` / ``dispatch`` /
   ``device_compute`` (the async-dispatch ``block_until_ready``
-  boundary) / ``telemetry_drain`` / ``ckpt_io`` plus the ``other``
+  boundary: host time spent WAITING on the device, not device busy
+  time) / ``telemetry_drain`` / ``ckpt_io`` plus the ``other``
   residual, emitted per step as one ``attr`` event with
   ``wall_ms = Σ parts`` and ``wall_device_ratio`` — ROADMAP item 2's
   exit criterion ("wall/device > 0.9") as a per-step number.
@@ -54,6 +64,8 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, NamedTuple, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from ..analysis.flags import flag_float, flag_int, flag_str
 from ..utils.log_util import get_logger
 from .events import Event, Sink, terminal_reason
@@ -62,6 +74,7 @@ logger = get_logger(__name__)
 
 __all__ = [
     "Span", "SpanTracer", "get_tracer", "set_tracer", "span",
+    "recording",
     "StepWaterfall", "WATERFALL_PARTS",
     "DeviceMetricsBuffer", "MetricsBufferState", "DeferredTelemetry",
     "CaptureTrigger", "TraceSession",
@@ -132,16 +145,9 @@ class _SpanHandle(contextlib.ContextDecorator):
                           attrs=self._attrs)
         return False
 
-
-class _NullSpan(contextlib.ContextDecorator):
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
+    def set(self, **attrs) -> None:
+        """Add attributes known only inside the span."""
+        self._attrs.update(attrs)
 
 
 class SpanTracer:
@@ -297,15 +303,66 @@ def set_tracer(tracer: Optional[SpanTracer]) -> None:
     _GLOBAL_TRACER = tracer
 
 
+class _ProfilerSpan(TraceAnnotation):
+    """What :func:`span` returns with no tracer installed: the bare
+    profiler annotation (two calls into jaxlib, no Python frame)."""
+
+    __slots__ = ()
+    set = TraceAnnotation.set_metadata
+
+
+class _TracedSpan(contextlib.ContextDecorator):
+    """What :func:`span` returns with a tracer installed: the profiler
+    annotation and the tracer's record of the same occurrence (a new
+    pair on each entry, so the decorator form works)."""
+
+    def __init__(self, tracer: SpanTracer, name: str,
+                 attrs: Dict[str, Any]):
+        self._tracer = tracer
+        self._name = name
+        self._attrs = attrs
+
+    def __enter__(self):
+        self._annotation = TraceAnnotation(self._name, **self._attrs)
+        self._record = self._tracer.span(self._name, **self._attrs)
+        self._annotation.__enter__()
+        self._record.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._record.__exit__(*exc)
+        self._annotation.__exit__(*exc)
+        return False
+
+    def set(self, **attrs) -> None:
+        self._annotation.set_metadata(**attrs)
+        self._record.set(**attrs)
+
+
 def span(name: str, **attrs):
-    """Module-level ``with span("name"):`` against the process-wide
-    tracer — a no-op (shared null handle, zero allocation) when no
-    tracer is installed, so library code can instrument
-    unconditionally."""
+    """``with span("apex.<layer>.<phase>") as s:`` — the program's one
+    host-span primitive, so library code instruments unconditionally.
+
+    It always opens a ``jax.profiler.TraceAnnotation(name, **attrs)``:
+    inert unless a profiler session is on, and then an event on the
+    device trace's own clock, with ``attrs`` as its stats.  When a
+    :class:`SpanTracer` is installed the same occurrence is also
+    recorded there (JSONL ``span`` events, Chrome export).
+    ``s.set(**attrs)`` adds attributes known only inside the span, to
+    both; gather them under :func:`recording` so that a run with
+    tracing off builds nothing.  Each call allocates one small object
+    (an annotation enter/exit is about half a microsecond with the
+    profiler off)."""
     t = _GLOBAL_TRACER
     if t is None:
-        return _NULL_SPAN
-    return t.span(name, **attrs)
+        return _ProfilerSpan(name, **attrs)
+    return _TracedSpan(t, name, attrs)
+
+
+def recording() -> bool:
+    """Whether a :func:`span` opened now lands anywhere: a profiler
+    session is on, or a tracer is installed."""
+    return _GLOBAL_TRACER is not None or TraceAnnotation.is_enabled()
 
 
 def _chrome_json(events: List[dict], *, pid: int,
@@ -751,7 +808,8 @@ class StepWaterfall:
                 yield
             return
         span_ctx = (self._tracer.span(name, step=self._step)
-                    if self._tracer is not None else _NULL_SPAN)
+                    if self._tracer is not None
+                    else contextlib.nullcontext())
         t0 = self._clock()
         try:
             with span_ctx:

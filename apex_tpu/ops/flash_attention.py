@@ -622,6 +622,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_mask=None,
                 jax.ShapeDtypeStruct((bh, psq, d), q.dtype),
                 jax.ShapeDtypeStruct((bh, 1, 8 * g, bq), jnp.float32),
             ],
+            name="flash_attention_fwd",
             interpret=_interpret(),
         )(*operands)
         return _unpack(o, lse8)
@@ -663,6 +664,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_mask=None,
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
+        name="flash_attention_fwd",
         interpret=_interpret(),
     )(*operands)
     return _unpack(o, lse8)
@@ -718,6 +720,7 @@ def _flash_fwd_packed(qkv, b, h, scale, causal, block_q, block_k,
                 jax.ShapeDtypeStruct((bh, ps, d), qkv.dtype),
                 jax.ShapeDtypeStruct((bh, 1, 8, bq), jnp.float32),
             ],
+            name="flash_attention_fwd",
             interpret=_interpret(),
         )(*operands)
         lse = lse8[:, :, 0, :].reshape(bh, ps)[:, :s]
@@ -756,6 +759,7 @@ def _flash_fwd_packed(qkv, b, h, scale, causal, block_q, block_k,
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
+        name="flash_attention_fwd",
         interpret=_interpret(),
     )(*operands)
     lse = lse8[:, :, 0, :].reshape(bh, ps)[:, :s]
@@ -1124,6 +1128,7 @@ def _flash_bwd(scale, causal, block_q, block_k, res, do, kv_mask=None,
             out_shape=[jax.ShapeDtypeStruct((bh, psq, d), q.dtype),
                        jax.ShapeDtypeStruct((bh, psk, d), k.dtype),
                        jax.ShapeDtypeStruct((bh, psk, d), v.dtype)],
+            name="flash_attention_bwd",
             interpret=_interpret(),
         )(*operands)
         return _unpack_grads(dq, dk, dv)
@@ -1160,6 +1165,7 @@ def _flash_bwd(scale, causal, block_q, block_k, res, do, kv_mask=None,
         out_specs=q_spec_i,
         out_shape=jax.ShapeDtypeStruct((bh, psq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        name="flash_attention_bwd_dq",
         interpret=_interpret(),
     )(*operands)
 
@@ -1196,6 +1202,7 @@ def _flash_bwd(scale, causal, block_q, block_k, res, do, kv_mask=None,
                    jax.ShapeDtypeStruct((bh, psk, d), v.dtype)],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
+        name="flash_attention_bwd_dkv",
         interpret=_interpret(),
     )(*operands)
 
@@ -1260,6 +1267,7 @@ def _flash_bwd_packed(scale, causal, block_q, block_k, res, do,
             in_specs=in_specs,
             out_specs=[ob_spec, ob_spec, ob_spec],
             out_shape=[jax.ShapeDtypeStruct((bh, ps, d), qkv.dtype)] * 3,
+            name="flash_attention_bwd",
             interpret=_interpret(),
         )(*operands)
         return jnp.concatenate([dq[:, :s], dk[:, :s], dv[:, :s]],
@@ -1294,6 +1302,7 @@ def _flash_bwd_packed(scale, causal, block_q, block_k, res, do,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((bh, ps, d), qkv.dtype),
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        name="flash_attention_bwd_dq",
         interpret=_interpret(),
     )(*operands)
 
@@ -1329,6 +1338,7 @@ def _flash_bwd_packed(scale, causal, block_q, block_k, res, do,
         out_shape=[jax.ShapeDtypeStruct((bh, ps, d), qkv.dtype)] * 2,
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
+        name="flash_attention_bwd_dkv",
         interpret=_interpret(),
     )(*operands)
 
@@ -1971,6 +1981,7 @@ def _flash_fwd_e(qkv_e, h, scale, causal, kv_mask=None, drop=0.0,
             jax.ShapeDtypeStruct((b, ps, h * d), qkv_e.dtype),
             jax.ShapeDtypeStruct((b, h, 8, ps), jnp.float32),
         ],
+        name="flash_attention_fwd",
         interpret=_interpret(),
     )(*operands)
     lse = lse8[:, :, 0, :s]                # (b, h, s)
@@ -2137,6 +2148,7 @@ def _flash_fwd_e_blocked(qkv_e, h, scale, causal, kv_mask=None,
             pltpu.VMEM((bs, 128), jnp.float32),
             pltpu.VMEM((bs, 128), jnp.float32),
         ],
+        name="flash_attention_fwd",
         interpret=_interpret(),
     )(*operands)
     lse = lse8[:, :, 0, :s]                # (b, h, s)
@@ -2256,6 +2268,7 @@ def _flash_bwd_e(h, scale, causal, res, do, kv_mask=None, drop=0.0,
         in_specs=in_specs,
         out_specs=qkv_spec,
         out_shape=jax.ShapeDtypeStruct((b, ps, width), qkv3.dtype),
+        name="flash_attention_bwd",
         interpret=_interpret(),
     )(*operands)
     return dqkv[:, :s]
@@ -2482,6 +2495,7 @@ def _flash_bwd_e_blocked(h, scale, causal, res, do, kv_mask=None,
             pltpu.VMEM((bs, hg * d), jnp.float32),
             pltpu.VMEM((bs, hg * d), jnp.float32),
         ],
+        name="flash_attention_bwd",
         interpret=_interpret(),
     )(*operands)
     return dqkv[:, :s]

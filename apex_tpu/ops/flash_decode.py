@@ -253,6 +253,7 @@ def _decode_paged(q3, k_cache, v_cache, block_tables, seq_lens, scale,
         functools.partial(_decode_kernel, a, bs, pack, has_scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hk, 1, dk), q3.dtype),
+        name="paged_flash_decode",
         interpret=_interpret(),
     )(block_tables, seq_lens, *operands)[:, :, 0, :]
 
@@ -420,6 +421,7 @@ def _decode_paged_multi(q4, k_cache, v_cache, block_tables, seq_lens,
                           has_scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hk, t, dk), q4.dtype),
+        name="paged_flash_decode_multi",
         interpret=_interpret(),
     )(block_tables, seq_lens, *operands)
 

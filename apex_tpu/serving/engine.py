@@ -49,6 +49,7 @@ import numpy as np
 
 from ..analysis.flags import flag_bool, flag_float, flag_int, flag_str
 from ..monitor.export import MetricsRegistry
+from ..monitor.tracing import recording, span
 from ..utils.log_util import get_logger
 from .kv_cache import (DUMP_BLOCK, KVCacheConfig, KVCacheManager,
                        PrefixMatch, init_cache)
@@ -67,6 +68,11 @@ __all__ = ["Request", "BucketLadder", "ServingEngine", "ServeSummary",
 # per-token latency samples kept for the p50/p99 window (a lifetime
 # list would grow without bound on a long-running serve)
 _LATENCY_WINDOW = 100_000
+
+# the gauges of ``_tick_tail`` that ride on the ``apex.serve.step`` span
+# as its metadata (with ``admitted``), set only while a trace records
+_STEP_SPAN_COUNTERS = ("batch", "batch_bucket", "pages_bucket",
+                       "queue_depth", "used_blocks", "pool_blocks")
 
 
 def _parse_ladder(raw: str) -> Tuple[int, ...]:
@@ -507,6 +513,7 @@ class ServingEngine:
         # bounded: a weeks-long serve must not grow host memory per
         # token — percentiles read the most recent window only
         self._latencies: deque = deque(maxlen=_LATENCY_WINDOW)
+        self._tick_levels: Optional[Dict[str, Any]] = None
         self._done_count = 0
         self._preempted_count = 0
         self._done_tokens = 0
@@ -886,18 +893,19 @@ class ServingEngine:
             bt = self.manager.block_table(req.rid, s_pad // bs)
             tokens = np.zeros(s_pad, np.int32)
             tokens[:p_len] = req.prompt
-            fn = self._prefill_fn(s_pad)
-            self.cache, next_token = fn(
-                self.weights, self.cache, jnp.asarray(tokens),
-                jnp.int32(p_len), jnp.asarray(bt))
-            if self.draft_cache is not None:
-                dfn = self._draft_prefill_fn(s_pad)
-                self.draft_cache, _ = dfn(
-                    self.draft_weights, self.draft_cache,
-                    jnp.asarray(tokens), jnp.int32(p_len),
-                    jnp.asarray(bt))
-            first = int(next_token)      # explicit host sync: the
-            # admission boundary needs the token to seed the decode
+            with span("apex.serve.prefill"):
+                fn = self._prefill_fn(s_pad)
+                self.cache, next_token = fn(
+                    self.weights, self.cache, jnp.asarray(tokens),
+                    jnp.int32(p_len), jnp.asarray(bt))
+                if self.draft_cache is not None:
+                    dfn = self._draft_prefill_fn(s_pad)
+                    self.draft_cache, _ = dfn(
+                        self.draft_weights, self.draft_cache,
+                        jnp.asarray(tokens), jnp.int32(p_len),
+                        jnp.asarray(bt))
+                first = int(next_token)      # explicit host sync: the
+                # admission boundary needs the token to seed the decode
             dt = self._clock() - t0
             req.out_tokens.append(first)
             req.token_latency_s.append(dt)
@@ -953,23 +961,24 @@ class ServingEngine:
             wo[ct - n + j] = p % bs
         sl = np.asarray([job.written + n], np.int32)
         t0 = self._clock()
-        fn = self._extend_fn(1, ct, pb)
-        self.cache, out = fn(
-            self.weights, self.cache, jnp.asarray(toks[None]),
-            jnp.asarray(bt[None]), jnp.asarray(sl),
-            jnp.asarray(wb[None]), jnp.asarray(wo[None]))
-        if self.draft_cache is not None:
-            dfn = self._draft_extend_fn(1, ct, pb)
-            self.draft_cache, _ = dfn(
-                self.draft_weights, self.draft_cache,
-                jnp.asarray(toks[None]), jnp.asarray(bt[None]),
-                jnp.asarray(sl), jnp.asarray(wb[None]),
-                jnp.asarray(wo[None]))
-        job.written += n
-        self.prefill_chunks += 1
-        done = job.written >= p_len
-        first = int(np.asarray(out)[0, -1]) if done else None
-        # ^ the only host sync: non-final chunks stay async
+        with span("apex.serve.prefill"):
+            fn = self._extend_fn(1, ct, pb)
+            self.cache, out = fn(
+                self.weights, self.cache, jnp.asarray(toks[None]),
+                jnp.asarray(bt[None]), jnp.asarray(sl),
+                jnp.asarray(wb[None]), jnp.asarray(wo[None]))
+            if self.draft_cache is not None:
+                dfn = self._draft_extend_fn(1, ct, pb)
+                self.draft_cache, _ = dfn(
+                    self.draft_weights, self.draft_cache,
+                    jnp.asarray(toks[None]), jnp.asarray(bt[None]),
+                    jnp.asarray(sl), jnp.asarray(wb[None]),
+                    jnp.asarray(wo[None]))
+            job.written += n
+            self.prefill_chunks += 1
+            done = job.written >= p_len
+            first = int(np.asarray(out)[0, -1]) if done else None
+            # ^ the only host sync: non-final chunks stay async
         dt = self._clock() - t0
         self._event("prefill_chunk", value=round(dt * 1e3, 3),
                     rid=str(req.rid), tokens=int(n),
@@ -1213,15 +1222,30 @@ class ServingEngine:
         bucketed decode step — speculative when ``speculate_k > 0`` —
         over every active request.  Returns the number of tokens
         generated this tick."""
-        self._poll_escalation()
-        # finished requests leave BEFORE deadline enforcement: a
-        # request whose last token arrived within its deadline must
-        # end terminal "finished" even when the next boundary lands
-        # past the deadline
-        for rid in [r for r, q in self.active.items() if q.done]:
-            self._finish(self.active[rid])
-        self._expire_deadlines()
-        shedding = self._apply_shedding()
+        with span("apex.serve.step") as tick:
+            self._tick_levels = None
+            gained, admitted = self._tick()
+            if self._tick_levels is not None and recording():
+                # the tick's counters ride on its span: an operator
+                # reads them in xprof on the step they belong to
+                tick.set(admitted=admitted,
+                         **{k: self._tick_levels[k]
+                            for k in _STEP_SPAN_COUNTERS})
+            return gained
+
+    def _tick(self) -> Tuple[int, int]:
+        """The body of :meth:`step` under its span: ``(tokens
+        generated, requests admitted)``."""
+        with span("apex.serve.schedule"):
+            self._poll_escalation()
+            # finished requests leave BEFORE deadline enforcement: a
+            # request whose last token arrived within its deadline must
+            # end terminal "finished" even when the next boundary lands
+            # past the deadline
+            for rid in [r for r, q in self.active.items() if q.done]:
+                self._finish(self.active[rid])
+            self._expire_deadlines()
+            shedding = self._apply_shedding()
         advanced_prefill = False
         if self.prefilling:
             # FIFO: the oldest admission's next chunk, exactly one
@@ -1240,22 +1264,26 @@ class ServingEngine:
             # landing on an idle tick defers to one it can affect.
             self._event("alloc_rejected", injected=True)
             admit = False
-        if admit:
-            while (self.queue
-                   and (len(self.active) + len(self.prefilling)
-                        < self.ladder.max_batch)):
-                # one match per admission attempt: the PrefixMatch
-                # feeds both the reservation check and the admission
-                # itself (hashing the prompt every tick for a blocked
-                # queue head would sit on the hot path for nothing)
-                req = self.queue[0]
-                prefix = self.manager.match_prefix(req.prompt)
-                if not self.manager.can_admit(
-                        len(req.prompt), req.max_new_tokens,
-                        reserved_blocks=self._reserved_blocks(),
-                        prefix=prefix):
-                    break
-                self._admit(self.queue.popleft(), prefix=prefix)
+        admitted = 0
+        if admit and self.queue:
+            with span("apex.serve.admit"):
+                while (self.queue
+                       and (len(self.active) + len(self.prefilling)
+                            < self.ladder.max_batch)):
+                    # one match per admission attempt: the PrefixMatch
+                    # feeds both the reservation check and the
+                    # admission itself (hashing the prompt every tick
+                    # for a blocked queue head would sit on the hot
+                    # path for nothing)
+                    req = self.queue[0]
+                    prefix = self.manager.match_prefix(req.prompt)
+                    if not self.manager.can_admit(
+                            len(req.prompt), req.max_new_tokens,
+                            reserved_blocks=self._reserved_blocks(),
+                            prefix=prefix):
+                        break
+                    self._admit(self.queue.popleft(), prefix=prefix)
+                    admitted += 1
         # requests may finish at admission (max_new_tokens == 1)
         for rid in [r for r, q in self.active.items() if q.done]:
             self._finish(self.active[rid])
@@ -1266,12 +1294,12 @@ class ServingEngine:
                 # stall heartbeat must see chunked-prefill progress
                 # even before anything decodes
                 self._tick_tail(0, 0, 0)
-            return 0
+            return 0, admitted
         reqs = [self.active[r] for r in sorted(self.active,
                                                key=lambda r: str(r))]
         if self.speculate_k > 0:
-            return self._spec_tick(reqs)
-        return self._decode_tick(reqs)
+            return self._spec_tick(reqs), admitted
+        return self._decode_tick(reqs), admitted
 
     def _append_slot(self, req: Request):
         """One KV append with the copy-on-write guard: a slot landing
@@ -1285,41 +1313,45 @@ class ServingEngine:
         return self.manager.append(req.rid)
 
     def _decode_tick(self, reqs: List[Request]) -> int:
-        n = len(reqs)
-        bb = self.ladder.pick_batch(n)
-        slots = [self._append_slot(q) for q in reqs]
-        pb = self.ladder.pick_pages(
-            max(self.manager.num_pages(q.rid) for q in reqs))
-        tokens = np.zeros(bb, np.int32)
-        positions = np.zeros(bb, np.int32)
-        seq_lens = np.zeros(bb, np.int32)
-        wb = np.full(bb, DUMP_BLOCK, np.int32)
-        wo = np.zeros(bb, np.int32)
-        bt = np.full((bb, pb), DUMP_BLOCK, np.int32)
-        for i, (q, (blk, off)) in enumerate(zip(reqs, slots)):
-            new_len = self.manager.seq_len(q.rid)   # post-append
-            tokens[i] = q.out_tokens[-1]
-            positions[i] = new_len - 1
-            seq_lens[i] = new_len
-            wb[i], wo[i] = blk, off
-            bt[i] = self.manager.block_table(q.rid, pb)
-        fn = self._decode_fn(bb, pb)
+        with span("apex.serve.decode.build"):
+            n = len(reqs)
+            bb = self.ladder.pick_batch(n)
+            slots = [self._append_slot(q) for q in reqs]
+            pb = self.ladder.pick_pages(
+                max(self.manager.num_pages(q.rid) for q in reqs))
+            tokens = np.zeros(bb, np.int32)
+            positions = np.zeros(bb, np.int32)
+            seq_lens = np.zeros(bb, np.int32)
+            wb = np.full(bb, DUMP_BLOCK, np.int32)
+            wo = np.zeros(bb, np.int32)
+            bt = np.full((bb, pb), DUMP_BLOCK, np.int32)
+            for i, (q, (blk, off)) in enumerate(zip(reqs, slots)):
+                new_len = self.manager.seq_len(q.rid)   # post-append
+                tokens[i] = q.out_tokens[-1]
+                positions[i] = new_len - 1
+                seq_lens[i] = new_len
+                wb[i], wo[i] = blk, off
+                bt[i] = self.manager.block_table(q.rid, pb)
+            fn = self._decode_fn(bb, pb)
         t0 = self._clock()
-        self.cache, next_tokens = fn(
-            self.weights, self.cache, jnp.asarray(tokens),
-            jnp.asarray(positions), jnp.asarray(bt),
-            jnp.asarray(seq_lens), jnp.asarray(wb), jnp.asarray(wo))
-        out = np.asarray(next_tokens)    # the tick's ONE device fetch
+        with span("apex.serve.decode.dispatch"):
+            self.cache, next_tokens = fn(
+                self.weights, self.cache, jnp.asarray(tokens),
+                jnp.asarray(positions), jnp.asarray(bt),
+                jnp.asarray(seq_lens), jnp.asarray(wb), jnp.asarray(wo))
+        with span("apex.serve.decode.fetch"):
+            out = np.asarray(next_tokens)    # the tick's ONE device fetch
         dt = self._clock() - t0
-        for i, q in enumerate(reqs):
-            q.out_tokens.append(int(out[i]))
-            q.token_latency_s.append(dt)
-            self._latencies.append(dt)
-        self.decode_wall_s += dt
-        self.decode_tokens += n
-        self.steps += 1
-        self._event("decode_step", value=round(dt * 1e3, 3),
-                    batch=n, batch_bucket=bb, pages_bucket=pb)
+        with span("apex.serve.deliver"):
+            for i, q in enumerate(reqs):
+                q.out_tokens.append(int(out[i]))
+                q.token_latency_s.append(dt)
+                self._latencies.append(dt)
+            self.decode_wall_s += dt
+            self.decode_tokens += n
+            self.steps += 1
+            self._event("decode_step", value=round(dt * 1e3, 3),
+                        batch=n, batch_bucket=bb, pages_bucket=pb)
         self._tick_tail(n, bb, pb)
         return n
 
@@ -1335,34 +1367,35 @@ class ServingEngine:
         manager's (block, offset) slot accounting; the draft cache
         catches up its one unwritten position on full acceptance so
         the next tick's proposals stay on-policy."""
-        K = self.speculate_k
-        T = K + 1
-        n = len(reqs)
-        bb = self.ladder.pick_batch(n)
-        base = np.zeros(bb, np.int32)
-        caps = np.zeros(bb, np.int32)
-        slots: List[List[Tuple[int, int]]] = []
-        for i, q in enumerate(reqs):
-            base[i] = self.manager.seq_len(q.rid)
-            # a row near its token budget writes fewer real slots —
-            # the reservation contract (prompt + max_new) caps the
-            # pages a tick may claim, so overshoot positions go to
-            # the dump page and their (unused) logits are garbage
-            caps[i] = max(1, min(T, q.max_new_tokens
-                                 - len(q.out_tokens)))
-            row = []
-            for j in range(int(caps[i])):
-                if j == 0:
-                    row.append(self._append_slot(q))
-                else:
-                    row.append(self.manager.append(q.rid))
-            slots.append(row)
-        pb = self.ladder.pick_pages(
-            max(self.manager.num_pages(q.rid) for q in reqs))
-        bt = np.full((bb, pb), DUMP_BLOCK, np.int32)
-        for i, q in enumerate(reqs):
-            bt[i] = self.manager.block_table(q.rid, pb)
-        bt_j = jnp.asarray(bt)
+        with span("apex.serve.decode.build"):
+            K = self.speculate_k
+            T = K + 1
+            n = len(reqs)
+            bb = self.ladder.pick_batch(n)
+            base = np.zeros(bb, np.int32)
+            caps = np.zeros(bb, np.int32)
+            slots: List[List[Tuple[int, int]]] = []
+            for i, q in enumerate(reqs):
+                base[i] = self.manager.seq_len(q.rid)
+                # a row near its token budget writes fewer real slots —
+                # the reservation contract (prompt + max_new) caps the
+                # pages a tick may claim, so overshoot positions go to
+                # the dump page and their (unused) logits are garbage
+                caps[i] = max(1, min(T, q.max_new_tokens
+                                     - len(q.out_tokens)))
+                row = []
+                for j in range(int(caps[i])):
+                    if j == 0:
+                        row.append(self._append_slot(q))
+                    else:
+                        row.append(self.manager.append(q.rid))
+                slots.append(row)
+            pb = self.ladder.pick_pages(
+                max(self.manager.num_pages(q.rid) for q in reqs))
+            bt = np.full((bb, pb), DUMP_BLOCK, np.int32)
+            for i, q in enumerate(reqs):
+                bt[i] = self.manager.block_table(q.rid, pb)
+            bt_j = jnp.asarray(bt)
         t0 = self._clock()
         # --- draft proposals: K sequential single-token steps -------
         d = np.zeros((bb, K), np.int32)
@@ -1370,117 +1403,129 @@ class ServingEngine:
         for i, q in enumerate(reqs):
             prev[i] = q.out_tokens[-1]
         for k in range(1, K + 1):
-            toks = prev if k == 1 else d[:, k - 2]
-            pos = np.zeros(bb, np.int32)
-            sl = np.zeros(bb, np.int32)
-            wbk = np.full(bb, DUMP_BLOCK, np.int32)
-            wok = np.zeros(bb, np.int32)
-            for i in range(n):
-                pos[i] = base[i] + k - 1
-                sl[i] = base[i] + k
-                if k - 1 < caps[i]:
-                    wbk[i], wok[i] = slots[i][k - 1]
-            dfn = self._draft_decode_fn(bb, pb)
-            self.draft_cache, nt = dfn(
-                self.draft_weights, self.draft_cache,
-                jnp.asarray(toks), jnp.asarray(pos), bt_j,
-                jnp.asarray(sl), jnp.asarray(wbk), jnp.asarray(wok))
-            d[:, k - 1] = np.asarray(nt)
+            with span("apex.serve.decode.build"):
+                toks = prev if k == 1 else d[:, k - 2]
+                pos = np.zeros(bb, np.int32)
+                sl = np.zeros(bb, np.int32)
+                wbk = np.full(bb, DUMP_BLOCK, np.int32)
+                wok = np.zeros(bb, np.int32)
+                for i in range(n):
+                    pos[i] = base[i] + k - 1
+                    sl[i] = base[i] + k
+                    if k - 1 < caps[i]:
+                        wbk[i], wok[i] = slots[i][k - 1]
+                dfn = self._draft_decode_fn(bb, pb)
+            with span("apex.serve.decode.dispatch"):
+                self.draft_cache, nt = dfn(
+                    self.draft_weights, self.draft_cache,
+                    jnp.asarray(toks), jnp.asarray(pos), bt_j,
+                    jnp.asarray(sl), jnp.asarray(wbk),
+                    jnp.asarray(wok))
+            with span("apex.serve.decode.fetch"):
+                d[:, k - 1] = np.asarray(nt)
         # --- target verification: ONE teacher-forced extend call ----
-        vt = np.zeros((bb, T), np.int32)
-        wbv = np.full((bb, T), DUMP_BLOCK, np.int32)
-        wov = np.zeros((bb, T), np.int32)
-        slv = np.zeros(bb, np.int32)
-        for i in range(n):
-            vt[i, 0] = prev[i]
-            vt[i, 1:] = d[i]
-            slv[i] = base[i] + T
-            for j, (blk, off) in enumerate(slots[i]):
-                wbv[i, j], wov[i, j] = blk, off
-        fn = self._extend_fn(bb, T, pb)
-        self.cache, out = fn(
-            self.weights, self.cache, jnp.asarray(vt), bt_j,
-            jnp.asarray(slv), jnp.asarray(wbv), jnp.asarray(wov))
-        a = np.asarray(out)              # (bb, T) — the tick's fetch
+        with span("apex.serve.decode.build"):
+            vt = np.zeros((bb, T), np.int32)
+            wbv = np.full((bb, T), DUMP_BLOCK, np.int32)
+            wov = np.zeros((bb, T), np.int32)
+            slv = np.zeros(bb, np.int32)
+            for i in range(n):
+                vt[i, 0] = prev[i]
+                vt[i, 1:] = d[i]
+                slv[i] = base[i] + T
+                for j, (blk, off) in enumerate(slots[i]):
+                    wbv[i, j], wov[i, j] = blk, off
+            fn = self._extend_fn(bb, T, pb)
+        with span("apex.serve.decode.dispatch"):
+            self.cache, out = fn(
+                self.weights, self.cache, jnp.asarray(vt), bt_j,
+                jnp.asarray(slv), jnp.asarray(wbv), jnp.asarray(wov))
+        with span("apex.serve.decode.fetch"):
+            a = np.asarray(out)          # (bb, T) — the tick's fetch
         # --- greedy-match acceptance + rollback ---------------------
-        gained = 0
-        full_rows: List[int] = []
-        keeps: List[int] = []
-        tick_proposed = 0
-        tick_accepted = 0
-        for i, q in enumerate(reqs):
-            cap = int(caps[i])
-            emit = [int(a[i, 0])]
-            j = 0
-            while j < cap - 1 and int(d[i, j]) == emit[-1]:
-                emit.append(int(a[i, j + 1]))
-                j += 1
-            tick_proposed += max(0, cap - 1)
-            tick_accepted += j
-            if q.eos_token is not None and q.eos_token in emit:
-                emit = emit[:emit.index(q.eos_token) + 1]
-            keep = len(emit)
-            if keep < cap:
-                self.manager.truncate(q.rid, int(base[i]) + keep)
-            if keep == T:
-                full_rows.append(i)
-            q.out_tokens.extend(emit)
-            keeps.append(keep)
-            gained += keep
-        dt = self._clock() - t0
-        # amortize the tick wall over each row's gained tokens — the
-        # tokens arrive together, so the honest per-token figure is
-        # the tick cost split across them (the same population
-        # ServeSummary.itl draws from)
-        for q, keep in zip(reqs, keeps):
-            share = dt / keep
-            for _ in range(keep):
-                q.token_latency_s.append(share)
-                self._latencies.append(share)
-        self.spec_proposed += tick_proposed
-        self.spec_accepted += tick_accepted
-        self.metrics.gauges.on_spec(tick_proposed, tick_accepted)
-        if self.spec_governor is not None \
-                and self.spec_governor.observe(tick_proposed,
-                                               tick_accepted):
-            # degraded mode: sustained verify mismatch (a drifted or
-            # stalled draft) — turn speculation off for the rest of
-            # the run.  Alarm + gauge, never a crash; output identity
-            # is preserved (speculative greedy == greedy), so the only
-            # observable change is ITL returning to one token/tick.
-            self.spec_disabled = True
-            self.speculate_k = 0
-            if self.monitor is not None:
-                self.monitor.event(
-                    "alarm", "spec_disabled", step=self.steps,
-                    low_streak=self.spec_governor.window,
-                    min_accept=self.spec_governor.min_accept)
+        with span("apex.serve.deliver"):
+            gained = 0
+            full_rows: List[int] = []
+            keeps: List[int] = []
+            tick_proposed = 0
+            tick_accepted = 0
+            for i, q in enumerate(reqs):
+                cap = int(caps[i])
+                emit = [int(a[i, 0])]
+                j = 0
+                while j < cap - 1 and int(d[i, j]) == emit[-1]:
+                    emit.append(int(a[i, j + 1]))
+                    j += 1
+                tick_proposed += max(0, cap - 1)
+                tick_accepted += j
+                if q.eos_token is not None and q.eos_token in emit:
+                    emit = emit[:emit.index(q.eos_token) + 1]
+                keep = len(emit)
+                if keep < cap:
+                    self.manager.truncate(q.rid, int(base[i]) + keep)
+                if keep == T:
+                    full_rows.append(i)
+                q.out_tokens.extend(emit)
+                keeps.append(keep)
+                gained += keep
+            dt = self._clock() - t0
+            # amortize the tick wall over each row's gained tokens — the
+            # tokens arrive together, so the honest per-token figure is
+            # the tick cost split across them (the same population
+            # ServeSummary.itl draws from)
+            for q, keep in zip(reqs, keeps):
+                share = dt / keep
+                for _ in range(keep):
+                    q.token_latency_s.append(share)
+                    self._latencies.append(share)
+            self.spec_proposed += tick_proposed
+            self.spec_accepted += tick_accepted
+            self.metrics.gauges.on_spec(tick_proposed, tick_accepted)
+            if self.spec_governor is not None \
+                    and self.spec_governor.observe(tick_proposed,
+                                                   tick_accepted):
+                # degraded mode: sustained verify mismatch (a drifted or
+                # stalled draft) — turn speculation off for the rest of
+                # the run.  Alarm + gauge, never a crash; output identity
+                # is preserved (speculative greedy == greedy), so the only
+                # observable change is ITL returning to one token/tick.
+                self.spec_disabled = True
+                self.speculate_k = 0
+                if self.monitor is not None:
+                    self.monitor.event(
+                        "alarm", "spec_disabled", step=self.steps,
+                        low_streak=self.spec_governor.window,
+                        min_accept=self.spec_governor.min_accept)
         # --- draft catch-up: on full acceptance the draft never wrote
         # position base + K (the target's verify did) — one masked
         # draft step fills it so next tick's proposals read real k/v
         if full_rows:
-            toks = np.zeros(bb, np.int32)
-            pos = np.zeros(bb, np.int32)
-            sl = np.zeros(bb, np.int32)
-            wbk = np.full(bb, DUMP_BLOCK, np.int32)
-            wok = np.zeros(bb, np.int32)
-            for i in full_rows:
-                toks[i] = reqs[i].out_tokens[-2]     # the token AT
-                pos[i] = base[i] + K                 # position base+K
-                sl[i] = base[i] + T
-                wbk[i], wok[i] = slots[i][K]
-            dfn = self._draft_decode_fn(bb, pb)
-            self.draft_cache, _ = dfn(
-                self.draft_weights, self.draft_cache,
-                jnp.asarray(toks), jnp.asarray(pos), bt_j,
-                jnp.asarray(sl), jnp.asarray(wbk), jnp.asarray(wok))
-        self.decode_wall_s += dt
-        self.decode_tokens += gained
-        self.steps += 1
-        self._event("decode_step", value=round(dt * 1e3, 3),
-                    batch=n, batch_bucket=bb, pages_bucket=pb,
-                    spec_proposed=tick_proposed,
-                    spec_accepted=tick_accepted, tokens=gained)
+            with span("apex.serve.decode.build"):
+                toks = np.zeros(bb, np.int32)
+                pos = np.zeros(bb, np.int32)
+                sl = np.zeros(bb, np.int32)
+                wbk = np.full(bb, DUMP_BLOCK, np.int32)
+                wok = np.zeros(bb, np.int32)
+                for i in full_rows:
+                    toks[i] = reqs[i].out_tokens[-2]     # the token AT
+                    pos[i] = base[i] + K             # position base+K
+                    sl[i] = base[i] + T
+                    wbk[i], wok[i] = slots[i][K]
+                dfn = self._draft_decode_fn(bb, pb)
+            with span("apex.serve.decode.dispatch"):
+                self.draft_cache, _ = dfn(
+                    self.draft_weights, self.draft_cache,
+                    jnp.asarray(toks), jnp.asarray(pos), bt_j,
+                    jnp.asarray(sl), jnp.asarray(wbk),
+                    jnp.asarray(wok))
+        with span("apex.serve.deliver"):
+            self.decode_wall_s += dt
+            self.decode_tokens += gained
+            self.steps += 1
+            self._event("decode_step", value=round(dt * 1e3, 3),
+                        batch=n, batch_bucket=bb, pages_bucket=pb,
+                        spec_proposed=tick_proposed,
+                        spec_accepted=tick_accepted, tokens=gained)
         self._tick_tail(n, bb, pb)
         return gained
 
@@ -1489,47 +1534,49 @@ class ServingEngine:
         registered cadence, snapshot-trigger poll, and the watchdog
         stall heartbeat — all host bookkeeping the engine already
         holds, after the tick's one device fetch."""
-        levels = dict(
-            batch=batch, batch_bucket=bb, pages_bucket=pb,
-            free_blocks=self.manager.free_blocks,
-            used_blocks=self.manager.used_blocks,
-            reserved_blocks=self._reserved_blocks(),
-            shared_blocks=self.manager.shared_blocks,
-            pool_blocks=self.cache_cfg.usable_blocks,
-            queue_depth=len(self.queue),
-            prefilling=len(self.prefilling),
-            compiles=sum(self._compiles.values()))
-        if self.shed is not None and self.shed.enabled:
-            levels["shed_engaged"] = self.shed.engaged
-        if self.spec_disabled:
-            levels["spec_disabled"] = True
-        self.metrics.on_tick(self.steps, **levels)
-        if self.journal is not None and self.active:
-            # ONE aggregated progress record per tick (not one write
-            # per request — the journal flushes per line, and O(batch)
-            # syscalls per generated token would tax ITL): the replay
-            # ledger's observability record (replay correctness rides
-            # the submit/terminal records — greedy decode regenerates)
-            self.journal.record_progress(
-                {rid: len(q.out_tokens)
-                 for rid, q in self.active.items()}, self.steps)
-        if self.snapshot is not None:
-            self.snapshot.poll(self.steps, self.snapshot_state,
-                               self.monitor)
-        # the serve loop's stall heartbeat: each tick feeds the same
-        # Watchdog the training loops drive through StepMonitor, so a
-        # wedged decode step raises the once-per-episode stall alarm
-        # (with the optional jax.profiler capture) mid-serve
-        wd = getattr(self.monitor, "watchdog", None)
-        if wd is not None:
-            wd.observe_step(self.steps)
-        # ISSUE-17: SLO burn evaluation, then one lock-free exporter
-        # publish — SLO first so the published /healthz already
-        # reflects an episode that opened this tick
-        if self.slo is not None:
-            self._poll_slo()
-        if self.exporter is not None:
-            self._publish_exporter()
+        with span("apex.serve.tick_tail"):
+            levels = dict(
+                batch=batch, batch_bucket=bb, pages_bucket=pb,
+                free_blocks=self.manager.free_blocks,
+                used_blocks=self.manager.used_blocks,
+                reserved_blocks=self._reserved_blocks(),
+                shared_blocks=self.manager.shared_blocks,
+                pool_blocks=self.cache_cfg.usable_blocks,
+                queue_depth=len(self.queue),
+                prefilling=len(self.prefilling),
+                compiles=sum(self._compiles.values()))
+            if self.shed is not None and self.shed.enabled:
+                levels["shed_engaged"] = self.shed.engaged
+            if self.spec_disabled:
+                levels["spec_disabled"] = True
+            self.metrics.on_tick(self.steps, **levels)
+            self._tick_levels = levels
+            if self.journal is not None and self.active:
+                # ONE aggregated progress record per tick (not one write
+                # per request — the journal flushes per line, and O(batch)
+                # syscalls per generated token would tax ITL): the replay
+                # ledger's observability record (replay correctness rides
+                # the submit/terminal records — greedy decode regenerates)
+                self.journal.record_progress(
+                    {rid: len(q.out_tokens)
+                     for rid, q in self.active.items()}, self.steps)
+            if self.snapshot is not None:
+                self.snapshot.poll(self.steps, self.snapshot_state,
+                                   self.monitor)
+            # the serve loop's stall heartbeat: each tick feeds the same
+            # Watchdog the training loops drive through StepMonitor, so a
+            # wedged decode step raises the once-per-episode stall alarm
+            # (with the optional jax.profiler capture) mid-serve
+            wd = getattr(self.monitor, "watchdog", None)
+            if wd is not None:
+                wd.observe_step(self.steps)
+            # ISSUE-17: SLO burn evaluation, then one lock-free exporter
+            # publish — SLO first so the published /healthz already
+            # reflects an episode that opened this tick
+            if self.slo is not None:
+                self._poll_slo()
+            if self.exporter is not None:
+                self._publish_exporter()
 
     def _poll_slo(self) -> None:
         """Per-tick SLO boundary: lazily emit the objective-

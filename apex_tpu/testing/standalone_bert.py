@@ -159,31 +159,35 @@ class BertModel(nn.Module):
                 attention_mask.astype(jnp.float32))
             h = self.transformer(h, ext_mask, deterministic)
 
-        binary_logits = None
-        if self.add_binary_head:
-            binary_logits = self.binary_head(
-                self.pooler(h).astype(jnp.float32))
+        # both heads and the LM loss under the scope a device trace
+        # reads them by (``head_loss_ms.train``)
+        with jax.named_scope("apex.head_loss"):
+            binary_logits = None
+            if self.add_binary_head:
+                binary_logits = self.binary_head(
+                    self.pooler(h).astype(jnp.float32))
 
-        lm_logits = self.lm_head(h, self.embedding.attend)
-        if lm_labels is None:
-            return lm_logits, binary_logits
-        if self.axis_name is not None:
-            lm_loss = vocab_parallel_cross_entropy(
-                lm_logits.astype(jnp.float32), lm_labels,
-                axis_name=self.axis_name)
-        else:
-            # fused CE: the plain logsumexp/take pair feeds the same
-            # fp32 view to two consumers, materializing an fp32 copy of
-            # the (tokens, vocab) logits (measured 9.2 ms/step of
-            # convert+reduce at BERT-large's 30k vocab); the custom-VJP
-            # loss keeps single-consumer fp32 views in fwd AND bwd.
-            from ..contrib.xentropy import softmax_cross_entropy_loss
+            lm_logits = self.lm_head(h, self.embedding.attend)
+            if lm_labels is None:
+                return lm_logits, binary_logits
+            if self.axis_name is not None:
+                lm_loss = vocab_parallel_cross_entropy(
+                    lm_logits.astype(jnp.float32), lm_labels,
+                    axis_name=self.axis_name)
+            else:
+                # fused CE: the plain logsumexp/take pair feeds the
+                # same fp32 view to two consumers, materializing an
+                # fp32 copy of the (tokens, vocab) logits (measured
+                # 9.2 ms/step of convert+reduce at BERT-large's 30k
+                # vocab); the custom-VJP loss keeps single-consumer
+                # fp32 views in fwd AND bwd.
+                from ..contrib.xentropy import softmax_cross_entropy_loss
 
-            # 3-D logits go straight in (the loss broadcasts over
-            # leading dims) — a flatten/reshape round-trip materialized
-            # a copy of the 0.5 GB logits
-            lm_loss = softmax_cross_entropy_loss(
-                lm_logits, lm_labels, half_to_float=True)
+                # 3-D logits go straight in (the loss broadcasts
+                # over leading dims) — a flatten/reshape round-trip
+                # materialized a copy of the 0.5 GB logits
+                lm_loss = softmax_cross_entropy_loss(
+                    lm_logits, lm_labels, half_to_float=True)
         return lm_loss, binary_logits
 
 
@@ -248,9 +252,10 @@ def make_step_fn(setup: BertSmokeSetup):
 
             lm_loss, bin_logits = model.apply(
                 {"params": p}, tokens, mask, lm_labels=labels)
-            nsp_loss = jnp.mean(softmax_cross_entropy_loss(
-                bin_logits, nsp, half_to_float=True))
-            loss = jnp.mean(lm_loss) + nsp_loss
+            with jax.named_scope("apex.head_loss"):
+                nsp_loss = jnp.mean(softmax_cross_entropy_loss(
+                    bin_logits, nsp, half_to_float=True))
+                loss = jnp.mean(lm_loss) + nsp_loss
             return amp_opt.scale_loss(loss, amp_state), loss
 
         grads, loss = jax.grad(loss_fn, has_aux=True)(params)

@@ -138,8 +138,11 @@ class GPTModel(nn.Module):
             dtype=self.dtype, axis_name=self.axis_name, name="transformer")
 
     def __call__(self, tokens, deterministic: bool = True):
-        return self.embedding.attend(
-            self.hidden_states(tokens, deterministic))
+        h = self.hidden_states(tokens, deterministic)
+        # the scope a device trace reads the LM head + loss by
+        # (``head_loss_ms.train``); make_step_fn puts the loss under it
+        with jax.named_scope("apex.head_loss"):
+            return self.embedding.attend(h)
 
     def hidden_states(self, tokens, deterministic: bool = True):
         """Final hidden states WITHOUT the tied-head projection — for
@@ -325,7 +328,8 @@ def make_step_fn(setup: SmokeSetup):
     def _step(params, amp_state):
         def loss_fn(p):
             logits = model.apply({"params": p}, tokens)
-            loss = gpt_loss(logits, labels)
+            with jax.named_scope("apex.head_loss"):
+                loss = gpt_loss(logits, labels)
             return amp_opt.scale_loss(loss, amp_state), loss
 
         grads, loss = jax.grad(loss_fn, has_aux=True)(params)

@@ -54,7 +54,9 @@ def configure_compile_cache() -> Optional[str]:
 
     Idempotent.  The min-entry-size and min-compile-time floors are
     relaxed so the smoke/test-tier programs (fast compiles, small
-    modules) are cached too.
+    modules) are cached too, and the cache key includes the ops'
+    metadata, so an executable loaded from the cache carries the
+    names the program has now.
     """
     global _configured
     import jax
@@ -70,9 +72,17 @@ def configure_compile_cache() -> Optional[str]:
     if not from_env:
         os.makedirs(directory, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", directory)
+    # An executable's op names (``jax.named_scope``s, flax module
+    # paths) are what a device trace attributes time by, and jax's
+    # default key strips them: a program that differs from a cached one
+    # in names alone would be handed the cached executable with the OLD
+    # names, and ``apex.optimizer`` / ``apex.head_loss`` would be
+    # missing from its profile.  With the metadata in the key a change
+    # of names (or of a traced line's number) compiles once more.
     for name, val in (
             ("jax_persistent_cache_min_compile_time_secs", 0.0),
-            ("jax_persistent_cache_min_entry_size_bytes", -1)):
+            ("jax_persistent_cache_min_entry_size_bytes", -1),
+            ("jax_compilation_cache_include_metadata_in_key", True)):
         jax.config.update(name, val)
     # jax initializes the cache AT MOST ONCE, on the first compile: if
     # any compile ran before this call (or the dir changed), the

@@ -205,7 +205,8 @@ class TestCompileCachePrecedence:
         saved = {k: getattr(jax.config, k) for k in (
             "jax_compilation_cache_dir",
             "jax_persistent_cache_min_compile_time_secs",
-            "jax_persistent_cache_min_entry_size_bytes")}
+            "jax_persistent_cache_min_entry_size_bytes",
+            "jax_compilation_cache_include_metadata_in_key")}
         monkeypatch.setattr(compile_cache, "_configured", None)
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
         monkeypatch.delenv("APEX_TPU_COMPILE_CACHE_DIR", raising=False)
@@ -250,6 +251,36 @@ class TestCompileCachePrecedence:
         assert compile_cache.configure_compile_cache() == fixed
         assert jax.config.jax_compilation_cache_dir == fixed
         assert os.path.isdir(fixed)
+
+    def test_a_program_renamed_is_not_handed_the_old_names(
+            self, monkeypatch, tmp_path):
+        """jax's default cache key strips op names: a step that gained
+        ``jax.named_scope``s would load the executable cached before it
+        had them, and a device trace would show none (PR 27)."""
+        import contextlib
+
+        import jax.numpy as jnp
+
+        from apex_tpu.utils import compile_cache
+
+        monkeypatch.setenv("APEX_TPU_COMPILE_CACHE_DIR", str(tmp_path))
+        assert compile_cache.configure_compile_cache() == str(tmp_path)
+
+        def build(scope):
+            def step(x):
+                with scope:
+                    return jnp.sin(x) * 2 + 1
+            return jax.jit(step)
+
+        x = jnp.ones((64, 64))
+        bare = build(contextlib.nullcontext())
+        scoped = build(jax.named_scope("apex.optimizer"))
+        assert bare.lower(x).as_text() == scoped.lower(x).as_text()
+        bare.lower(x).compile()
+        entries = len(list(tmp_path.iterdir()))
+        assert entries > 0
+        assert "apex.optimizer" in scoped.lower(x).compile().as_text()
+        assert len(list(tmp_path.iterdir())) > entries
 
     def test_none_on_the_cpu(self):
         from apex_tpu.utils import compile_cache
