@@ -37,11 +37,45 @@ combine.  ``APEX_TPU_FLASH_PACK_D64=0`` forces the half-width layout
 end to end (cache layout and kernel agree by construction — both ask
 :func:`use_decode_head_packing`).
 
+The grid is ``(batch row, head-group chunk, page)`` and a step carries
+a **page's worth of work**: every head group of the row's page, one
+contiguous ``(hk, bs, dk)`` block each of k and v (32 KB at the served
+GPT-2 shape, where ``(1, 1, bs, dk)`` was 4 KB and the grid eight times
+as long).  The chunk axis is 1 at every served decode shape; it exists
+for steps whose working set would pass a stated VMEM budget
+(:func:`_heads_per_step`: the largest divisor of ``hk`` that fits, read
+from the cache's and the query chunk's shape alone) — a cache of very
+many heads or very long blocks, and the multi-token path's long chunks
+(below).  Inside a step the head groups'
+independent softmax chains are the row blocks of ONE score tile: all
+``hk`` queries against the page's ``hk * bs`` k rows in one full-width
+matmul, the products of a query with another group's rows masked away
+(:func:`_own_page_mask`) — at 8 groups x 16 slots the tile is a single
+fp32 vreg and a page costs two matmuls a product where per-head
+programs ran ``hk`` slivers.  The wasted MXU columns are free; grid
+steps, and the fixed ~6 us every (row, head group) pass of the old
+grid paid, were not (PERF.md, PR 28).  One page a step: several (a
+BlockSpec a page) cut a live page's cost threefold in the kernel
+alone, but a cache passed as several operands loses XLA's fast-memory
+placement of the per-layer cache slices, which costs more than the
+kernel gains while those slices exist (PERF.md, PR 28).
+
 Online softmax runs across pages exactly as the training forward runs
-across k-blocks: per-(batch, head-group) scratch carries m/l/acc over
-the page grid dimension, pages wholly past ``seq_len`` are skipped via
-``pl.when``, and the straddling page masks by global position.  Softmax
-math is fp32 with the exp2 pre-folded constants.
+across k-blocks: per-(batch row) scratch carries m/l/acc — one row a
+head group — over the page grid dimension, pages wholly past
+``seq_len`` are skipped via ``pl.when`` (their table entry is the dump
+page, so no fetch either: a skipped step costs the bare grid step),
+and the straddling page masks by global position.  Softmax math is
+fp32 with the exp2 pre-folded constants.  A row's arithmetic depends
+on that row's pages alone, never on the batch or page rung.
+
+The multi-token path (:func:`flash_decode_multi`: speculative verify,
+chunked extend) is the same program with ``t`` query rows a head group.
+Its carries, q and o blocks and score tiles grow with ``hg * t``, so
+the same budget takes the head groups apart as the chunk grows: at the
+served cache every group a step up to a 128-token chunk, four at 256,
+one at the 1,024-token rung (where the tile is the per-head program's
+own ``(t, bs)``, and nothing of another head is computed).
 
 Int8 KV (weight-only storage; APEX_TPU_SERVE_KV_DTYPE=int8): k/v store
 as int8 with **per-row** (per cached token, per head) fp32 scales, so
@@ -100,21 +134,76 @@ def unpack_decode_heads(x: jnp.ndarray) -> jnp.ndarray:
     return x.reshape(*lead, hp * 2, d2 // 2)
 
 
-def _pos_mask(shape, page0, sl):
-    """cols are global positions [page0, page0 + bs); True = attend."""
-    pos = page0 + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    return pos < sl
+# What one grid step's working set may take of VMEM: well inside the
+# 16 MiB scoped default, so the compiler's own temporaries have room
+# (tests/test_tpu_compile.py holds the estimate to Mosaic's verdict:
+# the cells' cache with a 1,024-token chunk at every head a step was
+# refused at 37.2 MiB, where this count reads 38.2).
+_STEP_VMEM_BYTES = 6 * 1024 * 1024
 
 
-def _row_scales(s_ref, pack, width):
-    """This page's per-row dequant factors as a (bs, 1) column — or,
-    packed, a (bs, width) array with each head's factor on its lane
-    half.  ``s_ref`` is the (1, 1, g, bs) block of the
-    (nb, hk, g, bs) scale view."""
+def _step_vmem_bytes(hg: int, t: int, bs: int, dk: int) -> int:
+    """The working set of a step that carries ``hg`` head groups of
+    ``t`` query rows: k and v pages double-buffered (two bytes an
+    element at most) and up to four page-sized fp32 temporaries (the
+    int8 path dequantizes, the packed path rotates); per query row the
+    m/l/acc carries, q and o double-buffered, and up to four fp32
+    temporaries of the score tile's width (a lane tile at least)."""
+    page, rows = hg * bs * dk, hg * t
+    return (page * (2 * 2 * 2 + 4 * 4)
+            + rows * ((2 * 128 + dk) * 4 + 2 * 2 * dk * 2
+                      + 4 * max(hg * bs, 128) * 4))
+
+
+def _heads_per_step(hk: int, t: int, bs: int, dk: int) -> int:
+    """Head groups one grid step carries: every one of ``hk`` unless
+    that step's working set would pass :data:`_STEP_VMEM_BYTES`, then
+    the largest divisor of ``hk`` that does not — one head group a step
+    at a long chunk, where the carries alone are megabytes.  A divisor
+    short of ``hk`` must make ``hg * t`` a multiple of 8: the q block is
+    ``(hg * t, dk)`` of a flat ``(hk * t, dk)`` and Mosaic takes a
+    second-minor block dimension only whole or in sublane tiles; where
+    no such divisor fits, the smallest is taken and the compiler has the
+    last word.  Read from the cache's and the chunk's shape alone —
+    never from the batch or page rung, so a row's arithmetic is the same
+    under every bucket."""
+    ok = [g for g in range(1, hk + 1)
+          if hk % g == 0 and (g == hk or g * t % 8 == 0)]
+    fits = [g for g in ok
+            if _step_vmem_bytes(g, t, bs, dk) <= _STEP_VMEM_BYTES]
+    return max(fits) if fits else min(ok)
+
+
+def _own_page_mask(shape, t, bs, page0, sl):
+    """The (hg*t, hg*bs) score tile's live entries.  Row ``r`` is query
+    ``r % t`` of head group ``r // t``; column ``c`` is slot ``c % bs``
+    of head group ``c // bs``'s page.  Live = the query's own head
+    group (the off-diagonal blocks are other heads' products, computed
+    because one full-width matmul is cheaper than ``hg`` slivers, and
+    never used) AND the causal rule of a contiguous chunk ending at
+    ``sl``: position <= sl - t + query — at ``t == 1``, ``pos < sl``."""
+    row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    if t == 1:
+        head, query = row, 0
+    else:
+        head = jax.lax.div(row, t)
+        query = row - head * t
+    slot = col - head * bs        # in [0, bs): the row's own head group
+    return (slot >= 0) & (slot < bs) & (page0 + slot <= sl - t + query)
+
+
+def _row_scales(s_ref, hg, pack, width):
+    """This page's per-row dequant factors for the (hg*bs, width) k/v
+    tile: a (hg*bs, 1) column — or, packed, a (hg*bs, width) array with
+    each head's factor on its lane half.  ``s_ref`` is the
+    (1, hg, g, bs) block of the (nb, hk, g, bs) scale view."""
+    def col(gg):
+        return jnp.concatenate(
+            [s_ref[0, hh, gg, :][:, None] for hh in range(hg)], axis=0)
     if pack:
-        return _pack_lane_cols(s_ref[0, 0, 0, :][:, None],
-                               s_ref[0, 0, 1, :][:, None], width)
-    return s_ref[0, 0, 0, :][:, None]
+        return _pack_lane_cols(col(0), col(1), width)
+    return col(0)
 
 
 def _normalized(l_sc, acc, pack):
@@ -130,18 +219,24 @@ def _normalized(l_sc, acc, pack):
 
 def _scale_operand(scale, hk, g):
     """(nb, h, bs) global-head-order scales viewed (nb, hk, g, bs): a
-    program's head group becomes a whole trailing (g, bs) block, which
-    the TPU lowering accepts where a size-g block on an axis of h is
+    step's head groups become whole trailing (g, bs) blocks, which the
+    TPU lowering accepts where a size-g block on an axis of h is
     refused."""
     nb, _, bs = scale.shape
     return scale.reshape(nb, hk, g, bs)
 
 
-def _decode_kernel(a, bs, pack, has_scale, *refs):
-    """One (batch row, head group, page) program.  Scalar-prefetch refs
-    lead: block tables (consumed by the index maps, unused here) and
-    seq_lens.  Scratch m/l ride columns 0..g-1 of a (1, 128) carry —
-    the training kernels' column-per-head idiom at bq=1."""
+def _paged_kernel(a, bs, t, hg, pack, has_scale, *refs):
+    """One (batch row, head-group chunk, page) program: ALL ``hg`` head
+    groups of the row's page, ``t`` query rows each (``t == 1``: plain
+    decode).  Scalar-prefetch refs lead: block tables (consumed by the
+    index maps, unused here) and seq_lens.  The ``hg`` independent
+    online-softmax chains are the row blocks of one (hg*t, hg*bs)
+    score tile — at the serving shape (8 groups x 16 slots) exactly one
+    fp32 vreg — so a page costs two full-width matmuls a product
+    instead of ``hg`` slivers; see :func:`_own_page_mask`.  Scratch m/l
+    ride columns 0..g-1 of a (hg*t, 128) carry — the training kernels'
+    column-per-head idiom."""
     bt_ref, sl_ref, q_ref, k_ref, v_ref, *rest = refs
     if has_scale:
         ks_ref, vs_ref, *rest = rest
@@ -157,25 +252,25 @@ def _decode_kernel(a, bs, pack, has_scale, *refs):
         l_sc[:] = jnp.zeros_like(l_sc)
         acc[:] = jnp.zeros_like(acc)
 
-    # a page wholly past the sequence contributes nothing — skip it
-    # (its block-table entry points at the dump page; the DMA is the
-    # bucketed cost the ladder accounts for, the FLOPs are not paid)
+    # a page wholly past the sequence contributes nothing — skip it.
+    # Its block-table entry is the dump page, as is its neighbours', so
+    # the pipeline re-fetches nothing either: what the bucketed page
+    # rung costs a short row is bare grid steps (~10 ns each on v5e).
     @pl.when(j * bs < sl)
     def _page():
-        q = q_ref[0, 0]                               # (1, dk)
-        k = k_ref[0, 0]                               # (bs, dk)
-        v = v_ref[0, 0]
+        dk = acc.shape[1]
+        q = q_ref[0]                                  # (hg*t, dk)
+        k = k_ref[0].reshape(hg * bs, dk)             # rows (head, slot)
+        v = v_ref[0].reshape(hg * bs, dk)
         if has_scale:
             # int8 rows -> f32 in VMEM; per-row scales so history is
             # never requantized by an append.  Packed: each lane half
             # is one head's row, scaled by that head's factor.
-            k = k.astype(jnp.float32) * _row_scales(ks_ref, pack,
-                                                    k.shape[-1])
-            v = v.astype(jnp.float32) * _row_scales(vs_ref, pack,
-                                                    v.shape[-1])
+            k = k.astype(jnp.float32) * _row_scales(ks_ref, hg, pack, dk)
+            v = v.astype(jnp.float32) * _row_scales(vs_ref, hg, pack, dk)
         heads = _packed_scores(q, k) if pack \
-            else (_dot(q, k, trans_b=True),)           # (1, bs) fp32
-        mask = _pos_mask(heads[0].shape, j * bs, sl)
+            else (_dot(q, k, trans_b=True),)           # (hg*t, hg*bs) fp32
+        mask = _own_page_mask(heads[0].shape, t, bs, j * bs, sl)
         pas, corrs = [], []
         for hh, s in enumerate(heads):
             s = jnp.where(mask, s, _NEG)
@@ -184,9 +279,10 @@ def _decode_kernel(a, bs, pack, has_scale, *refs):
                                                 keepdims=True))
             corr = jnp.exp2((m_prev - m_cur) * a)
             p = jnp.exp2((s - m_cur) * a)
-            # the straddling page's masked tail: (s - m_cur) = 0 there
-            # when every column so far is masked — zero p explicitly
-            # so dead rows sum to l = 0 and emit exactly 0
+            # masked entries: (s - m_cur) = 0 there when every column
+            # so far is masked — zero p explicitly so dead rows sum to
+            # l = 0 and emit exactly 0, and so another head group's
+            # v rows drop out of the p v product below
             p = jnp.where(mask, p, 0.0)
             l_sc[:, hh:hh + 1] = l_sc[:, hh:hh + 1] * corr \
                 + jnp.sum(p, axis=1, keepdims=True)
@@ -202,60 +298,118 @@ def _decode_kernel(a, bs, pack, has_scale, *refs):
 
     @pl.when(j == nj - 1)
     def _finish():
-        o_ref[0, 0] = _normalized(l_sc, acc, pack).astype(o_ref.dtype)
+        out = _normalized(l_sc, acc, pack).astype(o_ref.dtype)
+        for hh in range(hg):
+            o_ref[0, hh] = out[hh * t:(hh + 1) * t]
 
 
-def _decode_paged(q3, k_cache, v_cache, block_tables, seq_lens, scale,
-                  k_scale, v_scale, pack):
-    """The pallas_call driver: grid (b, head groups, pages), block
-    tables + seq_lens scalar-prefetched so the k/v index maps read the
-    page id directly — the gather IS the pipeline's block fetch."""
-    b, hk, dk = q3.shape
+def _paged_program(q4, k_cache, v_cache, block_tables, seq_lens, scale,
+                   k_scale, v_scale, pack, interpret):
+    """What both pallas_call drivers share: ``(kernel, keywords,
+    operands)`` for q4 = (b, hk, t, dk).  Grid (b, hk // hg, pages)
+    with ``hg`` = :func:`_heads_per_step` (all ``hk`` at every served
+    decode shape, so the middle axis is 1); block tables + seq_lens are
+    scalar-prefetched so the k/v index maps read the page id directly —
+    the gather IS the pipeline's block fetch, one contiguous
+    (hg, bs, dk) page a step."""
+    b, hk, t, dk = q4.shape
     nb, _, bs, _ = k_cache.shape
     mp = block_tables.shape[1]
     a = float(scale) * _LOG2E
     has_scale = k_scale is not None
     g = 2 if pack else 1
+    hg = _heads_per_step(hk, t, bs, dk)
 
-    # q/o ride as (b, hk, 1, dk): the block's trailing (1, dk) is then
-    # the array's own — a block of 1 on the hk axis of (b, hk, dk) is
-    # refused by the TPU lowering
-    def qo_spec():
-        return pl.BlockSpec((1, 1, 1, dk),
-                            lambda b_, h_, j, bt, sl: (b_, h_, 0, 0),
-                            memory_space=pltpu.VMEM)
+    def row_map(b_, h_, j, bt, sl):
+        return (b_, h_, 0, 0)
 
-    kv_spec = pl.BlockSpec(
-        (1, 1, bs, dk),
-        lambda b_, h_, j, bt, sl: (bt[b_, j], h_, 0, 0),
-        memory_space=pltpu.VMEM)
-    in_specs = [qo_spec(), kv_spec, kv_spec]
-    operands = [q3[:, :, None, :], k_cache, v_cache]
+    def page_map(b_, h_, j, bt, sl):
+        return (bt[b_, j], h_, 0, 0)
+
+    # q rides flat, (b, hk*t, dk), so a step's queries are one
+    # (hg*t, dk) tile with no in-kernel relayout; o keeps the 4-D
+    # (b, hk, t, dk) — a block's trailing (t, dk) is the array's own
+    kv_spec = pl.BlockSpec((1, hg, bs, dk), page_map,
+                           memory_space=pltpu.VMEM)
+    in_specs = [pl.BlockSpec((1, hg * t, dk),
+                             lambda b_, h_, j, bt, sl: (b_, h_, 0),
+                             memory_space=pltpu.VMEM),
+                kv_spec, kv_spec]
+    operands = [q4.reshape(b, hk * t, dk), k_cache, v_cache]
     if has_scale:
-        sc_spec = pl.BlockSpec(
-            (1, 1, g, bs),
-            lambda b_, h_, j, bt, sl: (bt[b_, j], h_, 0, 0),
-            memory_space=pltpu.VMEM)
+        sc_spec = pl.BlockSpec((1, hg, g, bs), page_map,
+                               memory_space=pltpu.VMEM)
         in_specs += [sc_spec, sc_spec]
         operands += [_scale_operand(k_scale, hk, g),
                      _scale_operand(v_scale, hk, g)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hk, mp),
+        grid=(b, hk // hg, mp),
         in_specs=in_specs,
-        out_specs=qo_spec(),
+        out_specs=pl.BlockSpec((1, hg, t, dk), row_map,
+                               memory_space=pltpu.VMEM),
         scratch_shapes=[
-            pltpu.VMEM((1, 128), jnp.float32),
-            pltpu.VMEM((1, 128), jnp.float32),
-            pltpu.VMEM((1, dk), jnp.float32),
+            pltpu.VMEM((hg * t, 128), jnp.float32),
+            pltpu.VMEM((hg * t, 128), jnp.float32),
+            pltpu.VMEM((hg * t, dk), jnp.float32),
         ])
-    return pl.pallas_call(
-        functools.partial(_decode_kernel, a, bs, pack, has_scale),
+    kernel = functools.partial(_paged_kernel, a, bs, t, hg, pack,
+                               has_scale)
+    keywords = dict(
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hk, 1, dk), q3.dtype),
-        name="paged_flash_decode",
-        interpret=_interpret(),
-    )(block_tables, seq_lens, *operands)[:, :, 0, :]
+        out_shape=jax.ShapeDtypeStruct((b, hk, t, dk), q4.dtype),
+        interpret=interpret)
+    return kernel, keywords, (block_tables, seq_lens, *operands)
+
+
+# Both drivers are jitted: a decode program calls its driver once a
+# layer with the same shapes, and under the outer jit an inner one is
+# traced and lowered once per program, not once per layer.  That
+# Pallas -> Mosaic lowering is Python time on every start, compile
+# cache hit or not (PERF.md, PR 28: 24 layers x 8 decode buckets).
+# ``interpret`` is an argument so that it keys the trace.
+_jit_driver = functools.partial(
+    jax.jit, static_argnames=("scale", "pack", "interpret"))
+
+
+@_jit_driver
+def _decode_paged(q3, k_cache, v_cache, block_tables, seq_lens, scale,
+                  k_scale, v_scale, pack, interpret):
+    """The single-token pallas_call driver: (b, hk, dk) queries are the
+    ``t == 1`` chunk.  The call's output stays ``(b, hk, 1, dk)`` and
+    its first operand the block table as the engine built it."""
+    kernel, keywords, operands = _paged_program(
+        q3[:, :, None, :], k_cache, v_cache, block_tables, seq_lens,
+        scale, k_scale, v_scale, pack, interpret)
+    return pl.pallas_call(kernel, name="paged_flash_decode",
+                          **keywords)(*operands)[:, :, 0, :]
+
+
+def _cache_is_packed(q_shape, k_cache, v_cache, k_scale, v_scale):
+    """Validate the cache (and scales) against q's trailing (h, d) and
+    say whether it is stored head-packed — the cache layout decides the
+    kernel path."""
+    h, d = q_shape[-2:]
+    nb, hk, bs, dk = k_cache.shape
+    if v_cache.shape != k_cache.shape:
+        raise ValueError(f"k/v cache shapes differ: {k_cache.shape} "
+                         f"vs {v_cache.shape}")
+    if (k_scale is None) != (v_scale is None):
+        raise ValueError("pass both k_scale and v_scale or neither")
+    if hk == h and dk == d:
+        pack = False
+    elif h % 2 == 0 and hk == h // 2 and dk == 2 * d:
+        pack = True
+    else:
+        raise ValueError(
+            f"cache head layout {(hk, dk)} matches neither unpacked "
+            f"{(h, d)} nor head-packed {(h // 2, 2 * d)} for q "
+            f"{q_shape}")
+    for name, sc in (("k_scale", k_scale), ("v_scale", v_scale)):
+        if sc is not None and sc.shape != (nb, h, bs):
+            raise ValueError(f"{name} shape {sc.shape} != expected "
+                             f"{(nb, h, bs)} (global head order)")
+    return pack
 
 
 def flash_decode(q: jnp.ndarray, k_cache: jnp.ndarray,
@@ -276,154 +430,29 @@ def flash_decode(q: jnp.ndarray, k_cache: jnp.ndarray,
     (nb, h, bs) fp32 arm the int8 weight-only dequant path.  Returns
     (b, h, d) in q's dtype.  Inference-only (no VJP).
     """
-    b, h, d = q.shape
     if scale is None:
-        scale = d ** -0.5
-    nb, hk, bs, dk = k_cache.shape
-    if v_cache.shape != k_cache.shape:
-        raise ValueError(f"k/v cache shapes differ: {k_cache.shape} "
-                         f"vs {v_cache.shape}")
-    if (k_scale is None) != (v_scale is None):
-        raise ValueError("pass both k_scale and v_scale or neither")
-    if hk == h and dk == d:
-        pack = False
-    elif h % 2 == 0 and hk == h // 2 and dk == 2 * d:
-        pack = True
-    else:
-        raise ValueError(
-            f"cache head layout {(hk, dk)} matches neither unpacked "
-            f"{(h, d)} nor head-packed {(h // 2, 2 * d)} for q "
-            f"{q.shape}")
-    for name, sc in (("k_scale", k_scale), ("v_scale", v_scale)):
-        if sc is not None and sc.shape != (nb, h, bs):
-            raise ValueError(f"{name} shape {sc.shape} != expected "
-                             f"{(nb, h, bs)} (global head order)")
+        scale = q.shape[-1] ** -0.5
+    pack = _cache_is_packed(q.shape, k_cache, v_cache, k_scale, v_scale)
     q3 = pack_decode_heads(q) if pack else q
     out = _decode_paged(q3, k_cache, v_cache,
                         block_tables.astype(jnp.int32),
                         seq_lens.astype(jnp.int32), scale,
-                        k_scale, v_scale, pack)
+                        k_scale, v_scale, pack, _interpret())
     return unpack_decode_heads(out) if pack else out
 
 
 # --- multi-token path (speculative verify / chunked prefill) ---------------
 
-def _decode_multi_kernel(a, bs, t, pack, has_scale, *refs):
-    """One (batch row, head group, page) program over a CHUNK of ``t``
-    query rows.  Row ``r`` of batch ``b`` sits at global position
-    ``seq_lens[b] - t + r`` (chunk positions are contiguous and end at
-    the last written slot), so the per-row causal mask is
-    ``pos <= sl - t + r`` — at ``t == 1`` this is exactly the decode
-    kernel's ``pos < sl``.  m/l scratch carries one row per query in
-    columns 0..g-1; everything else mirrors :func:`_decode_kernel`."""
-    bt_ref, sl_ref, q_ref, k_ref, v_ref, *rest = refs
-    if has_scale:
-        ks_ref, vs_ref, *rest = rest
-    o_ref, m_sc, l_sc, acc = rest
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
-    sl = sl_ref[b]
-
-    @pl.when(j == 0)
-    def _init():
-        m_sc[:] = jnp.full_like(m_sc, _NEG)
-        l_sc[:] = jnp.zeros_like(l_sc)
-        acc[:] = jnp.zeros_like(acc)
-
-    @pl.when(j * bs < sl)
-    def _page():
-        q = q_ref[0, 0]                               # (t, dk)
-        k = k_ref[0, 0]                               # (bs, dk)
-        v = v_ref[0, 0]
-        if has_scale:
-            k = k.astype(jnp.float32) * _row_scales(ks_ref, pack,
-                                                    k.shape[-1])
-            v = v.astype(jnp.float32) * _row_scales(vs_ref, pack,
-                                                    v.shape[-1])
-        heads = _packed_scores(q, k) if pack \
-            else (_dot(q, k, trans_b=True),)           # (t, bs) fp32
-        # per-row causal mask: row r attends positions <= sl - t + r
-        shape = heads[0].shape
-        pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
-        mask = pos <= sl - t + row
-        corrs = []
-        pas = []
-        for hh, s in enumerate(heads):
-            s = jnp.where(mask, s, _NEG)
-            m_prev = m_sc[:, hh:hh + 1]
-            m_cur = jnp.maximum(m_prev, jnp.max(s, axis=1,
-                                                keepdims=True))
-            corr = jnp.exp2((m_prev - m_cur) * a)
-            p = jnp.exp2((s - m_cur) * a)
-            p = jnp.where(mask, p, 0.0)
-            l_sc[:, hh:hh + 1] = l_sc[:, hh:hh + 1] * corr \
-                + jnp.sum(p, axis=1, keepdims=True)
-            m_sc[:, hh:hh + 1] = m_cur
-            pas.append(p)
-            corrs.append(corr)
-        if pack:
-            corr_w = _pack_lane_cols(corrs[0], corrs[1], acc.shape[1])
-            acc[:] = acc[:] * corr_w + _packed_out(pas[0], pas[1], v)
-        else:
-            acc[:] = acc[:] * corrs[0] \
-                + _dot(pas[0].astype(v.dtype), v)
-
-    @pl.when(j == nj - 1)
-    def _finish():
-        o_ref[0, 0] = _normalized(l_sc, acc, pack).astype(o_ref.dtype)
-
-
+@_jit_driver
 def _decode_paged_multi(q4, k_cache, v_cache, block_tables, seq_lens,
-                        scale, k_scale, v_scale, pack):
-    """pallas_call driver for the t-row chunk path: grid
-    (b, head groups, pages) like the single-token driver, q/o blocks
-    carry the whole (t, dk) chunk per program."""
-    b, hk, t, dk = q4.shape
-    nb, _, bs, _ = k_cache.shape
-    mp = block_tables.shape[1]
-    a = float(scale) * _LOG2E
-    has_scale = k_scale is not None
-    g = 2 if pack else 1
-
-    def qo_spec():
-        return pl.BlockSpec((1, 1, t, dk),
-                            lambda b_, h_, j, bt, sl: (b_, h_, 0, 0),
-                            memory_space=pltpu.VMEM)
-
-    kv_spec = pl.BlockSpec(
-        (1, 1, bs, dk),
-        lambda b_, h_, j, bt, sl: (bt[b_, j], h_, 0, 0),
-        memory_space=pltpu.VMEM)
-    in_specs = [qo_spec(), kv_spec, kv_spec]
-    operands = [q4, k_cache, v_cache]
-    if has_scale:
-        sc_spec = pl.BlockSpec(
-            (1, 1, g, bs),
-            lambda b_, h_, j, bt, sl: (bt[b_, j], h_, 0, 0),
-            memory_space=pltpu.VMEM)
-        in_specs += [sc_spec, sc_spec]
-        operands += [_scale_operand(k_scale, hk, g),
-                     _scale_operand(v_scale, hk, g)]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, hk, mp),
-        in_specs=in_specs,
-        out_specs=qo_spec(),
-        scratch_shapes=[
-            pltpu.VMEM((t, 128), jnp.float32),
-            pltpu.VMEM((t, 128), jnp.float32),
-            pltpu.VMEM((t, dk), jnp.float32),
-        ])
-    return pl.pallas_call(
-        functools.partial(_decode_multi_kernel, a, bs, t, pack,
-                          has_scale),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hk, t, dk), q4.dtype),
-        name="paged_flash_decode_multi",
-        interpret=_interpret(),
-    )(block_tables, seq_lens, *operands)
+                        scale, k_scale, v_scale, pack, interpret):
+    """pallas_call driver of the t-row chunk path: (b, hk, t, dk)
+    queries through the single-token driver's program."""
+    kernel, keywords, operands = _paged_program(
+        q4, k_cache, v_cache, block_tables, seq_lens, scale, k_scale,
+        v_scale, pack, interpret)
+    return pl.pallas_call(kernel, name="paged_flash_decode_multi",
+                          **keywords)(*operands)
 
 
 def flash_decode_multi(q: jnp.ndarray, k_cache: jnp.ndarray,
@@ -448,28 +477,9 @@ def flash_decode_multi(q: jnp.ndarray, k_cache: jnp.ndarray,
     exactly 0.  Layout/packing/int8 conventions are identical to
     :func:`flash_decode`; at ``t == 1`` the two paths compute the
     same attention.  Inference-only (no VJP)."""
-    b, t, h, d = q.shape
     if scale is None:
-        scale = d ** -0.5
-    nb, hk, bs, dk = k_cache.shape
-    if v_cache.shape != k_cache.shape:
-        raise ValueError(f"k/v cache shapes differ: {k_cache.shape} "
-                         f"vs {v_cache.shape}")
-    if (k_scale is None) != (v_scale is None):
-        raise ValueError("pass both k_scale and v_scale or neither")
-    if hk == h and dk == d:
-        pack = False
-    elif h % 2 == 0 and hk == h // 2 and dk == 2 * d:
-        pack = True
-    else:
-        raise ValueError(
-            f"cache head layout {(hk, dk)} matches neither unpacked "
-            f"{(h, d)} nor head-packed {(h // 2, 2 * d)} for q "
-            f"{q.shape}")
-    for name, sc in (("k_scale", k_scale), ("v_scale", v_scale)):
-        if sc is not None and sc.shape != (nb, h, bs):
-            raise ValueError(f"{name} shape {sc.shape} != expected "
-                             f"{(nb, h, bs)} (global head order)")
+        scale = q.shape[-1] ** -0.5
+    pack = _cache_is_packed(q.shape, k_cache, v_cache, k_scale, v_scale)
     # (b, t, h, d) -> (b, hk, t, dk): the pack is a reshape on the
     # trailing axes (same free-at-decode property as the single-token
     # path), then heads move ahead of the chunk axis
@@ -478,7 +488,7 @@ def flash_decode_multi(q: jnp.ndarray, k_cache: jnp.ndarray,
     out = _decode_paged_multi(q4, k_cache, v_cache,
                               block_tables.astype(jnp.int32),
                               seq_lens.astype(jnp.int32), scale,
-                              k_scale, v_scale, pack)
+                              k_scale, v_scale, pack, _interpret())
     out = out.transpose(0, 2, 1, 3)                    # (b, t, hk, dk)
     return unpack_decode_heads(out) if pack else out
 

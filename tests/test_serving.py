@@ -79,6 +79,35 @@ def make_paged_case(b=3, h=2, d=32, nb=8, bs=8, mp=3, *, seed=0,
             ksc, vsc)
 
 
+def make_ragged_case(lens, layout, *, h=4, d=64, nb=64, bs=8, mp=4,
+                     seed=0):
+    """q + paged cache in one of the four storage layouts, one row a
+    length in ``lens``; returns (q, k, v, tables, seq_lens, scales)."""
+    b = len(lens)
+    ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(ks[0], (b, h, d), jnp.float32)
+    # the cache is the same for every seed, so that a request keeps
+    # its pages' contents from one case to the next
+    kc, vc = jax.random.normal(jax.random.PRNGKey(99),
+                               (2, nb, h, bs, d), jnp.float32)
+    scales = {}
+    if layout.startswith("int8"):
+        kc, ksc = quantize_kv_rows(kc)
+        vc, vsc = quantize_kv_rows(vc)
+        scales = dict(k_scale=ksc, v_scale=vsc)
+    if layout in ("packed", "int8_packed"):
+        kc, vc = _pack_cache(kc), _pack_cache(vc)
+    bt = np.full((b, mp), DUMP_BLOCK, np.int32)
+    pool = np.random.RandomState(seed).permutation(np.arange(1, nb))
+    nxt = 0
+    for i, n in enumerate(lens):
+        pages = -(-n // bs)
+        bt[i, :pages] = pool[nxt:nxt + pages]
+        nxt += pages
+    return (q, kc, vc, jnp.asarray(bt),
+            jnp.asarray(np.asarray(lens, np.int32)), scales)
+
+
 def _assert_close(got, want, dtype):
     tol = 2e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
@@ -170,6 +199,86 @@ class TestFlashDecodeParity:
         got = flash_decode(q, jnp.asarray(poisoned), vc, bt, sl)
         _assert_close(got, clean, jnp.float32)
 
+    # --- the page-a-step grid (every head group of a page in one
+    # program): lengths at each edge of a page, and rung invariance ---
+
+    @pytest.mark.parametrize("layout", ["unpacked", "packed", "int8",
+                                        "int8_packed"])
+    @pytest.mark.parametrize("length", ["0", "1", "bs-1", "bs", "bs+1",
+                                        "full"])
+    def test_ragged_lengths(self, layout, length):
+        bs, mp = 8, 4
+        n = {"0": 0, "1": 1, "bs-1": bs - 1, "bs": bs, "bs+1": bs + 1,
+             "full": mp * bs}[length]
+        case = make_ragged_case([n, mp * bs, 3], layout, bs=bs, mp=mp)
+        q, kc, vc, bt, sl, scales = case
+        got = flash_decode(q, kc, vc, bt, sl, **scales)
+        want = paged_attention_reference(q, kc, vc, bt, sl, **scales)
+        _assert_close(got, want, jnp.float32)
+        if n == 0:
+            assert np.all(np.asarray(got)[0] == 0.0)
+
+    @pytest.mark.parametrize("layout", ["unpacked", "packed", "int8",
+                                        "int8_packed"])
+    def test_heads_split_by_vmem_budget(self, layout, monkeypatch):
+        # a step whose working set passes the budget takes a divisor of
+        # the head groups (eight: a sublane tile of queries), same
+        # attention
+        from apex_tpu.ops import flash_decode as fd
+        case = make_ragged_case([0, 48, 13], layout, h=32, bs=8, mp=6)
+        q, kc, vc, bt, sl, scales = case
+        hk, dk = kc.shape[1], kc.shape[3]
+        whole = flash_decode(q, kc, vc, bt, sl, **scales)
+        monkeypatch.setattr(fd, "_STEP_VMEM_BYTES",
+                            fd._step_vmem_bytes(8, 1, 8, dk))
+        assert fd._heads_per_step(hk, 1, 8, dk) == 8 < hk
+        # the drivers are jitted on shapes: a budget changed under
+        # them needs a fresh trace
+        jax.clear_caches()
+        split = flash_decode(q, kc, vc, bt, sl, **scales)
+        jax.clear_caches()
+        _assert_close(split, whole, jnp.float32)
+
+    @pytest.mark.parametrize("hk,t,bs,dk,want", [
+        (8, 1, 16, 128, 8),       # the cells' decode: every head group
+        (8, 4, 16, 128, 8),       # speculative verify
+        (8, 256, 16, 128, 4),     # a 256-token chunk of the cells' cache
+        (8, 1024, 16, 128, 1),    # the 1,024 rung: one head group
+        (16, 1, 16, 128, 16),     # unpacked d=128, 16 heads
+        (64, 1, 64, 128, 16),     # 64 heads in 64-token blocks: splits
+        (24, 1, 128, 128, 8),     # 12 fits but is no sublane tile
+        (20, 1, 128, 128, 20),    # no tile divides 20: whole, over budget
+    ])
+    def test_heads_per_step(self, hk, t, bs, dk, want):
+        from apex_tpu.ops.flash_decode import _heads_per_step
+        hg = _heads_per_step(hk, t, bs, dk)
+        assert hg == want
+        assert hg == hk or hg * t % 8 == 0
+
+    @pytest.mark.parametrize("layout", ["unpacked", "packed", "int8",
+                                        "int8_packed"])
+    def test_rung_invariance(self, layout):
+        # one request, alone on the smallest rungs and as row 0 / row 31
+        # of a 32 x 64-page bucket among other rows: bitwise the same
+        # attention (gpt_decode_step's docstring promises a token
+        # stream invariant to bucket shape)
+        bs, length = 8, 16 * 8 - 3
+        small = make_ragged_case([length], layout, bs=bs, mp=16, nb=600)
+        q1, kc, vc, bt1, sl1, scales = small
+        alone = np.asarray(flash_decode(q1, kc, vc, bt1, sl1, **scales))
+        others = [5, 64 * bs, 0, 17] * 8
+        for row in (0, 31):
+            lens = others[:31]
+            lens.insert(row, length)
+            q, _, _, bt, sl, _ = make_ragged_case(
+                lens, layout, bs=bs, mp=64, nb=600, seed=row + 1)
+            q = q.at[row].set(q1[0])
+            bt = bt.at[row].set(DUMP_BLOCK).at[row, :16].set(bt1[0])
+            # the other rows' tables may name this request's blocks
+            # too: a row reads its pages, whoever else reads them
+            got = np.asarray(flash_decode(q, kc, vc, bt, sl, **scales))
+            assert np.array_equal(got[row], alone[0]), (layout, row)
+
     def test_pack_unpack_roundtrip(self):
         x = jax.random.normal(jax.random.PRNGKey(0), (5, 4, 64))
         assert jnp.array_equal(
@@ -253,6 +362,28 @@ class TestFlashDecodeMultiParity:
                                  v_scale=vsc)
         want = paged_attention_multi_reference(
             q, kq, vq, bt, sl, k_scale=ksc, v_scale=vsc)
+        _assert_close(got, want, jnp.float32)
+
+    @pytest.mark.parametrize("layout", ["unpacked", "packed", "int8",
+                                        "int8_packed"])
+    @pytest.mark.parametrize("hg", [1, 2])
+    def test_chunk_splits_heads_by_vmem_budget(self, layout, hg,
+                                               monkeypatch):
+        # a long chunk's carries pass the budget: a step takes fewer
+        # head groups (one at the cells' 1,024 rung), same attention
+        from apex_tpu.ops import flash_decode as fd
+        _, kc, vc, bt, sl, scales = make_ragged_case(
+            [0, 45, 13], layout, h=8, bs=8, mp=6)
+        t, (hk, dk) = 8, (kc.shape[1], kc.shape[3])
+        q = jax.random.normal(jax.random.PRNGKey(7), (3, t, 8, 64))
+        want = paged_attention_multi_reference(q, kc, vc, bt, sl,
+                                               **scales)
+        monkeypatch.setattr(fd, "_STEP_VMEM_BYTES",
+                            fd._step_vmem_bytes(hg, t, 8, dk))
+        assert fd._heads_per_step(hk, t, 8, dk) == hg < hk
+        jax.clear_caches()
+        got = flash_decode_multi(q, kc, vc, bt, sl, **scales)
+        jax.clear_caches()
         _assert_close(got, want, jnp.float32)
 
     def test_t1_matches_single_token_decode(self):

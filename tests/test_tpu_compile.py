@@ -134,6 +134,39 @@ _UNPACKED = ((N_BLOCKS, H, KV_BLOCK, D), BF16)
 _TABLES = (((B, PAGES), I32), ((B,), I32))
 _KV_SCALE = ((N_BLOCKS, H, KV_BLOCK), F32)
 _FLAT = ((HID * 4 * HID,), F32)          # one fc1 weight, packed flat
+# the benchmark's serving cells: batch rung 32, a pool of 2,049 blocks,
+# page rungs 64 and 16
+CELL_B, CELL_BLOCKS = 32, 2049
+_CELL_CACHE = ((CELL_BLOCKS, H // 2, KV_BLOCK, 2 * D), BF16)
+
+
+def _cell_decode_args(pages):
+    return ((((CELL_B, H, D), BF16), _CELL_CACHE, _CELL_CACHE)
+            + (((CELL_B, pages), I32), ((CELL_B,), I32)))
+
+
+def _cell_extend_args(t):
+    """One row's ``t``-token chunk against the cells' cache: what the
+    engine's warm-up compiles a chunk rung when ``prefill_chunk`` or
+    ``prefix_share`` is on (64 pages = the 1,024-token rung)."""
+    return ((((1, t, H, D), BF16), _CELL_CACHE, _CELL_CACHE)
+            + (((1, 64), I32), ((1,), I32)))
+
+
+# 64 heads of 128 in 64-token blocks: a (hk, bs, dk) block is past the
+# kernel's VMEM budget, so a step takes a divisor of the heads
+_WIDE = ((B * 4 + 1, 64, 64, 128), BF16)
+
+
+# 24 heads of 128 in 128-token blocks: 12 head groups would fit the
+# budget, but a step's queries must be a sublane tile, so it takes 8;
+# and 20 heads, which no tile divides: every head, over the budget
+_ODD = {hk: ((B * 4 + 1, hk, 128, 128), BF16) for hk in (24, 20)}
+
+
+def _odd_decode_args(hk):
+    return (((B, hk, 128), BF16), _ODD[hk], _ODD[hk],
+            ((B, 4), I32), ((B,), I32))
 
 
 def _qmm_args(m, n):
@@ -173,6 +206,13 @@ CASES = {
         _decode, (((B, H, D), BF16),
                   (_UNPACKED[0], I8), (_UNPACKED[0], I8)) + _TABLES
         + (_KV_SCALE, _KV_SCALE)),
+    "flash_decode_cell_b32_p64": (_decode, _cell_decode_args(64)),
+    "flash_decode_cell_b32_p16": (_decode, _cell_decode_args(16)),
+    "flash_decode_heads_split": (
+        _decode, (((B, 64, 128), BF16), _WIDE, _WIDE,
+                  ((B, 4), I32), ((B,), I32))),
+    "flash_decode_heads_split_24": (_decode, _odd_decode_args(24)),
+    "flash_decode_heads_whole_20": (_decode, _odd_decode_args(20)),
     "flash_decode_multi_t4_packed": (
         _decode_multi, (((B, 4, H, D), BF16), _PACKED, _PACKED)
         + _TABLES),
@@ -180,6 +220,9 @@ CASES = {
         _decode_multi, (((B, 4, H, D), BF16),
                         (_PACKED[0], I8), (_PACKED[0], I8)) + _TABLES
         + (_KV_SCALE, _KV_SCALE)),
+    "flash_decode_multi_cell_t256": (_decode_multi, _cell_extend_args(256)),
+    "flash_decode_multi_cell_t1024": (_decode_multi,
+                                      _cell_extend_args(1024)),
     # off the 345M path (MoE routing; optimizer sweeps, off by default)
     "moe_route_dispatch": (_moe_route, (((S, HID), BF16),   # one prompt
                                         ((S, 8), F32))),    # 8 experts
