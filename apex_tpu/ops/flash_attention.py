@@ -229,6 +229,14 @@ def _tri_mask(shape, q_off, k_off):
     return q_pos >= k_pos
 
 
+def _window_mask(shape, q_off, k_off, window):
+    """q_pos - k_pos < window: with the causal mask, each query keeps
+    the ``window`` keys ending at its own position."""
+    q_pos = q_off + jax.lax.broadcasted_iota(jnp.int32, (shape[0], 1), 0)
+    k_pos = k_off + jax.lax.broadcasted_iota(jnp.int32, (1, shape[1]), 1)
+    return q_pos - k_pos < window
+
+
 def _kcol_mask(shape, k_off, sk):
     k_pos = k_off + jax.lax.broadcasted_iota(jnp.int32, (1, shape[1]), 1)
     return jnp.broadcast_to(k_pos < sk, shape)
@@ -300,7 +308,7 @@ def rand_keep_global(shape, seed, rate, batch_offset=0, head_offset=0,
 # --- forward ---------------------------------------------------------------
 
 def _fwd_single_kernel(scale, a, causal, has_kvm, has_off, kpad, sq, sk,
-                       *refs, drop=0.0, h=1, pack=False):
+                       *refs, drop=0.0, h=1, pack=False, window=None):
     """Whole-(padded)-sequence-in-one-block forward: plain softmax, no
     online-correction carries (the default 1024 blocks put GPT s=1024
     and BERT s=512 here).  ``has_off``: a leading SMEM ref carries
@@ -337,6 +345,8 @@ def _fwd_single_kernel(scale, a, causal, has_kvm, has_off, kpad, sq, sk,
     mask = None
     if causal:
         mask = _tri_mask(heads[0].shape, qoff, koff)
+    if window is not None:
+        mask = mask & _window_mask(heads[0].shape, qoff, koff, window)
     if kpad and not has_kvm:
         # _kvm8 zero-pads, so kv_mask already masks pad columns
         km = _kcol_mask(heads[0].shape, 0, sk)
@@ -410,7 +420,7 @@ def _fwd_single_kernel(scale, a, causal, has_kvm, has_off, kpad, sq, sk,
 
 
 def _fwd_kernel(scale, a, causal, has_kvm, has_off, kpad, sq, sk, bq, bk,
-                *refs, drop=0.0, h=1, pack=False):
+                *refs, drop=0.0, h=1, pack=False, window=None):
     if has_off:
         off_ref, *refs = refs
         qoff, koff = off_ref[0], off_ref[1]
@@ -439,6 +449,11 @@ def _fwd_kernel(scale, a, causal, has_kvm, has_off, kpad, sq, sk, bq, bk,
 
     run = (j * bk + koff <= i * bq + qoff + bq - 1) if causal \
         else (j >= 0)
+    if window is not None:
+        # a key block wholly behind the window of the block's first
+        # query is skipped like one wholly in the causal future (and
+        # not fetched: the index map clamps j into the band)
+        run = run & (j * bk + koff + bk - 1 > i * bq + qoff - window)
 
     @pl.when(run)
     def _block():
@@ -452,6 +467,9 @@ def _fwd_kernel(scale, a, causal, has_kvm, has_off, kpad, sq, sk, bq, bk,
         if causal:
             mask = _tri_mask(heads[0].shape, i * bq + qoff,
                              j * bk + koff)
+        if window is not None:
+            mask = mask & _window_mask(heads[0].shape, i * bq + qoff,
+                                       j * bk + koff, window)
         if kpad and not has_kvm:
             # _kvm8 zero-pads, so kv_mask already masks pad columns
             km = _kcol_mask(heads[0].shape, j * bk, sk)
@@ -468,7 +486,7 @@ def _fwd_kernel(scale, a, causal, has_kvm, has_off, kpad, sq, sk, bq, bk,
                                 jnp.max(s, axis=1, keepdims=True))
             corr = jnp.exp2((m_prev - m_cur) * a)
             p = jnp.exp2((s - m_cur) * a)
-            if has_kvm or (has_off and causal):
+            if has_kvm or (has_off and causal) or window is not None:
                 # rows with every key masked so far keep m_cur = _NEG
                 # and (s - m_cur) = 0 at masked entries — zero p
                 # explicitly so such rows sum to l = 0 and emit exactly
@@ -554,10 +572,14 @@ def _kvm8(kv_mask, b, psk, bk):
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_mask=None,
-               offsets=None, drop=0.0, dsalt=None):
+               offsets=None, drop=0.0, dsalt=None, window=None):
     b, h, sq, d = q.shape
     sk = k.shape[2]
-    pack = _use_head_packing(h, d)
+    # grouped-query attention: ``groups`` query heads read each of k/v's
+    # heads (query head j reads head j // groups), through the k/v
+    # index maps alone -- no repeated copy of k or v is made
+    groups = h // k.shape[1]
+    pack = groups == 1 and _use_head_packing(h, d)
     if pack:
         # d=64 head-pair packing (module note): adjacent heads share a
         # 128-lane tile; h counts PAIRS below, lse carries 2 sublane
@@ -569,8 +591,8 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_mask=None,
     bq = min(block_q, max(8, sq))
     bk = min(block_k, max(128, sk))
     q3 = _pad_to(q.reshape(b * h, sq, d), 1, bq)
-    k3 = _pad_to(k.reshape(b * h, sk, d), 1, bk)
-    v3 = _pad_to(v.reshape(b * h, sk, d), 1, bk)
+    k3 = _pad_to(k.reshape(b * h // groups, sk, d), 1, bk)
+    v3 = _pad_to(v.reshape(b * h // groups, sk, d), 1, bk)
     bh, psq, _ = q3.shape
     psk = k3.shape[1]
     nq, nk = psq // bq, psk // bk
@@ -593,7 +615,9 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_mask=None,
     if nq == 1 and nk == 1:
         qb_spec = pl.BlockSpec((1, psq, d), lambda b_: (b_, 0, 0),
                                memory_space=pltpu.VMEM)
-        kb_spec = pl.BlockSpec((1, psk, d), lambda b_: (b_, 0, 0),
+        kb_spec = pl.BlockSpec((1, psk, d),
+                               (lambda b_: (b_, 0, 0)) if groups == 1
+                               else (lambda b_: (b_ // groups, 0, 0)),
                                memory_space=pltpu.VMEM)
         lse_spec = pl.BlockSpec((1, 1, 8 * g, bq),
                                 lambda b_: (b_, 0, 0, 0),
@@ -614,7 +638,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_mask=None,
         o, lse8 = pl.pallas_call(
             functools.partial(_fwd_single_kernel, scale, a, causal,
                               has_kvm, has_off, kpad, sq, sk,
-                              drop=drop, h=h, pack=pack),
+                              drop=drop, h=h, pack=pack, window=window),
             grid=(bh,),
             in_specs=in_specs,
             out_specs=[qb_spec, lse_spec],
@@ -629,8 +653,18 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_mask=None,
 
     q_spec = pl.BlockSpec((1, bq, d), lambda b_, i, j: (b_, i, 0),
                           memory_space=pltpu.VMEM)
-    k_spec = pl.BlockSpec((1, bk, d), lambda b_, i, j: (b_, j, 0),
-                          memory_space=pltpu.VMEM)
+    if groups == 1 and window is None:
+        def k_map(b_, i, j):
+            return (b_, j, 0)
+    else:
+        def k_map(b_, i, j):
+            if window is not None:
+                # hold j inside the band of key blocks this query block
+                # attends to: a block outside it is not fetched again
+                j = jnp.clip(j, jnp.maximum(i * bq - window + 1, 0) // bk,
+                             (i * bq + bq - 1) // bk)
+            return (b_ // groups, j, 0)
+    k_spec = pl.BlockSpec((1, bk, d), k_map, memory_space=pltpu.VMEM)
     lse_spec = pl.BlockSpec((1, 1, 8 * g, bq),
                             lambda b_, i, j: (b_, i, 0, 0),
                             memory_space=pltpu.VMEM)
@@ -651,7 +685,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_mask=None,
     o, lse8 = pl.pallas_call(
         functools.partial(_fwd_kernel, scale, a, causal, has_kvm,
                           has_off, kpad, sq, sk, bq, bk,
-                          drop=drop, h=h, pack=pack),
+                          drop=drop, h=h, pack=pack, window=window),
         grid=(bh, nq, nk),
         in_specs=in_specs,
         out_specs=[q_spec, lse_spec],
@@ -1363,7 +1397,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     causal: bool = False,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K,
-                    kv_mask: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                    kv_mask: Optional[jnp.ndarray] = None,
+                    window: Optional[int] = None) -> jnp.ndarray:
     """Fused attention: softmax(q k^T * scale [masked]) v.
 
     Shapes: q (b, h, sq, d); k, v (b, h, sk, d).  ``scale`` defaults to
@@ -1378,6 +1413,13 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     the head-packed full-width kernels — two heads per 128-lane MXU
     tile, ~2x the half-width rate; ``APEX_TPU_FLASH_PACK_D64=0`` or
     :func:`set_head_packing` force the old path (module note).
+
+    Forward only (serving's prefill): ``k``/``v`` with fewer heads than
+    ``q`` is grouped-query attention (query head ``j`` reads head
+    ``j // (h // hk)``), and ``window`` with ``causal`` keeps for each
+    query the ``window`` keys ending at its own position, whole key
+    blocks outside that band neither fetched nor computed.  Neither
+    has a backward pass yet.
     """
     from ._context import in_manual_axis_context
     from .._autocast_ctx import autocast_compute_dtype
@@ -1391,7 +1433,15 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         q, k, v = (x.astype(act) for x in (q, k, v))
     if in_manual_axis_context(q, k, v):
         return mha_reference(q, k, v, scale=scale, causal=causal,
-                             kv_mask=kv_mask)
+                             kv_mask=kv_mask, window=window)
+    if window is not None or k.shape[1] != q.shape[1]:
+        if kv_mask is not None or (window is not None and not causal):
+            raise ValueError("grouped heads and a window run the causal "
+                             "or the unmasked forward only")
+        if scale is None:
+            scale = q.shape[-1] ** -0.5
+        return _flash_fwd(q, k, v, scale, causal, block_q, block_k,
+                          window=window)[0]
     if kv_mask is not None:
         return _flash_attention_masked(q, k, v,
                                        kv_mask.astype(jnp.float32),
@@ -2744,17 +2794,25 @@ def _fallback_dropout_attention(q, k, v, scale, causal, kv_mask, rate,
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
-def mha_reference(q, k, v, scale=None, causal=False, kv_mask=None):
+def mha_reference(q, k, v, scale=None, causal=False, kv_mask=None,
+                  window=None):
     """Unfused reference (the [b,h,sq,sk]-materializing baseline the
     reference's standalone GPT uses) — for parity tests and benchmarks.
-    ``kv_mask`` (b, sk): True/nonzero = attend."""
+    ``kv_mask`` (b, sk): True/nonzero = attend.  ``k``/``v`` of fewer
+    heads are repeated for the query heads that read them; ``window``
+    (with ``causal``) keeps the ``window`` keys ending at the query."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
+    if k.shape[1] != q.shape[1]:
+        k, v = (jnp.repeat(x, q.shape[1] // x.shape[1], axis=1)
+                for x in (k, v))
     s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
                    k.astype(jnp.float32)) * scale
     sq, sk = s.shape[-2:]
     if causal:
         mask = jnp.tril(jnp.ones((sq, sk), bool))
+        if window is not None:
+            mask = mask & ~jnp.tril(jnp.ones((sq, sk), bool), -window)
         s = jnp.where(mask, s, _NEG)
     if kv_mask is not None:
         s = jnp.where(kv_mask[:, None, None, :].astype(bool), s, _NEG)

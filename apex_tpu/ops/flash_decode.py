@@ -77,6 +77,18 @@ served cache every group a step up to a 128-token chunk, four at 256,
 one at the 1,024-token rung (where the tile is the per-head program's
 own ``(t, bs)``, and nothing of another head is computed).
 
+Grouped-query attention and a causal window (serving's ``rope_moe``
+family) are the same program again.  An unpacked cache of fewer heads
+than ``q`` has: the ``h // hk`` query heads that read a cache head ride
+as that head group's query rows (``groups * t`` of them), so a page is
+still fetched once and scored in one matmul.  ``window``: each row
+keeps the newest ``window`` positions; the page axis of the grid is
+then as long as the pages a window can straddle (33 of 16 for 512), not
+as the page rung, and starts at the row's first page in the window, so
+the pages behind it cost no fetch, no compute and no grid step (a
+skipped step that still has its index map to evaluate took 0.16 us on a
+v5e, 544 of them a row more than the 33 live pages; PERF.md, PR 29).
+
 Int8 KV (weight-only storage; APEX_TPU_SERVE_KV_DTYPE=int8): k/v store
 as int8 with **per-row** (per cached token, per head) fp32 scales, so
 appending a token never requantizes history; the kernel dequantizes
@@ -174,23 +186,37 @@ def _heads_per_step(hk: int, t: int, bs: int, dk: int) -> int:
     return max(fits) if fits else min(ok)
 
 
-def _own_page_mask(shape, t, bs, page0, sl):
-    """The (hg*t, hg*bs) score tile's live entries.  Row ``r`` is query
-    ``r % t`` of head group ``r // t``; column ``c`` is slot ``c % bs``
+def _own_page_mask(shape, t, bs, page0, sl, groups=1, window=None):
+    """The (hg*groups*t, hg*bs) score tile's live entries.  A head
+    group's rows are its ``groups`` query heads (grouped-query
+    attention: several query heads read one cache head), ``t`` queries
+    each: row ``r`` is query ``r % t`` of query head ``r // t``, whose
+    cache head is ``r // (groups * t)``; column ``c`` is slot ``c % bs``
     of head group ``c // bs``'s page.  Live = the query's own head
     group (the off-diagonal blocks are other heads' products, computed
     because one full-width matmul is cheaper than ``hg`` slivers, and
     never used) AND the causal rule of a contiguous chunk ending at
-    ``sl``: position <= sl - t + query — at ``t == 1``, ``pos < sl``."""
+    ``sl``: position <= sl - t + query -- at ``t == 1``, ``pos < sl``
+    -- AND, under a ``window``, position > that bound - window (the
+    window counts the query's own position)."""
     row = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
     col = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
-    if t == 1:
+    rows = groups * t
+    if rows == 1:
         head, query = row, 0
     else:
-        head = jax.lax.div(row, t)
-        query = row - head * t
+        head = jax.lax.div(row, rows)
+        if t == 1:
+            query = 0
+        elif groups == 1:
+            query = row - head * t
+        else:
+            query = row - jax.lax.div(row, t) * t
     slot = col - head * bs        # in [0, bs): the row's own head group
-    return (slot >= 0) & (slot < bs) & (page0 + slot <= sl - t + query)
+    live = (slot >= 0) & (slot < bs) & (page0 + slot <= sl - t + query)
+    if window is not None:
+        live = live & (page0 + slot > sl - t + query - window)
+    return live
 
 
 def _row_scales(s_ref, hg, pack, width):
@@ -226,10 +252,11 @@ def _scale_operand(scale, hk, g):
     return scale.reshape(nb, hk, g, bs)
 
 
-def _paged_kernel(a, bs, t, hg, pack, has_scale, *refs):
+def _paged_kernel(a, bs, t, hg, pack, has_scale, groups, window, *refs):
     """One (batch row, head-group chunk, page) program: ALL ``hg`` head
-    groups of the row's page, ``t`` query rows each (``t == 1``: plain
-    decode).  Scalar-prefetch refs lead: block tables (consumed by the
+    groups of the row's page, ``groups`` query heads of ``t`` query rows
+    each (``t == 1``: plain decode; ``groups == 1``: as many query as
+    cache heads).  Scalar-prefetch refs lead: block tables (consumed by the
     index maps, unused here) and seq_lens.  The ``hg`` independent
     online-softmax chains are the row blocks of one (hg*t, hg*bs)
     score tile — at the serving shape (8 groups x 16 slots) exactly one
@@ -256,7 +283,16 @@ def _paged_kernel(a, bs, t, hg, pack, has_scale, *refs):
     # Its block-table entry is the dump page, as is its neighbours', so
     # the pipeline re-fetches nothing either: what the bucketed page
     # rung costs a short row is bare grid steps (~10 ns each on v5e).
-    @pl.when(j * bs < sl)
+    # Under a window the grid is only as long as a window's pages and
+    # starts at the first page the chunk's oldest query (position
+    # sl - t) still sees: the pages behind it are never visited.
+    if window is None:
+        page = j
+    else:
+        page = jnp.maximum(sl - t - window + 1, 0) // bs + j
+    live = page * bs < sl
+
+    @pl.when(live)
     def _page():
         dk = acc.shape[1]
         q = q_ref[0]                                  # (hg*t, dk)
@@ -270,7 +306,8 @@ def _paged_kernel(a, bs, t, hg, pack, has_scale, *refs):
             v = v.astype(jnp.float32) * _row_scales(vs_ref, hg, pack, dk)
         heads = _packed_scores(q, k) if pack \
             else (_dot(q, k, trans_b=True),)           # (hg*t, hg*bs) fp32
-        mask = _own_page_mask(heads[0].shape, t, bs, j * bs, sl)
+        mask = _own_page_mask(heads[0].shape, t, bs, page * bs, sl,
+                              groups, window)
         pas, corrs = [], []
         for hh, s in enumerate(heads):
             s = jnp.where(mask, s, _NEG)
@@ -299,43 +336,61 @@ def _paged_kernel(a, bs, t, hg, pack, has_scale, *refs):
     @pl.when(j == nj - 1)
     def _finish():
         out = _normalized(l_sc, acc, pack).astype(o_ref.dtype)
-        for hh in range(hg):
+        for hh in range(hg * groups):              # query heads
             o_ref[0, hh] = out[hh * t:(hh + 1) * t]
 
 
 def _paged_program(q4, k_cache, v_cache, block_tables, seq_lens, scale,
-                   k_scale, v_scale, pack, interpret):
+                   k_scale, v_scale, pack, window, interpret):
     """What both pallas_call drivers share: ``(kernel, keywords,
-    operands)`` for q4 = (b, hk, t, dk).  Grid (b, hk // hg, pages)
-    with ``hg`` = :func:`_heads_per_step` (all ``hk`` at every served
-    decode shape, so the middle axis is 1); block tables + seq_lens are
-    scalar-prefetched so the k/v index maps read the page id directly —
-    the gather IS the pipeline's block fetch, one contiguous
-    (hg, bs, dk) page a step."""
-    b, hk, t, dk = q4.shape
-    nb, _, bs, _ = k_cache.shape
+    operands)`` for q4 = (b, h, t, dk), ``h`` the (packed) query heads:
+    ``groups = h // hk`` of them read each of the cache's ``hk`` heads
+    and ride as ``groups * t`` query rows of that head group.  Grid
+    (b, hk // hg, pages) with ``hg`` = :func:`_heads_per_step` (all
+    ``hk`` at every served decode shape, so the middle axis is 1);
+    block tables + seq_lens are scalar-prefetched so the k/v index maps
+    read the page id directly -- the gather IS the pipeline's block
+    fetch, one contiguous (hg, bs, dk) page a step.  Under a ``window``
+    the page axis is as long as the pages a window can straddle, not as
+    the page rung, and step ``j`` is the ``j``-th page from the first
+    one in the row's window: pages behind the window are neither
+    fetched nor computed nor paid a grid step for."""
+    b, h, t, dk = q4.shape
+    nb, hk, bs, _ = k_cache.shape
+    groups = h // hk
+    rows = groups * t
     mp = block_tables.shape[1]
     a = float(scale) * _LOG2E
     has_scale = k_scale is not None
     g = 2 if pack else 1
-    hg = _heads_per_step(hk, t, bs, dk)
+    hg = _heads_per_step(hk, rows, bs, dk)
 
     def row_map(b_, h_, j, bt, sl):
         return (b_, h_, 0, 0)
 
-    def page_map(b_, h_, j, bt, sl):
-        return (bt[b_, j], h_, 0, 0)
+    if window is None:
+        steps = mp
 
-    # q rides flat, (b, hk*t, dk), so a step's queries are one
-    # (hg*t, dk) tile with no in-kernel relayout; o keeps the 4-D
-    # (b, hk, t, dk) — a block's trailing (t, dk) is the array's own
+        def page_map(b_, h_, j, bt, sl):
+            return (bt[b_, j], h_, 0, 0)
+    else:
+        # window + t - 1 positions straddle at most this many pages
+        steps = min(mp, (window + t - 2) // bs + 2)
+
+        def page_map(b_, h_, j, bt, sl):
+            first = jnp.maximum(sl[b_] - t - window + 1, 0) // bs
+            return (bt[b_, jnp.minimum(first + j, mp - 1)], h_, 0, 0)
+
+    # q rides flat, (b, h*t, dk), so a step's queries are one
+    # (hg*rows, dk) tile with no in-kernel relayout; o keeps the 4-D
+    # (b, h, t, dk) -- a block's trailing (t, dk) is the array's own
     kv_spec = pl.BlockSpec((1, hg, bs, dk), page_map,
                            memory_space=pltpu.VMEM)
-    in_specs = [pl.BlockSpec((1, hg * t, dk),
+    in_specs = [pl.BlockSpec((1, hg * rows, dk),
                              lambda b_, h_, j, bt, sl: (b_, h_, 0),
                              memory_space=pltpu.VMEM),
                 kv_spec, kv_spec]
-    operands = [q4.reshape(b, hk * t, dk), k_cache, v_cache]
+    operands = [q4.reshape(b, h * t, dk), k_cache, v_cache]
     if has_scale:
         sc_spec = pl.BlockSpec((1, hg, g, bs), page_map,
                                memory_space=pltpu.VMEM)
@@ -344,20 +399,20 @@ def _paged_program(q4, k_cache, v_cache, block_tables, seq_lens, scale,
                      _scale_operand(v_scale, hk, g)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hk // hg, mp),
+        grid=(b, hk // hg, steps),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, hg, t, dk), row_map,
+        out_specs=pl.BlockSpec((1, hg * groups, t, dk), row_map,
                                memory_space=pltpu.VMEM),
         scratch_shapes=[
-            pltpu.VMEM((hg * t, 128), jnp.float32),
-            pltpu.VMEM((hg * t, 128), jnp.float32),
-            pltpu.VMEM((hg * t, dk), jnp.float32),
+            pltpu.VMEM((hg * rows, 128), jnp.float32),
+            pltpu.VMEM((hg * rows, 128), jnp.float32),
+            pltpu.VMEM((hg * rows, dk), jnp.float32),
         ])
     kernel = functools.partial(_paged_kernel, a, bs, t, hg, pack,
-                               has_scale)
+                               has_scale, groups, window)
     keywords = dict(
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, hk, t, dk), q4.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, t, dk), q4.dtype),
         interpret=interpret)
     return kernel, keywords, (block_tables, seq_lens, *operands)
 
@@ -369,26 +424,29 @@ def _paged_program(q4, k_cache, v_cache, block_tables, seq_lens, scale,
 # cache hit or not (PERF.md, PR 28: 24 layers x 8 decode buckets).
 # ``interpret`` is an argument so that it keys the trace.
 _jit_driver = functools.partial(
-    jax.jit, static_argnames=("scale", "pack", "interpret"))
+    jax.jit, static_argnames=("scale", "pack", "window", "interpret"))
 
 
 @_jit_driver
 def _decode_paged(q3, k_cache, v_cache, block_tables, seq_lens, scale,
-                  k_scale, v_scale, pack, interpret):
-    """The single-token pallas_call driver: (b, hk, dk) queries are the
-    ``t == 1`` chunk.  The call's output stays ``(b, hk, 1, dk)`` and
-    its first operand the block table as the engine built it."""
+                  k_scale, v_scale, pack, window, interpret):
+    """The single-token pallas_call driver: (b, h, dk) queries are the
+    ``t == 1`` chunk.  The call's output stays ``(b, h, 1, dk)``, ``h``
+    the query heads, and its first operand the block table as the
+    engine built it."""
     kernel, keywords, operands = _paged_program(
         q3[:, :, None, :], k_cache, v_cache, block_tables, seq_lens,
-        scale, k_scale, v_scale, pack, interpret)
+        scale, k_scale, v_scale, pack, window, interpret)
     return pl.pallas_call(kernel, name="paged_flash_decode",
                           **keywords)(*operands)[:, :, 0, :]
 
 
 def _cache_is_packed(q_shape, k_cache, v_cache, k_scale, v_scale):
     """Validate the cache (and scales) against q's trailing (h, d) and
-    say whether it is stored head-packed — the cache layout decides the
-    kernel path."""
+    say whether it is stored head-packed -- the cache layout decides the
+    kernel path.  Unpacked, the cache may hold fewer heads than q has:
+    ``h // hk`` query heads then read each cache head (query head ``j``
+    reads cache head ``j // (h // hk)``)."""
     h, d = q_shape[-2:]
     nb, hk, bs, dk = k_cache.shape
     if v_cache.shape != k_cache.shape:
@@ -396,19 +454,20 @@ def _cache_is_packed(q_shape, k_cache, v_cache, k_scale, v_scale):
                          f"vs {v_cache.shape}")
     if (k_scale is None) != (v_scale is None):
         raise ValueError("pass both k_scale and v_scale or neither")
-    if hk == h and dk == d:
+    if dk == d and h % hk == 0:
         pack = False
     elif h % 2 == 0 and hk == h // 2 and dk == 2 * d:
         pack = True
     else:
         raise ValueError(
             f"cache head layout {(hk, dk)} matches neither unpacked "
-            f"{(h, d)} nor head-packed {(h // 2, 2 * d)} for q "
-            f"{q_shape}")
+            f"{(h, d)} (or a divisor of its heads) nor head-packed "
+            f"{(h // 2, 2 * d)} for q {q_shape}")
+    kv_heads = 2 * hk if pack else hk
     for name, sc in (("k_scale", k_scale), ("v_scale", v_scale)):
-        if sc is not None and sc.shape != (nb, h, bs):
+        if sc is not None and sc.shape != (nb, kv_heads, bs):
             raise ValueError(f"{name} shape {sc.shape} != expected "
-                             f"{(nb, h, bs)} (global head order)")
+                             f"{(nb, kv_heads, bs)} (global head order)")
     return pack
 
 
@@ -417,7 +476,8 @@ def flash_decode(q: jnp.ndarray, k_cache: jnp.ndarray,
                  seq_lens: jnp.ndarray, *,
                  scale: Optional[float] = None,
                  k_scale: Optional[jnp.ndarray] = None,
-                 v_scale: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+                 v_scale: Optional[jnp.ndarray] = None,
+                 window: Optional[int] = None) -> jnp.ndarray:
     """Single-query attention over a block-paged KV cache.
 
     ``q`` is (b, h, d) — one query token per sequence; the cache is
@@ -427,8 +487,13 @@ def flash_decode(q: jnp.ndarray, k_cache: jnp.ndarray,
     truth).  ``block_tables`` (b, max_pages) int32 names each row's
     pages; ``seq_lens`` (b,) bounds the attended positions, 0 marking
     an inactive row (output exactly 0).  ``k_scale``/``v_scale``
-    (nb, h, bs) fp32 arm the int8 weight-only dequant path.  Returns
-    (b, h, d) in q's dtype.  Inference-only (no VJP).
+    (nb, h, bs) fp32 arm the int8 weight-only dequant path.  An
+    unpacked cache of fewer heads than ``q`` is grouped-query
+    attention: query head ``j`` reads cache head ``j // (h // hk)``.
+    ``window`` (static) keeps the newest ``window`` positions of each
+    row, the query's own included; pages wholly behind it are neither
+    fetched nor computed.  Returns (b, h, d) in q's dtype.
+    Inference-only (no VJP).
     """
     if scale is None:
         scale = q.shape[-1] ** -0.5
@@ -437,7 +502,7 @@ def flash_decode(q: jnp.ndarray, k_cache: jnp.ndarray,
     out = _decode_paged(q3, k_cache, v_cache,
                         block_tables.astype(jnp.int32),
                         seq_lens.astype(jnp.int32), scale,
-                        k_scale, v_scale, pack, _interpret())
+                        k_scale, v_scale, pack, window, _interpret())
     return unpack_decode_heads(out) if pack else out
 
 
@@ -445,12 +510,12 @@ def flash_decode(q: jnp.ndarray, k_cache: jnp.ndarray,
 
 @_jit_driver
 def _decode_paged_multi(q4, k_cache, v_cache, block_tables, seq_lens,
-                        scale, k_scale, v_scale, pack, interpret):
-    """pallas_call driver of the t-row chunk path: (b, hk, t, dk)
+                        scale, k_scale, v_scale, pack, window, interpret):
+    """pallas_call driver of the t-row chunk path: (b, h, t, dk)
     queries through the single-token driver's program."""
     kernel, keywords, operands = _paged_program(
         q4, k_cache, v_cache, block_tables, seq_lens, scale, k_scale,
-        v_scale, pack, interpret)
+        v_scale, pack, window, interpret)
     return pl.pallas_call(kernel, name="paged_flash_decode_multi",
                           **keywords)(*operands)
 
@@ -460,8 +525,8 @@ def flash_decode_multi(q: jnp.ndarray, k_cache: jnp.ndarray,
                        seq_lens: jnp.ndarray, *,
                        scale: Optional[float] = None,
                        k_scale: Optional[jnp.ndarray] = None,
-                       v_scale: Optional[jnp.ndarray] = None
-                       ) -> jnp.ndarray:
+                       v_scale: Optional[jnp.ndarray] = None,
+                       window: Optional[int] = None) -> jnp.ndarray:
     """Multi-token paged attention: ``t`` contiguous query tokens per
     sequence against the block-paged cache — the speculative-verify /
     chunked-prefill counterpart of :func:`flash_decode`.
@@ -474,9 +539,10 @@ def flash_decode_multi(q: jnp.ndarray, k_cache: jnp.ndarray,
     causal rule is per row: attend to positions ``<= seq_lens[b] - t
     + r``.  Rows whose position is negative (front padding of a short
     chunk) and rows of an inactive sequence (``seq_lens == 0``) emit
-    exactly 0.  Layout/packing/int8 conventions are identical to
-    :func:`flash_decode`; at ``t == 1`` the two paths compute the
-    same attention.  Inference-only (no VJP)."""
+    exactly 0.  Layout/packing/int8 conventions, grouped query heads
+    and ``window`` (each row keeps the ``window`` positions ending at
+    its own) are identical to :func:`flash_decode`; at ``t == 1`` the
+    two paths compute the same attention.  Inference-only (no VJP)."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     pack = _cache_is_packed(q.shape, k_cache, v_cache, k_scale, v_scale)
@@ -488,7 +554,8 @@ def flash_decode_multi(q: jnp.ndarray, k_cache: jnp.ndarray,
     out = _decode_paged_multi(q4, k_cache, v_cache,
                               block_tables.astype(jnp.int32),
                               seq_lens.astype(jnp.int32), scale,
-                              k_scale, v_scale, pack, _interpret())
+                              k_scale, v_scale, pack, window,
+                              _interpret())
     out = out.transpose(0, 2, 1, 3)                    # (b, t, hk, dk)
     return unpack_decode_heads(out) if pack else out
 
@@ -512,24 +579,32 @@ def dequantize_kv(cache: jnp.ndarray,
     return cache.astype(jnp.float32) * s
 
 
+def _per_head_cache(cache, scale, h, d):
+    """(nb, h, bs, d) float view of a stored cache for ``h`` query
+    heads of size ``d``: dequantized, head pairs unpacked, and each
+    cache head repeated for the query heads that read it."""
+    cache = dequantize_kv(cache, scale)
+    if cache.shape[-1] != d:   # packed storage -> per-head view
+        cache = unpack_decode_heads(
+            cache.transpose(0, 2, 1, 3)).transpose(0, 2, 1, 3)
+    return jnp.repeat(cache, h // cache.shape[1], axis=1) \
+        if cache.shape[1] != h else cache
+
+
 def paged_attention_reference(q, k_cache, v_cache, block_tables,
                               seq_lens, scale=None, k_scale=None,
-                              v_scale=None):
+                              v_scale=None, window=None):
     """Dense jnp twin of :func:`flash_decode`: gather every row's pages
-    into contiguous (b, h, pages*bs, d) k/v, mask by global position,
-    fp32 softmax.  The parity oracle and the naive full-gather decode
-    baseline the serving bench row compares the kernel against."""
+    into contiguous (b, h, pages*bs, d) k/v, mask by global position
+    (and by the ``window``), fp32 softmax.  The parity oracle and the
+    naive full-gather decode baseline the serving bench row compares
+    the kernel against."""
     b, h, d = q.shape
     if scale is None:
         scale = d ** -0.5
-    nb, hk, bs, dk = k_cache.shape
-    k_cache = dequantize_kv(k_cache, k_scale)
-    v_cache = dequantize_kv(v_cache, v_scale)
-    if hk != h:   # packed storage -> per-head view
-        k_cache = unpack_decode_heads(
-            k_cache.transpose(0, 2, 1, 3)).transpose(0, 2, 1, 3)
-        v_cache = unpack_decode_heads(
-            v_cache.transpose(0, 2, 1, 3)).transpose(0, 2, 1, 3)
+    bs = k_cache.shape[2]
+    k_cache = _per_head_cache(k_cache, k_scale, h, d)
+    v_cache = _per_head_cache(v_cache, v_scale, h, d)
     mp = block_tables.shape[1]
     # (b, mp, h, bs, d) -> (b, h, mp*bs, d)
     k = k_cache[block_tables].transpose(0, 2, 1, 3, 4) \
@@ -540,6 +615,8 @@ def paged_attention_reference(q, k_cache, v_cache, block_tables,
                    k.astype(jnp.float32)) * scale
     pos = jnp.arange(mp * bs, dtype=jnp.int32)[None, None, :]
     mask = pos < seq_lens[:, None, None]
+    if window is not None:
+        mask = mask & (pos >= seq_lens[:, None, None] - window)
     s = jnp.where(mask, s, -jnp.inf)
     m = jnp.max(s, axis=-1, keepdims=True)
     m = jnp.where(jnp.isfinite(m), m, 0.0)             # inactive rows
@@ -554,23 +631,19 @@ def paged_attention_reference(q, k_cache, v_cache, block_tables,
 
 def paged_attention_multi_reference(q, k_cache, v_cache, block_tables,
                                     seq_lens, scale=None, k_scale=None,
-                                    v_scale=None):
+                                    v_scale=None, window=None):
     """Dense jnp twin of :func:`flash_decode_multi`: gather every
     row's pages, mask per query row by the contiguous-chunk causal
-    rule (row ``r`` attends positions ``<= seq_lens[b] - t + r``),
-    fp32 softmax.  The parity oracle for the multi-token kernel and
-    the dense verify/chunk baseline (``decode_attention="reference"``)."""
+    rule (row ``r`` attends positions ``<= seq_lens[b] - t + r``, and
+    under a ``window`` only the newest ``window`` of them), fp32
+    softmax.  The parity oracle for the multi-token kernel and the
+    dense verify/chunk baseline (``decode_attention="reference"``)."""
     b, t, h, d = q.shape
     if scale is None:
         scale = d ** -0.5
-    nb, hk, bs, dk = k_cache.shape
-    k_cache = dequantize_kv(k_cache, k_scale)
-    v_cache = dequantize_kv(v_cache, v_scale)
-    if hk != h:
-        k_cache = unpack_decode_heads(
-            k_cache.transpose(0, 2, 1, 3)).transpose(0, 2, 1, 3)
-        v_cache = unpack_decode_heads(
-            v_cache.transpose(0, 2, 1, 3)).transpose(0, 2, 1, 3)
+    bs = k_cache.shape[2]
+    k_cache = _per_head_cache(k_cache, k_scale, h, d)
+    v_cache = _per_head_cache(v_cache, v_scale, h, d)
     mp = block_tables.shape[1]
     k = k_cache[block_tables].transpose(0, 2, 1, 3, 4) \
         .reshape(b, h, mp * bs, d)
@@ -582,6 +655,8 @@ def paged_attention_multi_reference(q, k_cache, v_cache, block_tables,
     qpos = (seq_lens[:, None].astype(jnp.int32) - t
             + jnp.arange(t, dtype=jnp.int32)[None, :])   # (b, t)
     mask = pos <= qpos[:, :, None, None]
+    if window is not None:
+        mask = mask & (pos > qpos[:, :, None, None] - window)
     s = jnp.where(mask, s, -jnp.inf)
     m = jnp.max(s, axis=-1, keepdims=True)
     m = jnp.where(jnp.isfinite(m), m, 0.0)

@@ -295,6 +295,11 @@ def quantize_weights(weights) -> QuantGPTServingWeights:
     """Offline conversion of a :class:`~apex_tpu.serving.model.
     GPTServingWeights`-shaped pytree (duck-typed — this module sits
     below serving) to the Q8 deployment artifact."""
+    if not hasattr(weights, "wpe"):
+        raise ValueError(
+            f"quantize_weights: {type(weights).__name__} is not the "
+            f"'gpt2' family's weights; the 'rope_moe' family has no Q8 "
+            f"layout yet (its expert stacks have no int8 kernel)")
     layers = []
     for lw in weights.layers:
         qkv_k, qkv_s = quantize_weight(lw.qkv_k)

@@ -62,9 +62,11 @@ from .metrics import (EngineGauges, ReplicaMonitor, RequestTrace,
                       SnapshotTrigger)
 from .ep import (SERVING_EP_AXIS, EPContext, expand_moe_weights,
                  serving_ep_plan)
-from .model import (GPTServingWeights, LayerWeights, MoELayerWeights,
+from .model import (MOE_TICK_COUNTERS, GPTServingWeights, LayerSpec,
+                    LayerWeights, MoELayerWeights,
                     QuantGPTServingWeights, QuantLayerWeights,
-                    ServingModelConfig, copy_cache_block,
+                    RopeMoEWeights, RopeSpec, ServingModelConfig,
+                    copy_cache_block, init_rope_moe_weights,
                     extract_serving_weights, gather_cache_blocks,
                     gpt_decode_step, gpt_extend_step,
                     gpt_prefill_step, gpt_sequence_logits,
@@ -89,6 +91,8 @@ __all__ = [
     "GPTServingWeights", "LayerWeights", "MoELayerWeights",
     "QuantGPTServingWeights", "QuantLayerWeights",
     "ServingModelConfig",
+    "RopeMoEWeights", "LayerSpec", "RopeSpec", "init_rope_moe_weights",
+    "MOE_TICK_COUNTERS",
     "copy_cache_block", "extract_serving_weights",
     "gather_cache_blocks", "gpt_decode_step", "gpt_extend_step",
     "gpt_prefill_step", "gpt_sequence_logits", "quantize_weights",

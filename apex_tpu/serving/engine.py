@@ -55,7 +55,8 @@ from .kv_cache import (DUMP_BLOCK, KVCacheConfig, KVCacheManager,
                        PrefixMatch, init_cache)
 from .metrics import ServeMetrics, SLOTracker
 from ..ops.quant_matmul import is_quantized_weights
-from .model import (GPTServingWeights, ServingModelConfig,
+from .model import (MOE_TICK_COUNTERS, GPTServingWeights,
+                    ServingModelConfig,
                     copy_cache_block, gpt_decode_step,
                     gpt_extend_step, gpt_prefill_step)
 from .resilience import RequestJournal, ShedPolicy, SpeculationGovernor
@@ -388,6 +389,10 @@ class ServingEngine:
             weights = ep.shard_weights(weights)
         elif device is not None:
             weights = jax.device_put(weights, device)
+        if model_cfg.family == "rope_moe" and cache_cfg.quantized:
+            raise ValueError(
+                "family 'rope_moe' does not serve from an int8 cache "
+                "yet: its grouped-head kernel path is unproven there")
         self.weights = weights
         self.model_cfg = model_cfg
         self.cache_cfg = cache_cfg
@@ -453,7 +458,7 @@ class ServingEngine:
             # caches and prefix-shared / CoW'd pages mirror for free
             self.draft_cache_cfg = KVCacheConfig(
                 num_layers=draft_cfg.num_layers,
-                num_heads=draft_cfg.num_heads,
+                num_heads=draft_cfg.num_kv_heads,
                 head_dim=draft_cfg.head_dim,
                 num_blocks=cache_cfg.num_blocks,
                 block_size=cache_cfg.block_size,
@@ -527,6 +532,15 @@ class ServingEngine:
         self._draft_extend_exec: Dict[Tuple[int, int, int], Any] = {}
         self._cow_exec: Dict[str, Any] = {}
         self._compiles: Dict[str, int] = {}
+        # running sums of _tick_counts over the decode ticks run while
+        # a profiler session or a tracer was recording -- the ticks a
+        # device trace holds; filled in place, so a holder sees it grow
+        self.tick_sums: Dict[str, int] = {}
+        # {window or None: layers of that kind}, for the pages a tick's
+        # attention reads (families with windowed layers only)
+        windows = [s.window for s in model_cfg.layers]
+        self._layer_windows = {w: windows.count(w) for w in set(windows)} \
+            if any(windows) else {}
 
     # --- events -------------------------------------------------------
 
@@ -1350,10 +1364,52 @@ class ServingEngine:
             self.decode_wall_s += dt
             self.decode_tokens += n
             self.steps += 1
+            counts = self._tick_counts(n, seq_lens, out[bb:])
             self._event("decode_step", value=round(dt * 1e3, 3),
-                        batch=n, batch_bucket=bb, pages_bucket=pb)
+                        batch=n, batch_bucket=bb, pages_bucket=pb,
+                        **counts)
         self._tick_tail(n, bb, pb)
         return n
+
+    def _tick_counts(self, n: int, seq_lens: np.ndarray,
+                     extra: np.ndarray) -> Dict[str, int]:
+        """What a decode tick of a family with routed experts or
+        windowed layers counts, for the ``decode_step`` event and,
+        while :func:`~..monitor.tracing.recording`, added into
+        :attr:`tick_sums`; empty for GPT-2 and where neither a monitor
+        nor a recorder looks.  ``extra`` is what the step appended to
+        the tick's tokens (:data:`~.model.MOE_TICK_COUNTERS`).  The pages are the host's
+        own bookkeeping, summed over the layers of each kind:
+        ``pages_full`` / ``tokens_full`` the live pages and positions
+        the full layers read, ``pages_window`` / ``tokens_window``
+        those the windowed layers read, ``pages_dead`` those the
+        windowed layers hold wholly behind their window -- cache that
+        a block pool of their own would have freed."""
+        record = recording()
+        if not record and self.monitor is None:
+            return {}
+        counts = dict(zip(MOE_TICK_COUNTERS, (int(v) for v in extra)))
+        if self._layer_windows:
+            bs = self.cache_cfg.block_size
+            lens = seq_lens[:n].astype(np.int64)
+            pages = -(-lens // bs)
+            counts.update(ticks=1, rows=n, pages_full=0, tokens_full=0,
+                          pages_window=0, tokens_window=0, pages_dead=0)
+            for window, layers in self._layer_windows.items():
+                if window is None:
+                    counts["pages_full"] += layers * int(pages.sum())
+                    counts["tokens_full"] += layers * int(lens.sum())
+                    continue
+                dead = np.maximum(lens - window, 0) // bs
+                counts["pages_dead"] += layers * int(dead.sum())
+                counts["pages_window"] += layers * int(
+                    (pages - dead).sum())
+                counts["tokens_window"] += layers * int(
+                    np.minimum(lens, window).sum())
+        if record:
+            for key, value in counts.items():
+                self.tick_sums[key] = self.tick_sums.get(key, 0) + value
+        return counts
 
     def _spec_tick(self, reqs: List[Request]) -> int:
         """One speculative tick: the draft proposes K tokens row by
@@ -2085,7 +2141,7 @@ def default_cache_config(model_cfg: ServingModelConfig,
     ``APEX_TPU_SERVE_BLOCKS``); explicit arguments override."""
     return KVCacheConfig(
         num_layers=model_cfg.num_layers,
-        num_heads=model_cfg.num_heads,
+        num_heads=model_cfg.num_kv_heads,
         head_dim=model_cfg.head_dim,
         num_blocks=(num_blocks if num_blocks is not None
                     else flag_int("APEX_TPU_SERVE_BLOCKS")),

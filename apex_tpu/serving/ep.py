@@ -156,6 +156,10 @@ class EPContext:
                  cache_cfg: KVCacheConfig, ep: int, *,
                  axis: str = SERVING_EP_AXIS,
                  devices: Optional[Sequence[Any]] = None):
+        if model_cfg.family != "gpt2":
+            raise ValueError(
+                f"family {model_cfg.family!r} has no expert-parallel "
+                f"serving forward yet; serve it on one chip")
         if ep < 2:
             raise ValueError(f"ep {ep} must be >= 2 (ep=1 is the "
                              f"single-chip engine, no context needed)")

@@ -24,6 +24,7 @@ shapes are static per call site, per-request dynamics ride data
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, NamedTuple, Optional, Tuple
 
@@ -37,14 +38,19 @@ from ..ops.layer_norm import layer_norm
 from ..ops.quant_matmul import (QuantGPTServingWeights,
                                 QuantLayerWeights, quant_matmul,
                                 quantize_weights)
+from . import rope_moe
 from .kv_cache import (KVCacheConfig, PagedKVCache, write_prefill_kv,
                        write_token_kv)
+from .rope_moe import (MOE_TICK_COUNTERS, LayerSpec, RopeMoEWeights,
+                       RopeSpec, init_rope_moe_weights)
 
 __all__ = ["GPTServingWeights", "LayerWeights", "MoELayerWeights",
-           "ServingModelConfig",
+           "ServingModelConfig", "RopeMoEWeights", "LayerSpec",
+           "RopeSpec", "init_rope_moe_weights", "MOE_TICK_COUNTERS",
            "QuantGPTServingWeights", "QuantLayerWeights",
            "quantize_weights", "extract_serving_weights",
            "gpt_prefill_step", "gpt_decode_step", "gpt_extend_step",
+           "prefill_logits", "decode_logits", "extend_logits",
            "gpt_sequence_logits", "copy_cache_block",
            "gather_cache_blocks", "scatter_cache_blocks"]
 
@@ -141,12 +147,51 @@ class ServingModelConfig:
     num_experts: int = 0
     moe_capacity_factor: float = 1.25
     moe_a2a_chunks: int = 2
+    # head size and cache (key/value) heads, STATED: a model whose
+    # heads are not hidden / num_heads wide, or whose query heads share
+    # fewer cache heads, says so here.  None takes GPT-2's values,
+    # hidden // num_heads and num_heads.
+    head_dim: Optional[int] = None
+    num_kv_heads: Optional[int] = None
+    # 'gpt2': LayerNorm, learned positions, one fused QKV, GELU, tied
+    # head (the fields above describe it whole).  'rope_moe'
+    # (serving/rope_moe.py): RMSNorm, rotary positions, grouped-query
+    # heads with a per-head gate, full and windowed layers mixed, a
+    # SwiGLU MLP that is dense or a dropless top-k MoE beside a shared
+    # expert, untied head -- one LayerSpec a layer in ``layers``
+    # (query heads differ by layer there; ``num_heads`` is unused)
+    family: str = "gpt2"
+    layers: Tuple[LayerSpec, ...] = ()
+    experts_per_token: int = 1
+    routed_scaling: float = 1.0
 
     def __post_init__(self):
-        if self.hidden_size % self.num_heads:
-            raise ValueError(
-                f"hidden {self.hidden_size} not divisible by heads "
-                f"{self.num_heads}")
+        if self.head_dim is None:
+            if self.hidden_size % self.num_heads:
+                raise ValueError(
+                    f"hidden {self.hidden_size} not divisible by heads "
+                    f"{self.num_heads}")
+            object.__setattr__(self, "head_dim",
+                               self.hidden_size // self.num_heads)
+        if self.num_kv_heads is None:
+            object.__setattr__(self, "num_kv_heads", self.num_heads)
+        if self.family not in ("gpt2", "rope_moe"):
+            raise ValueError(f"family {self.family!r} not in "
+                             f"('gpt2', 'rope_moe')")
+        if self.family == "rope_moe":
+            if len(self.layers) != self.num_layers:
+                raise ValueError(
+                    f"family 'rope_moe' takes one LayerSpec a layer: "
+                    f"{len(self.layers)} given for {self.num_layers}")
+            if self.tp_axis is not None or self.ep_axis is not None:
+                raise ValueError(
+                    "family 'rope_moe' has no tensor- or expert-"
+                    "parallel forward yet")
+            for spec in self.layers:
+                if spec.num_heads % self.num_kv_heads:
+                    raise ValueError(
+                        f"{spec.num_heads} query heads do not divide "
+                        f"over {self.num_kv_heads} cache heads")
         if self.decode_attention not in ("kernel", "reference"):
             raise ValueError(
                 f"decode_attention {self.decode_attention!r} not in "
@@ -158,20 +203,19 @@ class ServingModelConfig:
             raise ValueError(
                 f"num_experts {self.num_experts} must be >= 0")
 
-    @property
-    def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
-
     @classmethod
     def from_model(cls, model, **overrides) -> "ServingModelConfig":
         """Geometry from a :class:`~apex_tpu.testing.standalone_gpt.
         GPTModel` instance."""
+        heads = model.num_attention_heads
         return cls(vocab_size=model.vocab_size,
                    hidden_size=model.hidden_size,
-                   num_heads=model.num_attention_heads,
+                   num_heads=heads,
                    num_layers=model.num_layers,
                    max_seq=model.max_sequence_length,
-                   dtype=model.dtype, **overrides)
+                   dtype=model.dtype,
+                   head_dim=model.hidden_size // heads,
+                   num_kv_heads=heads, **overrides)
 
 
 def _unbox(tree):
@@ -316,43 +360,103 @@ def _axis_size(axis) -> int:
     return axis_size(axis) if axis is not None else 1
 
 
-def _layer_tail(x, lw: LayerWeights, attn_out, cfg):
-    """residual + LN + MLP + residual — shared by prefill and decode.
-    fc1 is column-split under TP (local gelu), fc2 row-split (the
-    layer's second all-reduce); an ``MoELayerWeights`` layer routes
-    through the MoE FFN instead (duck-typed on ``router``)."""
+def _spec(cfg, i: int) -> Optional[LayerSpec]:
+    """Layer ``i``'s :class:`LayerSpec` -- None for a GPT-2 layer, which
+    the config's scalar fields describe whole.  What the per-layer
+    pieces below dispatch on."""
+    return cfg.layers[i] if cfg.layers else None
+
+
+def _attn_inputs(x, lw, cfg, spec, positions, h, d):
+    """One layer's normed input and its q, k, v ``(..., heads, d)``:
+    GPT-2's LayerNorm and fused QKV of ``h`` heads each, or
+    :mod:`.rope_moe`'s RMSNorm, separate projections of
+    ``spec.num_heads`` query and ``h`` cache heads, rotated by
+    ``positions``."""
+    if spec is not None:
+        a_in = rope_moe.rms_norm(x, lw.norm1, cfg.layernorm_eps)
+        return (a_in,) + rope_moe.qkv(a_in, lw, spec, cfg, positions)
+    a_in = layer_norm(x, lw.ln1_w, lw.ln1_b,
+                      cfg.layernorm_eps).astype(cfg.dtype)
+    qkv = _linear(a_in, lw.qkv_k, lw.qkv_b, cfg.dtype,
+                  getattr(lw, "qkv_s", None))
+    qkv = qkv.reshape(*x.shape[:-1], h, 3 * d)
+    return (a_in,) + tuple(jnp.split(qkv, 3, axis=-1))
+
+
+def _attn_scope(spec):
+    """The device-trace name of a ``rope_moe`` layer's attention, by
+    its kind; GPT-2's programs keep the names they have."""
+    if spec is None:
+        return contextlib.nullcontext()
+    return jax.named_scope("apex.attn.window" if spec.window
+                           else "apex.attn.full")
+
+
+def _attn_branch(ctx, a_in, lw, cfg, spec):
+    """Attention context ``(..., heads, d)`` -> the block's attention
+    branch ``(..., H)``."""
+    if spec is not None:
+        return rope_moe.attn_out(ctx, a_in, lw, spec, cfg)
+    ctx = ctx.reshape(*ctx.shape[:-2], -1)
+    return _row_linear(ctx, lw.dense_k, lw.dense_b, cfg.dtype,
+                       cfg.tp_axis, getattr(lw, "dense_s", None))
+
+
+def _layer_tail(x, lw, attn_out, cfg, live=None):
+    """residual + norm + MLP + residual -- shared by prefill, decode
+    and extend; returns ``(x, tick counters or None)``.  GPT-2: fc1 is
+    column-split under TP (local gelu), fc2 row-split (the layer's
+    second all-reduce); an ``MoELayerWeights`` layer routes through the
+    capacity MoE FFN instead (duck-typed on ``router``).  ``rope_moe``
+    (duck-typed on ``norm2``): RMSNorm and the dense or dropless-MoE
+    SwiGLU, whose routing counts over the ``live`` rows come back for
+    the decode tick's telemetry."""
     x = x + attn_out.astype(x.dtype)
+    if hasattr(lw, "norm2"):
+        branch, counters = rope_moe.mlp(
+            rope_moe.rms_norm(x, lw.norm2, cfg.layernorm_eps), lw, cfg,
+            live)
+        return x + branch, counters
     m_in = layer_norm(x, lw.ln2_w, lw.ln2_b,
                       cfg.layernorm_eps).astype(cfg.dtype)
     if getattr(lw, "router", None) is not None:
-        return x + _moe_mlp(m_in, lw, cfg).astype(x.dtype)
+        return x + _moe_mlp(m_in, lw, cfg).astype(x.dtype), None
     h1 = jax.nn.gelu(_linear(m_in, lw.fc1_k, lw.fc1_b, cfg.dtype,
                              getattr(lw, "fc1_s", None)))
     mlp_out = _row_linear(h1, lw.fc2_k, lw.fc2_b, cfg.dtype,
                           cfg.tp_axis, getattr(lw, "fc2_s", None))
-    return x + mlp_out.astype(x.dtype)
+    return x + mlp_out.astype(x.dtype), None
 
 
-def _lm_head(x, weights: GPTServingWeights, cfg):
-    """Final LN + tied-embedding projection (GPTHead + attend)."""
+def _lm_head(x, weights, cfg):
+    """Final norm + output projection: GPT-2's LayerNorm and tied
+    embedding (GPTHead + attend), or ``rope_moe``'s RMSNorm and untied
+    head with float32 logits."""
+    if isinstance(weights, RopeMoEWeights):
+        return rope_moe.head_logits(x, weights, cfg.layernorm_eps)
     hf = layer_norm(x, weights.lnf_w, weights.lnf_b,
                     cfg.layernorm_eps).astype(cfg.dtype)
     return hf.astype(cfg.dtype) @ weights.wte.astype(cfg.dtype).T
 
 
-def _embed(weights: GPTServingWeights, tokens, positions, cfg):
+def _embed(weights, tokens, positions, cfg):
+    """Token (+ GPT-2's learned position) embeddings: the residual
+    stream, which ``rope_moe`` carries in float32."""
+    if isinstance(weights, RopeMoEWeights):
+        return jnp.take(weights.embed, tokens, axis=0).astype(jnp.float32)
     dtype = cfg.dtype
     return (jnp.take(weights.wte.astype(dtype), tokens, axis=0)
             + jnp.take(weights.wpe.astype(dtype), positions, axis=0))
 
 
-def gpt_prefill_step(weights: GPTServingWeights,
-                     cfg: ServingModelConfig,
+def gpt_prefill_step(weights, cfg: ServingModelConfig,
                      cache_cfg: KVCacheConfig, cache: PagedKVCache,
                      tokens: jnp.ndarray, length: jnp.ndarray,
                      blocks: jnp.ndarray):
     """Run one prompt through the model, writing every layer's k/v
     into the request's pages; returns ``(cache, next_token)``.
+    (:func:`prefill_logits` is this step before its argmax.)
 
     ``tokens`` (s_pad,) int32, right-padded to the prompt-length
     bucket (``s_pad = len(blocks) * block_size``); ``length`` the true
@@ -365,7 +469,18 @@ def gpt_prefill_step(weights: GPTServingWeights,
     reads never weight.  The attention itself is the existing flash
     forward kernel (:func:`~apex_tpu.ops.flash_attention.
     flash_attention`) — prefill is exactly a training forward at
-    batch 1."""
+    batch 1 (with grouped heads and, on windowed layers, a causal
+    window for the ``rope_moe`` family, whose head runs on the last
+    real position's row alone)."""
+    cache, last = prefill_logits(weights, cfg, cache_cfg, cache, tokens,
+                                 length, blocks)
+    return cache, jnp.argmax(last, axis=-1).astype(jnp.int32)
+
+
+def prefill_logits(weights, cfg, cache_cfg, cache, tokens, length,
+                   blocks):
+    """:func:`gpt_prefill_step` up to the logits of the last real
+    position: ``(cache, (V,) logits)``."""
     from ..ops.flash_attention import flash_attention, mha_reference
 
     s_pad = tokens.shape[0]
@@ -374,34 +489,34 @@ def gpt_prefill_step(weights: GPTServingWeights,
     # cache is sized to match — the math below is per-shard math
     h, d = cache_cfg.num_heads, cache_cfg.head_dim
     scale = d ** -0.5
-    x = _embed(weights, tokens[None, :],
-               jnp.arange(s_pad, dtype=jnp.int32)[None, :], cfg)
+    tokens = tokens[None, :]
+    positions = jnp.arange(s_pad, dtype=jnp.int32)[None, :]
+    x = _embed(weights, tokens, positions, cfg)
     for i, lw in enumerate(weights.layers):
-        a_in = layer_norm(x, lw.ln1_w, lw.ln1_b,
-                          cfg.layernorm_eps).astype(cfg.dtype)
-        qkv = _linear(a_in, lw.qkv_k, lw.qkv_b, cfg.dtype,
-                      getattr(lw, "qkv_s", None))
-        qkv = qkv.reshape(1, s_pad, h, 3 * d)
-        q, k, v = jnp.split(qkv, 3, axis=-1)      # (1, s, h, d)
+        spec = _spec(cfg, i)
+        a_in, q, k, v = _attn_inputs(x, lw, cfg, spec, positions, h, d)
         cache = write_prefill_kv(cache, cache_cfg, i, k[0], v[0],
                                  blocks)
         qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
         attn = flash_attention if cfg.prefill_flash else mha_reference
-        ctx = attn(qt, kt, vt, scale=scale, causal=True)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(1, s_pad, h * d)
-        attn_out = _row_linear(ctx, lw.dense_k, lw.dense_b, cfg.dtype,
-                               cfg.tp_axis,
-                               getattr(lw, "dense_s", None))
-        x = _layer_tail(x, lw, attn_out, cfg)
-    logits = _lm_head(x, weights, cfg)[0]          # (s_pad, V)
-    last = jax.lax.dynamic_index_in_dim(logits, length - 1, axis=0,
-                                        keepdims=False)
-    next_token = jnp.argmax(last, axis=-1).astype(jnp.int32)
-    return cache, next_token
+        with _attn_scope(spec):
+            ctx = attn(qt, kt, vt, scale=scale, causal=True,
+                       window=spec.window if spec else None)
+        attn_out = _attn_branch(ctx.transpose(0, 2, 1, 3), a_in, lw, cfg,
+                                spec)
+        x, _ = _layer_tail(x, lw, attn_out, cfg)
+    if cfg.family == "rope_moe":
+        # the head is vocabulary-wide: run it on the one row that is read
+        last = _lm_head(jax.lax.dynamic_index_in_dim(
+            x[0], length - 1, axis=0, keepdims=False), weights, cfg)
+    else:
+        logits = _lm_head(x, weights, cfg)[0]          # (s_pad, V)
+        last = jax.lax.dynamic_index_in_dim(logits, length - 1, axis=0,
+                                            keepdims=False)
+    return cache, last
 
 
-def gpt_decode_step(weights: GPTServingWeights,
-                    cfg: ServingModelConfig,
+def gpt_decode_step(weights, cfg: ServingModelConfig,
                     cache_cfg: KVCacheConfig, cache: PagedKVCache,
                     tokens: jnp.ndarray, positions: jnp.ndarray,
                     block_tables: jnp.ndarray, seq_lens: jnp.ndarray,
@@ -419,46 +534,55 @@ def gpt_decode_step(weights: GPTServingWeights,
     rows carry ``seq_lens = 0``, point their writes at the dump page,
     and produce a (discarded) deterministic token.  Greedy argmax
     sampling happens in-graph — the step's only output traffic is the
-    cache carry and one int32 per row.
+    cache carry and one int32 per row.  The ``rope_moe`` family appends
+    :data:`~.rope_moe.MOE_TICK_COUNTERS` to ``next_tokens`` (two more
+    int32, summed over its MoE layers and the live rows), so the
+    routing's telemetry rides the tick's one fetch.
 
     Every row's math touches only that row's pages and lanes, so a
     request's token stream is invariant to bucket shape and admission
     interleave — the continuous-batching determinism the serving
-    tests prove.
+    tests prove.  (:func:`decode_logits` is this step before its
+    argmax.)
     """
-    h, d = cache_cfg.num_heads, cache_cfg.head_dim   # per-shard heads
-    b = tokens.shape[0]
-    scale = d ** -0.5
-    x = _embed(weights, tokens, positions, cfg)   # (b, H)
-    for i, lw in enumerate(weights.layers):
-        a_in = layer_norm(x, lw.ln1_w, lw.ln1_b,
-                          cfg.layernorm_eps).astype(cfg.dtype)
-        qkv = _linear(a_in, lw.qkv_k, lw.qkv_b, cfg.dtype,
-                      getattr(lw, "qkv_s", None))
-        qkv = qkv.reshape(b, h, 3 * d)
-        q, k, v = jnp.split(qkv, 3, axis=-1)       # (b, h, d)
-        cache = write_token_kv(cache, cache_cfg, i, k, v,
-                               write_blocks, write_offsets)
-        kc, vc, ks, vs = cache.layer(i)
-        if cfg.decode_attention == "kernel":
-            ctx = flash_decode(q, kc, vc, block_tables, seq_lens,
-                               scale=scale, k_scale=ks, v_scale=vs)
-        else:
-            ctx = paged_attention_reference(
-                q, kc, vc, block_tables, seq_lens, scale=scale,
-                k_scale=ks, v_scale=vs)
-        ctx = ctx.reshape(b, h * d)
-        attn_out = _row_linear(ctx, lw.dense_k, lw.dense_b, cfg.dtype,
-                               cfg.tp_axis,
-                               getattr(lw, "dense_s", None))
-        x = _layer_tail(x, lw, attn_out, cfg)
-    logits = _lm_head(x, weights, cfg)             # (b, V)
+    cache, logits, counters = decode_logits(
+        weights, cfg, cache_cfg, cache, tokens, positions, block_tables,
+        seq_lens, write_blocks, write_offsets)
     next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    if counters is not None:
+        next_tokens = jnp.concatenate([next_tokens, counters])
     return cache, next_tokens
 
 
-def gpt_extend_step(weights: GPTServingWeights,
-                    cfg: ServingModelConfig,
+def decode_logits(weights, cfg, cache_cfg, cache, tokens, positions,
+                  block_tables, seq_lens, write_blocks, write_offsets):
+    """:func:`gpt_decode_step` up to its logits: ``(cache, (b, V)
+    logits, the MoE tick counters or None)``."""
+    h, d = cache_cfg.num_heads, cache_cfg.head_dim   # per-shard heads
+    scale = d ** -0.5
+    x = _embed(weights, tokens, positions, cfg)   # (b, H)
+    live = seq_lens > 0 if cfg.layers else None    # rows the MoE counts
+    counters = None
+    for i, lw in enumerate(weights.layers):
+        spec = _spec(cfg, i)
+        a_in, q, k, v = _attn_inputs(x, lw, cfg, spec, positions, h, d)
+        cache = write_token_kv(cache, cache_cfg, i, k, v,
+                               write_blocks, write_offsets)
+        kc, vc, ks, vs = cache.layer(i)
+        attn = flash_decode if cfg.decode_attention == "kernel" \
+            else paged_attention_reference
+        with _attn_scope(spec):
+            ctx = attn(q, kc, vc, block_tables, seq_lens, scale=scale,
+                       k_scale=ks, v_scale=vs,
+                       window=spec.window if spec else None)
+        attn_out = _attn_branch(ctx, a_in, lw, cfg, spec)
+        x, c = _layer_tail(x, lw, attn_out, cfg, live)
+        if c is not None:
+            counters = c if counters is None else counters + c
+    return cache, _lm_head(x, weights, cfg), counters    # (b, V)
+
+
+def gpt_extend_step(weights, cfg: ServingModelConfig,
                     cache_cfg: KVCacheConfig, cache: PagedKVCache,
                     tokens: jnp.ndarray, block_tables: jnp.ndarray,
                     seq_lens: jnp.ndarray,
@@ -488,7 +612,18 @@ def gpt_extend_step(weights: GPTServingWeights,
 
     One compile per (batch bucket, t bucket, pages bucket) — the
     chunk/verify dimensions the engine's warmup adds to the ladder
-    product."""
+    product.  (:func:`extend_logits` is this step before its
+    argmax.)"""
+    cache, logits = extend_logits(
+        weights, cfg, cache_cfg, cache, tokens, block_tables, seq_lens,
+        write_blocks, write_offsets)
+    return cache, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def extend_logits(weights, cfg, cache_cfg, cache, tokens, block_tables,
+                  seq_lens, write_blocks, write_offsets):
+    """:func:`gpt_extend_step` up to its logits: ``(cache, (b, t, V)
+    logits)``."""
     h, d = cache_cfg.num_heads, cache_cfg.head_dim   # per-shard heads
     b, t = tokens.shape
     scale = d ** -0.5
@@ -500,66 +635,50 @@ def gpt_extend_step(weights: GPTServingWeights,
     wb = write_blocks.reshape(b * t)
     wo = write_offsets.reshape(b * t)
     for i, lw in enumerate(weights.layers):
-        a_in = layer_norm(x, lw.ln1_w, lw.ln1_b,
-                          cfg.layernorm_eps).astype(cfg.dtype)
-        qkv = _linear(a_in, lw.qkv_k, lw.qkv_b, cfg.dtype,
-                      getattr(lw, "qkv_s", None))
-        qkv = qkv.reshape(b, t, h, 3 * d)
-        q, k, v = jnp.split(qkv, 3, axis=-1)       # (b, t, h, d)
+        spec = _spec(cfg, i)
+        a_in, q, k, v = _attn_inputs(x, lw, cfg, spec, pos, h, d)
         cache = write_token_kv(cache, cache_cfg, i,
                                k.reshape(b * t, h, d),
                                v.reshape(b * t, h, d), wb, wo)
         kc, vc, ks, vs = cache.layer(i)
-        if cfg.decode_attention == "kernel":
-            ctx = flash_decode_multi(q, kc, vc, block_tables,
-                                     seq_lens, scale=scale,
-                                     k_scale=ks, v_scale=vs)
-        else:
-            ctx = paged_attention_multi_reference(
-                q, kc, vc, block_tables, seq_lens, scale=scale,
-                k_scale=ks, v_scale=vs)
-        ctx = ctx.reshape(b, t, h * d)
-        attn_out = _row_linear(ctx, lw.dense_k, lw.dense_b, cfg.dtype,
-                               cfg.tp_axis,
-                               getattr(lw, "dense_s", None))
-        x = _layer_tail(x, lw, attn_out, cfg)
-    logits = _lm_head(x, weights, cfg)             # (b, t, V)
-    next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    return cache, next_tokens
+        attn = flash_decode_multi if cfg.decode_attention == "kernel" \
+            else paged_attention_multi_reference
+        with _attn_scope(spec):
+            ctx = attn(q, kc, vc, block_tables, seq_lens, scale=scale,
+                       k_scale=ks, v_scale=vs,
+                       window=spec.window if spec else None)
+        attn_out = _attn_branch(ctx, a_in, lw, cfg, spec)
+        x, _ = _layer_tail(x, lw, attn_out, cfg)
+    return cache, _lm_head(x, weights, cfg)        # (b, t, V)
 
 
 def gpt_sequence_logits(weights, cfg: ServingModelConfig,
                         tokens: jnp.ndarray) -> jnp.ndarray:
     """Whole-sequence teacher-forced logits ``(b, s, V)`` — no KV
     cache, no paging: the training-forward view of the SAME serving
-    math (same ``_linear``/``_row_linear`` dispatch, so Q8 weights run
-    the quantized matmuls here too).  This is the oracle behind the
+    math (same per-layer pieces, so Q8 weights run the quantized
+    matmuls here too).  This is the oracle behind the
     bench's perplexity-delta row and the Q8-vs-O5 divergence tests;
     single-chip only (head counts come from ``cfg``, not a sharded
     cache config)."""
     from ..ops.flash_attention import flash_attention, mha_reference
 
     b, s = tokens.shape
-    h, d = cfg.num_heads, cfg.head_dim
+    h, d = cfg.num_kv_heads, cfg.head_dim
     scale = d ** -0.5
     pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None, :],
                            (b, s))
     x = _embed(weights, tokens, pos, cfg)
-    for lw in weights.layers:
-        a_in = layer_norm(x, lw.ln1_w, lw.ln1_b,
-                          cfg.layernorm_eps).astype(cfg.dtype)
-        qkv = _linear(a_in, lw.qkv_k, lw.qkv_b, cfg.dtype,
-                      getattr(lw, "qkv_s", None))
-        qkv = qkv.reshape(b, s, h, 3 * d)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
+    for i, lw in enumerate(weights.layers):
+        spec = _spec(cfg, i)
+        a_in, q, k, v = _attn_inputs(x, lw, cfg, spec, pos, h, d)
         qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
         attn = flash_attention if cfg.prefill_flash else mha_reference
-        ctx = attn(qt, kt, vt, scale=scale, causal=True)
-        ctx = ctx.transpose(0, 2, 1, 3).reshape(b, s, h * d)
-        attn_out = _row_linear(ctx, lw.dense_k, lw.dense_b, cfg.dtype,
-                               cfg.tp_axis,
-                               getattr(lw, "dense_s", None))
-        x = _layer_tail(x, lw, attn_out, cfg)
+        ctx = attn(qt, kt, vt, scale=scale, causal=True,
+                   window=spec.window if spec else None)
+        attn_out = _attn_branch(ctx.transpose(0, 2, 1, 3), a_in, lw, cfg,
+                                spec)
+        x, _ = _layer_tail(x, lw, attn_out, cfg)
     return _lm_head(x, weights, cfg)
 
 

@@ -151,6 +151,10 @@ class TPContext:
                  axis: str = SERVING_TP_AXIS,
                  devices: Optional[Sequence[Any]] = None,
                  weight_quantized: bool = False):
+        if model_cfg.family != "gpt2":
+            raise ValueError(
+                f"family {model_cfg.family!r} has no tensor-parallel "
+                f"serving forward yet; serve it on one chip")
         if tp < 2:
             raise ValueError(f"tp {tp} must be >= 2 (tp=1 is the "
                              f"single-chip engine, no context needed)")

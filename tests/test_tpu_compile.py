@@ -169,6 +169,35 @@ def _odd_decode_args(hk):
             ((B, 4), I32), ((B,), I32))
 
 
+# the rope_moe cell (laguna-xs2): 8 cache heads of 128 in a pool of
+# 12,289 blocks of 16, read by 48 (full layers) or 64 (window 512) query
+# heads; batch rung 32 on the 544-page rung; prefill rungs to 8,704
+GQ_BLOCKS, GQ_KV, GQ_D, GQ_WINDOW = 12289, 8, 128, 512
+_GQ_CACHE = ((GQ_BLOCKS, GQ_KV, KV_BLOCK, GQ_D), BF16)
+
+
+def _grouped_decode(window):
+    def fn(q, k, v, bt, sl):
+        return fd.flash_decode(q, k, v, bt, sl, window=window)
+    return fn
+
+
+def _grouped_decode_args(heads, pages):
+    return (((CELL_B, heads, GQ_D), BF16), _GQ_CACHE, _GQ_CACHE,
+            ((CELL_B, pages), I32), ((CELL_B,), I32))
+
+
+def _grouped_prefill(window):
+    def fn(q, k, v):
+        return fa.flash_attention(q, k, v, causal=True, window=window)
+    return fn
+
+
+def _grouped_prefill_args(heads, s):
+    return (((1, heads, s, GQ_D), BF16), ((1, GQ_KV, s, GQ_D), BF16),
+            ((1, GQ_KV, s, GQ_D), BF16))
+
+
 def _qmm_args(m, n):
     return (((m, HID), BF16), ((HID, n), I8), ((n,), F32))
 
@@ -223,6 +252,19 @@ CASES = {
     "flash_decode_multi_cell_t256": (_decode_multi, _cell_extend_args(256)),
     "flash_decode_multi_cell_t1024": (_decode_multi,
                                       _cell_extend_args(1024)),
+    # grouped query heads and a causal window (serving's second family)
+    "flash_decode_grouped_full_b32_p544": (
+        _grouped_decode(None), _grouped_decode_args(48, 544)),
+    "flash_decode_grouped_window_b32_p544": (
+        _grouped_decode(GQ_WINDOW), _grouped_decode_args(64, 544)),
+    "flash_decode_grouped_window_b32_p64": (
+        _grouped_decode(GQ_WINDOW), _grouped_decode_args(64, 64)),
+    "flash_fwd_grouped_full_s8704": (
+        _grouped_prefill(None), _grouped_prefill_args(48, 8704)),
+    "flash_fwd_grouped_window_s8704": (
+        _grouped_prefill(GQ_WINDOW), _grouped_prefill_args(64, 8704)),
+    "flash_fwd_grouped_window_s1024": (
+        _grouped_prefill(GQ_WINDOW), _grouped_prefill_args(64, 1024)),
     # off the 345M path (MoE routing; optimizer sweeps, off by default)
     "moe_route_dispatch": (_moe_route, (((S, HID), BF16),   # one prompt
                                         ((S, 8), F32))),    # 8 experts
