@@ -53,10 +53,10 @@ from .engine import (BucketLadder, Request, ServeSummary,
                      ServingEngine, default_cache_config)
 from .fleet import FleetRouter, FleetSummary, Replica, transfer_prefix
 from .kv_cache import (DUMP_BLOCK, CachePoolExhausted, KVCacheConfig,
-                       KVCacheManager, PagedKVCache, PrefixMatch,
-                       init_cache, prefix_chain_keys,
-                       quantize_kv_rows, write_prefill_kv,
-                       write_token_kv)
+                       KVCacheManager, PagedKVCache, PageWrite,
+                       PrefixMatch, init_cache, plan_page_write,
+                       prefix_chain_keys, quantize_kv_rows,
+                       write_prefill_kv, write_token_kv)
 from .metrics import (EngineGauges, ReplicaMonitor, RequestTrace,
                       ServeMetrics, SLObjective, SLOTracker,
                       SnapshotTrigger)
@@ -85,9 +85,9 @@ __all__ = [
     "default_cache_config",
     "FleetRouter", "FleetSummary", "Replica", "transfer_prefix",
     "DUMP_BLOCK", "CachePoolExhausted", "KVCacheConfig",
-    "KVCacheManager", "PagedKVCache", "PrefixMatch", "init_cache",
-    "prefix_chain_keys", "quantize_kv_rows", "write_prefill_kv",
-    "write_token_kv",
+    "KVCacheManager", "PagedKVCache", "PageWrite", "PrefixMatch",
+    "init_cache", "plan_page_write", "prefix_chain_keys",
+    "quantize_kv_rows", "write_prefill_kv", "write_token_kv",
     "GPTServingWeights", "LayerWeights", "MoELayerWeights",
     "QuantGPTServingWeights", "QuantLayerWeights",
     "ServingModelConfig",

@@ -642,11 +642,15 @@ class ServingEngine:
                 jnp.zeros((bb, t), jnp.int32),
                 jnp.zeros((bb, t), jnp.int32))
 
-    def _compiled(self, cache: dict, key, jit_builder, args, label):
+    def _compiled(self, cache: dict, key, jit_builder, make_args, label):
+        """The executable of one bucket, compiled on first use from the
+        example arguments ``make_args()`` builds: built only then, since
+        they are device arrays and a cached bucket is asked for on
+        every tick."""
         ex = cache.get(key)
         if ex is None:
             t0 = self._clock()
-            ex = jit_builder().lower(*args).compile()
+            ex = jit_builder().lower(*make_args()).compile()
             cache[key] = ex
             self._compiles[f"{label}:{key}"] = \
                 self._compiles.get(f"{label}:{key}", 0) + 1
@@ -665,41 +669,44 @@ class ServingEngine:
     def _decode_fn(self, bb: int, pb: int):
         return self._compiled(self._decode_exec, (bb, pb),
                               self._jit_decode,
-                              self._decode_args(bb, pb), "decode")
+                              lambda: self._decode_args(bb, pb), "decode")
 
     def _prefill_fn(self, s_pad: int):
         return self._compiled(self._prefill_exec, s_pad,
                               self._jit_prefill,
-                              self._prefill_args(s_pad), "prefill")
+                              lambda: self._prefill_args(s_pad), "prefill")
 
     def _extend_fn(self, bb: int, t: int, pb: int):
         return self._compiled(self._extend_exec, (bb, t, pb),
                               self._jit_extend,
-                              self._extend_args(bb, t, pb), "extend")
+                              lambda: self._extend_args(bb, t, pb),
+                              "extend")
 
     def _draft_decode_fn(self, bb: int, pb: int):
         return self._compiled(
             self._draft_decode_exec, (bb, pb),
             functools.partial(self._jit_decode, True),
-            self._decode_args(bb, pb, draft=True), "draft_decode")
+            lambda: self._decode_args(bb, pb, draft=True), "draft_decode")
 
     def _draft_prefill_fn(self, s_pad: int):
         return self._compiled(
             self._draft_prefill_exec, s_pad,
             functools.partial(self._jit_prefill, True),
-            self._prefill_args(s_pad, draft=True), "draft_prefill")
+            lambda: self._prefill_args(s_pad, draft=True),
+            "draft_prefill")
 
     def _draft_extend_fn(self, bb: int, t: int, pb: int):
         return self._compiled(
             self._draft_extend_exec, (bb, t, pb),
             functools.partial(self._jit_extend, True),
-            self._extend_args(bb, t, pb, draft=True), "draft_extend")
+            lambda: self._extend_args(bb, t, pb, draft=True),
+            "draft_extend")
 
     def _cow_fn(self, which: str):
         cache = self.draft_cache if which == "draft" else self.cache
         return self._compiled(
             self._cow_exec, which, self._jit_cow,
-            (cache, jnp.int32(0), jnp.int32(0)), "cow")
+            lambda: (cache, jnp.int32(0), jnp.int32(0)), "cow")
 
     @property
     def _chunking(self) -> bool:
@@ -1349,10 +1356,11 @@ class ServingEngine:
             fn = self._decode_fn(bb, pb)
         t0 = self._clock()
         with span("apex.serve.decode.dispatch"):
+            # the executable uploads its numpy inputs itself, in one
+            # batch: six jnp.asarray calls before it cost 1 ms a tick
             self.cache, next_tokens = fn(
-                self.weights, self.cache, jnp.asarray(tokens),
-                jnp.asarray(positions), jnp.asarray(bt),
-                jnp.asarray(seq_lens), jnp.asarray(wb), jnp.asarray(wo))
+                self.weights, self.cache, tokens, positions, bt,
+                seq_lens, wb, wo)
         with span("apex.serve.decode.fetch"):
             out = np.asarray(next_tokens)    # the tick's ONE device fetch
         dt = self._clock() - t0

@@ -185,6 +185,24 @@ _scatter_jit = functools.partial(jax.jit, donate_argnums=(0,))(
     scatter_cache_blocks)
 
 
+def _payload_shardings(dst: ServingEngine):
+    """Where a stacked ``(L, n, ...)`` handoff payload goes on ``dst``:
+    ``(k/v sharding, scales' sharding or None)``, each a cache leaf's
+    own sharding with an unsharded layer axis in front (``dst`` may be
+    another device, or a TP shard layout — the leaf describes both)."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    def stacked(leaves):
+        if leaves is None:
+            return None
+        sh = leaves[0].sharding
+        if isinstance(sh, NamedSharding):
+            return NamedSharding(sh.mesh, PartitionSpec(None, *sh.spec))
+        return sh
+
+    return stacked(dst.cache.k), stacked(dst.cache.k_scale)
+
+
 def _geometry_key(cfg) -> tuple:
     return (cfg.num_layers, cfg.num_heads, cfg.head_dim,
             cfg.block_size, cfg.kv_dtype, str(cfg.storage_dtype))
@@ -235,12 +253,9 @@ def transfer_prefix(src: ServingEngine, dst: ServingEngine,
     db[:n] = dst_blocks
     k, v, ks, vs = _gather_jit(src.cache, jnp.asarray(sb))
     # the wire hop: the payload leaves src's device for dst's pool
-    # (dst may be another device, or a TP shard layout — the dst
-    # cache's own sharding describes both)
-    sharding = dst.cache.k.sharding
+    sharding, ks_sh = _payload_shardings(dst)
     k, v = jax.device_put(k, sharding), jax.device_put(v, sharding)
     if ks is not None:
-        ks_sh = dst.cache.k_scale.sharding
         ks = jax.device_put(ks, ks_sh)
         vs = jax.device_put(vs, ks_sh)
     with contextlib.ExitStack() as stack:
@@ -306,12 +321,11 @@ def import_prefix_payload(dst: ServingEngine, prompt: Sequence[int],
         return 0                       # already resident — warm as-is
     db = np.full(pn, DUMP_BLOCK, np.int32)
     db[:n] = dst_blocks
-    sharding = dst.cache.k.sharding
+    sharding, ks_sh = _payload_shardings(dst)
     k = jax.device_put(jnp.asarray(arrays["k"]), sharding)
     v = jax.device_put(jnp.asarray(arrays["v"]), sharding)
     ks = vs = None
     if "ks" in arrays:
-        ks_sh = dst.cache.k_scale.sharding
         ks = jax.device_put(jnp.asarray(arrays["ks"]), ks_sh)
         vs = jax.device_put(jnp.asarray(arrays["vs"]), ks_sh)
     with contextlib.ExitStack() as stack:

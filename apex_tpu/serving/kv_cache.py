@@ -12,23 +12,37 @@ touched), never O(cache).
 
 Two cleanly separated halves:
 
-* :class:`PagedKVCache` — the DEVICE state: per-layer k/v block arrays
-  stacked over layers, ``(L, nb, hk, bs, dk)``, plus optional int8
-  per-row scales ``(L, nb, h, bs)``.  A pytree, threaded through the
-  jitted prefill/decode steps and **donated** every step (the same
-  carry discipline as the scan driver's amp state — the cache is the
+* :class:`PagedKVCache` — the DEVICE state: one k and one v block
+  array a layer, ``(nb, hk, bs, dk)`` each (tuples of ``num_layers``
+  arrays), plus optional int8 per-row scales ``(nb, h, bs)``.  A
+  pytree, threaded through the jitted prefill/decode steps and
+  **donated** every step, every leaf its own buffer (the same carry
+  discipline as the scan driver's amp state — the cache is the
   largest buffer in the serving process, double-buffering it halves
   capacity).  ``hk``/``dk`` follow the d=64 head-pair packing decision
   (:func:`apex_tpu.ops.flash_decode.use_decode_head_packing`) so the
-  kernel and the layout can never disagree.
+  kernel and the layout can never disagree.  A layer's array is what
+  the decode kernel reads, as it lies: nothing slices or transposes it
+  on the way, because every write is **page-granular** (gather the
+  touched pages, put the new rows in, scatter whole pages back;
+  :func:`plan_page_write` once a step, :func:`write_token_kv` a
+  layer).  A
+  row scatter ``arr.at[blocks, :, offsets, :]`` would make XLA keep
+  the array with ``hk`` minor to ``bs`` and copy all of it to the
+  kernel's layout and back on every step.  The page write rewrites a
+  whole page from what it gathered, so **no two rows of one step may
+  write the same page**, the dump page apart: live rows never do
+  (:meth:`KVCacheManager.cow_for_append` makes a shared page private
+  before an append).
 * :class:`KVCacheManager` — the HOST bookkeeping: free list, per-
   request tables and lengths.  Pure Python, no device work; the engine
   consults it between jitted steps (the continuous-batching boundary).
 
 Block 0 is reserved as the **dump page**: it is never handed to a
 request, block-table padding points at it, and inactive batch rows
-write their (masked-out) k/v there — so a bucketed decode step needs
-no write masking and a dead page read contributes exactly 0.
+point their writes at it (a page write addressed there puts the dump
+page back as it found it) — so a bucketed decode step needs no batch
+mask and a dead page read contributes exactly 0.
 
 Storage dtype (``APEX_TPU_SERVE_KV_DTYPE``): ``model`` stores k/v in
 the model compute dtype, ``bf16`` forces bfloat16 (the O4/O5-native
@@ -50,7 +64,8 @@ from ..ops.flash_decode import use_decode_head_packing
 
 __all__ = ["KVCacheConfig", "PagedKVCache", "KVCacheManager",
            "PrefixMatch", "CachePoolExhausted", "init_cache",
-           "write_token_kv", "write_prefill_kv", "quantize_kv_rows",
+           "PageWrite", "plan_page_write", "write_token_kv",
+           "write_prefill_kv", "quantize_kv_rows",
            "prefix_chain_keys", "DUMP_BLOCK"]
 
 # block 0: never allocated, pads every block table, absorbs inactive
@@ -106,17 +121,16 @@ class KVCacheConfig:
 
     @property
     def kv_shape(self):
-        """(L, nb, hk, bs, dk) — the packed storage head axes."""
+        """(nb, hk, bs, dk) of ONE layer's k (or v) array — the packed
+        storage head axes; the cache holds ``num_layers`` of each."""
         h, d = self.num_heads, self.head_dim
         hk, dk = (h // 2, 2 * d) if self.packed else (h, d)
-        return (self.num_layers, self.num_blocks, hk,
-                self.block_size, dk)
+        return (self.num_blocks, hk, self.block_size, dk)
 
     @property
     def scale_shape(self):
-        """(L, nb, h, bs) — scales keep GLOBAL head order."""
-        return (self.num_layers, self.num_blocks, self.num_heads,
-                self.block_size)
+        """(nb, h, bs) of one layer's scales — GLOBAL head order."""
+        return (self.num_blocks, self.num_heads, self.block_size)
 
     @property
     def usable_blocks(self) -> int:
@@ -130,36 +144,53 @@ class KVCacheConfig:
         n = 2 * int(np.prod(self.kv_shape)) * per
         if self.quantized:
             n += 2 * int(np.prod(self.scale_shape)) * 4
-        return n
+        return self.num_layers * n
 
 
 class PagedKVCache(NamedTuple):
-    """Device half of the cache (a pytree — jit/donation friendly)."""
+    """Device half of the cache (a pytree — jit/donation friendly):
+    ``num_layers`` arrays a field, one a layer."""
 
-    k: jnp.ndarray                     # (L, nb, hk, bs, dk)
-    v: jnp.ndarray
-    k_scale: Optional[jnp.ndarray]     # (L, nb, h, bs) fp32 | None
-    v_scale: Optional[jnp.ndarray]
+    k: Tuple[jnp.ndarray, ...]               # each (nb, hk, bs, dk)
+    v: Tuple[jnp.ndarray, ...]
+    k_scale: Optional[Tuple[jnp.ndarray, ...]]  # each (nb, h, bs) fp32
+    v_scale: Optional[Tuple[jnp.ndarray, ...]]
 
     def layer(self, i: int):
-        """(k, v, k_scale, v_scale) views of layer ``i``."""
+        """Layer ``i``'s (k, v, k_scale, v_scale) arrays."""
         return (self.k[i], self.v[i],
                 None if self.k_scale is None else self.k_scale[i],
                 None if self.v_scale is None else self.v_scale[i])
 
+    def with_layer(self, i: int, k, v, k_scale=None,
+                   v_scale=None) -> "PagedKVCache":
+        """This cache with layer ``i``'s arrays replaced; every other
+        leaf passes through untouched."""
+        def put(leaves, x):
+            if leaves is None:
+                return None
+            return leaves[:i] + (x,) + leaves[i + 1:]
+
+        return PagedKVCache(put(self.k, k), put(self.v, v),
+                            put(self.k_scale, k_scale),
+                            put(self.v_scale, v_scale))
+
 
 def init_cache(config: KVCacheConfig) -> PagedKVCache:
     """All-zero cache (zeros are the safe dead-page filler: even an
-    unmasked read of a never-written row contributes finite values)."""
-    k = jnp.zeros(config.kv_shape, config.storage_dtype)
-    v = jnp.zeros(config.kv_shape, config.storage_dtype)
+    unmasked read of a never-written row contributes finite values).
+    Every leaf is a DISTINCT buffer: the cache pytree is donated every
+    step, and aliased leaves would donate the same buffer twice."""
+    def leaves(shape, dtype):
+        return tuple(jnp.zeros(shape, dtype)
+                     for _ in range(config.num_layers))
+
+    k = leaves(config.kv_shape, config.storage_dtype)
+    v = leaves(config.kv_shape, config.storage_dtype)
     if config.quantized:
-        # k/v scales must be DISTINCT buffers: the cache pytree is
-        # donated every step, and aliased leaves would donate the same
-        # buffer twice
         return PagedKVCache(k, v,
-                            jnp.zeros(config.scale_shape, jnp.float32),
-                            jnp.zeros(config.scale_shape, jnp.float32))
+                            leaves(config.scale_shape, jnp.float32),
+                            leaves(config.scale_shape, jnp.float32))
     return PagedKVCache(k, v, None, None)
 
 
@@ -189,31 +220,111 @@ def _to_storage(x, config: KVCacheConfig):
     return x.astype(config.storage_dtype), None
 
 
+class PageWrite(NamedTuple):
+    """Where one step's new tokens go, page by page: the same for every
+    layer, so a step plans once (:func:`plan_page_write`) and writes a
+    layer at a time (:func:`write_token_kv`).  Row ``b`` touches ``P``
+    pages: ``slot_blocks`` (b, P) int32 is each one's block id (the
+    dump page where nothing lands), and ``src`` / ``hit`` (b, P * bs)
+    say which of the row's tokens lands on in-page row ``o`` of page
+    ``s``, at ``[b, s * bs + o]``, and whether one does."""
+
+    slot_blocks: jnp.ndarray
+    src: jnp.ndarray
+    hit: jnp.ndarray
+
+
+def plan_page_write(blocks: jnp.ndarray, offsets: jnp.ndarray,
+                    block_size: int) -> PageWrite:
+    """The :class:`PageWrite` of a step's write slots.
+
+    One token a row (the decode step): ``blocks``/``offsets`` (b,)
+    int32, each row's current page and in-page slot.  A chunk a row
+    (the extend step): (b, t).  Tokens whose block is the dump page
+    (inactive rows, a chunk's padding, positions past a row's budget)
+    are not written.  **A row's written tokens sit at contiguous
+    positions**, as a chunk's do, so they cover at most ``P = (t + bs -
+    2) // bs + 1`` pages, counted from the page of the first of them;
+    only that token's offset is read."""
+    if blocks.ndim == 1:
+        blocks, offsets = blocks[:, None], offsets[:, None]
+    bs = block_size
+    b, t = blocks.shape
+    n_slots = (t + bs - 2) // bs + 1
+    written = blocks != DUMP_BLOCK
+    first = jnp.argmax(written, axis=1).astype(jnp.int32)
+    start = first - jnp.take_along_axis(
+        offsets.astype(jnp.int32), first[:, None], axis=1)[:, 0]
+    src = start[:, None] + jnp.arange(n_slots * bs, dtype=jnp.int32)
+    inside = (src >= 0) & (src < t)
+    src = jnp.clip(src, 0, t - 1)
+    hit = inside & jnp.take_along_axis(written, src, axis=1)
+    landed = jnp.where(hit, jnp.take_along_axis(blocks, src, axis=1),
+                       DUMP_BLOCK)
+    # real block ids are > DUMP_BLOCK: a page's id is its tokens' max
+    slot_blocks = landed.reshape(b, n_slots, bs).max(axis=2)
+    return PageWrite(slot_blocks.astype(jnp.int32), src, hit)
+
+
+def _write_pages(arr, new, plan: PageWrite):
+    """``arr`` (nb, hk, bs, *tail) with the rows of ``new`` (b, t, hk,
+    *tail) put in per ``plan``: gather the touched pages, select the
+    new rows in, scatter whole pages back."""
+    b, n_slots = plan.slot_blocks.shape
+    bs, tail = arr.shape[2], arr.ndim - 3
+    pages = arr[plan.slot_blocks]            # (b, P, hk, bs, *tail)
+    if new.shape[1] == 1:
+        rows = new[:, :, :, None]            # the one token, every row
+    else:
+        rows = jnp.take_along_axis(
+            new, plan.src.reshape(b, n_slots * bs,
+                                  *(1,) * (new.ndim - 2)),
+            axis=1)                          # (b, P*bs, hk, *tail)
+        rows = jnp.moveaxis(
+            rows.reshape(b, n_slots, bs, *new.shape[2:]), 2, 3)
+    pages = jnp.where(plan.hit.reshape(b, n_slots, 1, bs, *(1,) * tail),
+                      rows, pages)
+    return arr.at[plan.slot_blocks.reshape(-1)].set(
+        pages.reshape(b * n_slots, *arr.shape[1:]))
+
+
 def write_token_kv(cache: PagedKVCache, config: KVCacheConfig,
                    layer: int, k_new: jnp.ndarray, v_new: jnp.ndarray,
-                   blocks: jnp.ndarray,
-                   offsets: jnp.ndarray) -> PagedKVCache:
-    """Scatter ONE token's k/v per batch row into layer ``layer``'s
-    page slots.
+                   plan: PageWrite) -> PagedKVCache:
+    """Write each batch row's new token(s) into layer ``layer``'s page
+    slots, a page at a time.
 
-    ``k_new``/``v_new`` (b, h, d) in model dtype; ``blocks``/
-    ``offsets`` (b,) int32 address each row's current page and in-page
-    slot (inactive rows point at the dump block).  Per-layer because
-    the decode step interleaves write -> attend inside its layer loop
-    (the new token attends to itself through the cache).  Traced code
-    — runs inside the jitted decode step; the cache argument is
-    donated by the caller so the scatter is in-place on device."""
+    ``k_new``/``v_new`` in model dtype, (b, h, d) for one token a row
+    (the decode step) or (b, t, h, d) for a chunk a row (the extend
+    step); ``plan`` is :func:`plan_page_write` of the step's write
+    slots.
+
+    The write is page-granular (module docstring): the touched pages
+    are gathered, the new rows selected in, and whole pages scattered
+    back, so the layer's array keeps the decode kernel's layout.
+    **Precondition: no two rows of one step write the same page.**
+    Live rows never do: a row owns the pages it writes, and a shared
+    prefix page is made private first (:meth:`KVCacheManager.
+    cow_for_append` / :meth:`~KVCacheManager.make_private`); a chunk's
+    several tokens on one page are put in together.  Pages nothing
+    lands on resolve to the dump page and go back as they came.
+
+    Per-layer because the decode step interleaves write -> attend
+    inside its layer loop (the new token attends to itself through the
+    cache); only layer ``layer``'s leaves change.  Traced code — runs
+    inside the jitted step; the cache argument is donated by the caller
+    so the page scatter is in-place on device."""
+    if k_new.ndim == 3:
+        k_new, v_new = k_new[:, None], v_new[:, None]
     kq, ks = _to_storage(k_new, config)
     vq, vs = _to_storage(v_new, config)
-    # scalar layer index collapses axis 0; the (blocks@0, offsets@2)
-    # advanced pair around the head slice selects (b, hk, dk) rows
-    k = cache.k.at[layer, blocks, :, offsets, :].set(kq)
-    v = cache.v.at[layer, blocks, :, offsets, :].set(vq)
-    k_scale, v_scale = cache.k_scale, cache.v_scale
+    kc, vc, kc_scale, vc_scale = cache.layer(layer)
+    kc = _write_pages(kc, kq, plan)
+    vc = _write_pages(vc, vq, plan)
     if config.quantized:
-        k_scale = k_scale.at[layer, blocks, :, offsets].set(ks)
-        v_scale = v_scale.at[layer, blocks, :, offsets].set(vs)
-    return PagedKVCache(k, v, k_scale, v_scale)
+        kc_scale = _write_pages(kc_scale, ks, plan)
+        vc_scale = _write_pages(vc_scale, vs, plan)
+    return cache.with_layer(layer, kc, vc, kc_scale, vc_scale)
 
 
 def write_prefill_kv(cache: PagedKVCache, config: KVCacheConfig,
@@ -241,13 +352,13 @@ def write_prefill_kv(cache: PagedKVCache, config: KVCacheConfig,
 
     kq, ks = paged(k_all)
     vq, vs = paged(v_all)
-    k = cache.k.at[layer, blocks].set(kq)
-    v = cache.v.at[layer, blocks].set(vq)
-    k_scale, v_scale = cache.k_scale, cache.v_scale
+    kc, vc, kc_scale, vc_scale = cache.layer(layer)
+    kc = kc.at[blocks].set(kq)
+    vc = vc.at[blocks].set(vq)
     if config.quantized:
-        k_scale = k_scale.at[layer, blocks].set(ks)
-        v_scale = v_scale.at[layer, blocks].set(vs)
-    return PagedKVCache(k, v, k_scale, v_scale)
+        kc_scale = kc_scale.at[blocks].set(ks)
+        vc_scale = vc_scale.at[blocks].set(vs)
+    return cache.with_layer(layer, kc, vc, kc_scale, vc_scale)
 
 
 def prefix_chain_keys(prompt: Sequence[int], block_size: int):

@@ -39,8 +39,8 @@ from ..ops.quant_matmul import (QuantGPTServingWeights,
                                 QuantLayerWeights, quant_matmul,
                                 quantize_weights)
 from . import rope_moe
-from .kv_cache import (KVCacheConfig, PagedKVCache, write_prefill_kv,
-                       write_token_kv)
+from .kv_cache import (KVCacheConfig, PagedKVCache, plan_page_write,
+                       write_prefill_kv, write_token_kv)
 from .rope_moe import (MOE_TICK_COUNTERS, LayerSpec, RopeMoEWeights,
                        RopeSpec, init_rope_moe_weights)
 
@@ -563,11 +563,12 @@ def decode_logits(weights, cfg, cache_cfg, cache, tokens, positions,
     x = _embed(weights, tokens, positions, cfg)   # (b, H)
     live = seq_lens > 0 if cfg.layers else None    # rows the MoE counts
     counters = None
+    write = plan_page_write(write_blocks, write_offsets,
+                            cache_cfg.block_size)
     for i, lw in enumerate(weights.layers):
         spec = _spec(cfg, i)
         a_in, q, k, v = _attn_inputs(x, lw, cfg, spec, positions, h, d)
-        cache = write_token_kv(cache, cache_cfg, i, k, v,
-                               write_blocks, write_offsets)
+        cache = write_token_kv(cache, cache_cfg, i, k, v, write)
         kc, vc, ks, vs = cache.layer(i)
         attn = flash_decode if cfg.decode_attention == "kernel" \
             else paged_attention_reference
@@ -632,14 +633,14 @@ def extend_logits(weights, cfg, cache_cfg, cache, tokens, block_tables,
     # padding rows sit at negative positions: clamp the embedding
     # lookup (their output is discarded; attention masks them to 0)
     x = _embed(weights, tokens, jnp.maximum(pos, 0), cfg)  # (b, t, H)
-    wb = write_blocks.reshape(b * t)
-    wo = write_offsets.reshape(b * t)
+    # the chunk is planned as (b, t): several of a row's tokens land on
+    # one page, and the page write puts them in together
+    write = plan_page_write(write_blocks, write_offsets,
+                            cache_cfg.block_size)
     for i, lw in enumerate(weights.layers):
         spec = _spec(cfg, i)
         a_in, q, k, v = _attn_inputs(x, lw, cfg, spec, pos, h, d)
-        cache = write_token_kv(cache, cache_cfg, i,
-                               k.reshape(b * t, h, d),
-                               v.reshape(b * t, h, d), wb, wo)
+        cache = write_token_kv(cache, cache_cfg, i, k, v, write)
         kc, vc, ks, vs = cache.layer(i)
         attn = flash_decode_multi if cfg.decode_attention == "kernel" \
             else paged_attention_multi_reference
@@ -688,16 +689,10 @@ def copy_cache_block(cache: PagedKVCache, src: jnp.ndarray,
     k+v+scales) into block ``dst``.  Traced code — the engine jits it
     once per cache (src/dst ride as data, so every CoW reuses the one
     compiled program) with the cache donated, making the copy an
-    in-place page-sized DMA."""
+    in-place page-sized DMA a leaf."""
     src = jnp.asarray(src, jnp.int32)
     dst = jnp.asarray(dst, jnp.int32)
-    k = cache.k.at[:, dst].set(cache.k[:, src])
-    v = cache.v.at[:, dst].set(cache.v[:, src])
-    k_scale, v_scale = cache.k_scale, cache.v_scale
-    if k_scale is not None:
-        k_scale = k_scale.at[:, dst].set(k_scale[:, src])
-        v_scale = v_scale.at[:, dst].set(v_scale[:, src])
-    return PagedKVCache(k, v, k_scale, v_scale)
+    return jax.tree.map(lambda a: a.at[dst].set(a[src]), cache)
 
 
 def gather_cache_blocks(cache: PagedKVCache, blocks: jnp.ndarray):
@@ -705,28 +700,26 @@ def gather_cache_blocks(cache: PagedKVCache, blocks: jnp.ndarray):
     contiguous payload — the EXPORT half of the disaggregated
     prefill→decode KV handoff (serving/fleet.py).  Returns
     ``(k, v, k_scale, v_scale)`` with ``k``/``v`` shaped
-    ``(L, n, hk, bs, dk)`` (the storage layout, bytes untouched — an
-    int8 cache ships int8 rows + their fp32 scales, a bf16 cache
-    ships bf16) and scales ``(L, n, h, bs)`` or None.  Traced code:
+    ``(L, n, hk, bs, dk)``: the layers' page spans stacked, the wire
+    format (storage bytes untouched — an int8 cache ships int8 rows +
+    their fp32 scales, a bf16 cache ships bf16) and scales
+    ``(L, n, h, bs)`` or None.  Traced code:
     the fleet jits it with the block list as data, padded to a page
     rung, so every export of a rung-sized span reuses one compiled
     program (dump-page padding gathers harmless zeros the importer
     drops)."""
     blocks = jnp.asarray(blocks, jnp.int32)
-    k = jnp.take(cache.k, blocks, axis=1)
-    v = jnp.take(cache.v, blocks, axis=1)
-    ks = vs = None
-    if cache.k_scale is not None:
-        ks = jnp.take(cache.k_scale, blocks, axis=1)
-        vs = jnp.take(cache.v_scale, blocks, axis=1)
-    return k, v, ks, vs
+    return tuple(None if leaves is None
+                 else jnp.stack([a[blocks] for a in leaves])
+                 for leaves in cache)
 
 
 def scatter_cache_blocks(cache: PagedKVCache, k: jnp.ndarray,
                          v: jnp.ndarray, k_scale, v_scale,
                          blocks: jnp.ndarray) -> PagedKVCache:
     """Write an exported payload into ``blocks`` of this cache — the
-    IMPORT half of the KV handoff.  Shapes/dtypes must match this
+    IMPORT half of the KV handoff: layer ``i`` of the stacked payload
+    goes to layer ``i``'s arrays.  Shapes/dtypes must match this
     cache's storage layout exactly (the fleet validates the two
     replicas' :class:`~.kv_cache.KVCacheConfig` geometry before any
     transfer); the cache is donated by the jitted caller so the
@@ -734,10 +727,7 @@ def scatter_cache_blocks(cache: PagedKVCache, k: jnp.ndarray,
     the dump block overwrite only the dump page (never read
     unmasked)."""
     blocks = jnp.asarray(blocks, jnp.int32)
-    ck = cache.k.at[:, blocks].set(k)
-    cv = cache.v.at[:, blocks].set(v)
-    cks, cvs = cache.k_scale, cache.v_scale
-    if cks is not None:
-        cks = cks.at[:, blocks].set(k_scale)
-        cvs = cvs.at[:, blocks].set(v_scale)
-    return PagedKVCache(ck, cv, cks, cvs)
+    return PagedKVCache(*(
+        None if leaves is None
+        else tuple(a.at[blocks].set(x[i]) for i, a in enumerate(leaves))
+        for leaves, x in zip(cache, (k, v, k_scale, v_scale))))

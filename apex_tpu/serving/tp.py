@@ -92,9 +92,10 @@ def serving_tp_plan(tp: int, num_layers: int, *,
                     weight_quantized: bool = False) -> MeshPlan:
     """The TP serving topology contract for the audited decode entry:
     weight specs under ``in0``, the paged cache's head axis (storage
-    axis 2 of ``(L, nb, hk, bs, dk)``) under ``in1`` and on the
-    returned-cache outputs (``out0``/``out1``; int8 caches add the
-    scale leaves), and the 2-psums-per-layer ceiling.  The runtime
+    axis 1 of each layer's ``(nb, hk, bs, dk)``) under ``in1`` and on
+    the returned-cache outputs (the first ``2 * num_layers`` flat
+    outputs; int8 caches add the scale leaves), and the
+    2-psums-per-layer ceiling.  The runtime
     (:class:`TPContext`) derives its shard_map in/out specs and jit
     in_shardings from THIS object, so plan drift is an APX703
     finding, not a silent reshard."""
@@ -102,18 +103,17 @@ def serving_tp_plan(tp: int, num_layers: int, *,
     for pat, spec in serving_weight_specs(
             axis, weight_quantized=weight_quantized).items():
         specs[r"^in0.*" + pat] = spec
-    cache_spec = (None, None, axis)
-    if quantized:
-        specs[r"^in1\.(k|v)_scale$"] = cache_spec
-        specs[r"^in1\.(k|v)$"] = cache_spec
-        # flat output order of (PagedKVCache, tokens): k, v, k_scale,
-        # v_scale, next_tokens
-        specs[r"^out[0-3]$"] = cache_spec
-        specs[r"^out4$"] = ()
-    else:
-        specs[r"^in1\.(k|v)$"] = cache_spec
-        specs[r"^out[01]$"] = cache_spec
-        specs[r"^out2$"] = ()
+    # one (nb, hk, bs, dk) array a layer (scales (nb, h, bs)): the head
+    # axis is storage axis 1 of every leaf
+    cache_spec = (None, axis)
+    fields = r"(k|v)(_scale)?" if quantized else r"(k|v)"
+    specs[r"^in1\.%s\[\d+\]$" % fields] = cache_spec
+    # flat output order of (PagedKVCache, tokens): every layer's k, then
+    # v (then k_scale, v_scale), then next_tokens
+    n_leaves = (4 if quantized else 2) * int(num_layers)
+    specs[r"^out(%s)$" % "|".join(map(str, range(n_leaves)))] = \
+        cache_spec
+    specs[r"^out%d$" % n_leaves] = ()
     return MeshPlan.build(
         axes=((axis, int(tp), "tensor"),),
         tensor_specs=specs,
@@ -182,9 +182,9 @@ class TPContext:
                 f"{local.num_heads}-head shard packs={local.packed} — "
                 f"choose tp so heads/tp stays even (or disable "
                 f"APEX_TPU_FLASH_PACK_D64)")
-        if cache_cfg.kv_shape[2] % tp:
+        if cache_cfg.kv_shape[1] % tp:
             raise ValueError(
-                f"cache head axis {cache_cfg.kv_shape[2]} not "
+                f"cache head axis {cache_cfg.kv_shape[1]} not "
                 f"divisible by tp {tp}")
         self.tp = int(tp)
         self.axis = axis

@@ -27,8 +27,8 @@ from apex_tpu.serving import (DUMP_BLOCK, BucketLadder,
                               KVCacheManager, Request, ServingEngine,
                               ServingModelConfig, default_cache_config,
                               extract_serving_weights, init_cache,
-                              quantize_kv_rows, write_prefill_kv,
-                              write_token_kv)
+                              plan_page_write, quantize_kv_rows,
+                              write_prefill_kv, write_token_kv)
 from apex_tpu.testing.standalone_gpt import GPTModel, serve_smoke
 
 
@@ -427,6 +427,74 @@ class TestFlashDecodeMultiParity:
 # KV paging invariants
 # ---------------------------------------------------------------------------
 
+
+# --- the page write's cases: per row (first position, tokens written,
+# where the chunk's unwritten slots are, the row's block table) ---------
+
+_PAGE_WRITE_CASES = {
+    # one token a row (a decode tick), the third row inactive
+    "decode": (1, [(6, 1, None, [2, 5]), (3, 1, None, [7]),
+                   (0, 0, None, [])]),
+    # several tokens of a chunk on one page
+    "extend_same_page": (3, [(4, 3, None, [2, 5]), (0, 3, None, [7]),
+                             (0, 0, "front", [])]),
+    # a chunk across a page boundary, and one ending a page exactly
+    "extend_crosses_page": (4, [(2, 4, None, [2, 5]), (4, 4, None, [7, 8]),
+                                (0, 0, "front", [])]),
+    # a chunk over three pages, and one page-aligned over two
+    "extend_three_pages": (6, [(3, 6, None, [9, 3, 4]),
+                               (0, 6, None, [6, 1]),
+                               (0, 0, "front", [])]),
+    # front padding: the written tokens are the chunk's last
+    "extend_front_padded": (4, [(5, 2, "front", [2, 5]),
+                                (3, 3, "front", [7, 8]),
+                                (0, 1, "front", [4])]),
+    # a speculative row near its budget: the overshoot goes to the dump
+    "extend_back_padded": (4, [(3, 2, "back", [2, 5]),
+                               (6, 1, "back", [7, 8]),
+                               (0, 4, None, [4])]),
+}
+
+
+def _page_write_slots(case, bs):
+    """(blocks, offsets) as the engine builds them: (b,) for one token
+    a row, else (b, t); unwritten slots at the dump page, offset 0."""
+    t, rows = case
+    blocks = np.full((len(rows), t), DUMP_BLOCK, np.int32)
+    offsets = np.zeros((len(rows), t), np.int32)
+    for i, (first, n, pad, table) in enumerate(rows):
+        at = t - n if pad == "front" else 0
+        for j in range(n):
+            blocks[i, at + j] = table[(first + j) // bs]
+            offsets[i, at + j] = (first + j) % bs
+    if t == 1:
+        return blocks[:, 0], offsets[:, 0]
+    return blocks, offsets
+
+
+def _row_by_row_write(cache, cfg, layer, k, v, blocks, offsets):
+    """The plain reference of :func:`write_token_kv`: every token's
+    storage row set alone, ``arr[block, :, offset] = row``, in numpy;
+    dump-page tokens left out.  Returns numpy leaves."""
+    out = [None if leaves is None else [np.array(a) for a in leaves]
+           for leaves in cache]
+    h, d = cfg.num_heads, cfg.head_dim
+    blocks, offsets = blocks.reshape(-1), offsets.reshape(-1)
+    for new, arr, scales in ((k, out[0], out[2]), (v, out[1], out[3])):
+        rows = np.asarray(new, np.float32).reshape(-1, h, d)
+        for row, blk, off in zip(rows, blocks, offsets):
+            if blk == DUMP_BLOCK:
+                continue
+            if cfg.quantized:
+                q, scale = quantize_kv_rows(jnp.asarray(row))
+                scales[layer][blk, :, off] = np.asarray(scale)
+                row = np.asarray(q)
+            if cfg.packed:
+                row = row.reshape(h // 2, 2 * d)
+            arr[layer][blk, :, off] = row.astype(arr[layer].dtype)
+    return out
+
+
 def _cfg(**kw):
     base = dict(num_layers=1, num_heads=2, head_dim=8, num_blocks=6,
                 block_size=4)
@@ -502,8 +570,9 @@ class TestCacheWrites:
         cache = init_cache(cfg)
         k = jax.random.normal(jax.random.PRNGKey(0),
                               (2, cfg.num_heads, cfg.head_dim))
-        cache = write_token_kv(cache, cfg, 0, k, k * 2.0,
-                               jnp.asarray([1, 3]), jnp.asarray([2, 0]))
+        cache = write_token_kv(
+            cache, cfg, 0, k, k * 2.0, plan_page_write(
+                jnp.asarray([1, 3]), jnp.asarray([2, 0]), cfg.block_size))
         kc, vc, ks, vs = cache.layer(0)
         got_k = paged_attention_reference(
             jnp.ones((2, cfg.num_heads, cfg.head_dim)), kc, vc,
@@ -537,16 +606,68 @@ class TestCacheWrites:
         step = init_cache(cfg)
         for t in range(n):
             step = write_token_kv(
-                step, cfg, 0, k[t][None], v[t][None],
-                jnp.asarray([int(blocks[t // bs])]),
-                jnp.asarray([t % bs]))
-        got = np.asarray(whole.k)
-        want = np.asarray(step.k)
+                step, cfg, 0, k[t][None], v[t][None], plan_page_write(
+                    jnp.asarray([int(blocks[t // bs])]),
+                    jnp.asarray([t % bs]), bs))
+        got = np.asarray(whole.k[0])
+        want = np.asarray(step.k[0])
         # rows past n were zero-padded in the whole-prompt write
-        np.testing.assert_array_equal(got[0, 2], want[0, 2])
-        np.testing.assert_array_equal(got[0, 4, :, :n - bs],
-                                      want[0, 4, :, :n - bs])
+        np.testing.assert_array_equal(got[2], want[2])
+        np.testing.assert_array_equal(got[4, :, :n - bs],
+                                      want[4, :, :n - bs])
 
+    def test_cache_is_one_array_a_layer(self):
+        cfg = _cfg(num_layers=3, kv_dtype="int8")
+        cache = init_cache(cfg)
+        assert len(cache.k) == len(cache.v) == 3
+        assert len(cache.k_scale) == len(cache.v_scale) == 3
+        assert cfg.kv_shape == (6, 2, 4, 8) and cfg.scale_shape == (6, 2, 4)
+        assert all(a.shape == cfg.kv_shape and a.dtype == jnp.int8
+                   for a in cache.k + cache.v)
+        assert all(a.shape == cfg.scale_shape
+                   for a in cache.k_scale + cache.v_scale)
+        # donated every step: no two leaves may share a buffer
+        leaves = jax.tree.leaves(cache)
+        assert len({a.unsafe_buffer_pointer() for a in leaves}) == 12
+        assert cfg.cache_nbytes() == sum(a.nbytes for a in leaves)
+        assert init_cache(_cfg()).k_scale is None
+
+    @pytest.mark.parametrize("packed", [False, True],
+                             ids=["unpacked", "packed"])
+    @pytest.mark.parametrize("kv_dtype", ["model", "bf16", "int8"])
+    @pytest.mark.parametrize("case", list(_PAGE_WRITE_CASES))
+    def test_page_write_matches_row_writes(self, case, kv_dtype, packed):
+        # the page-granular write (gather pages, put rows in, scatter
+        # pages back) against a plain row-by-row numpy write
+        cfg = _cfg(num_layers=2, num_blocks=10, kv_dtype=kv_dtype,
+                   head_dim=64 if packed else 8)
+        assert cfg.packed == packed
+        blocks, offsets = _page_write_slots(_PAGE_WRITE_CASES[case],
+                                            cfg.block_size)
+        rng = np.random.RandomState(7)
+
+        def fill(shape, dtype):
+            x = rng.randint(-100, 100, shape) if dtype == jnp.int8 \
+                else rng.randn(*shape)
+            return jnp.asarray(x).astype(dtype)
+
+        cache = init_cache(cfg)
+        cache = jax.tree.map(lambda a: fill(a.shape, a.dtype), cache)
+        new = blocks.shape + (cfg.num_heads, cfg.head_dim)
+        k = jnp.asarray(rng.randn(*new), jnp.float32)
+        v = jnp.asarray(rng.randn(*new), jnp.float32)
+        got = write_token_kv(cache, cfg, 1, k, v, plan_page_write(
+            jnp.asarray(blocks), jnp.asarray(offsets), cfg.block_size))
+        want = _row_by_row_write(cache, cfg, 1, k, v, blocks, offsets)
+        for name, g, w, before in zip(got._fields, got, want, cache):
+            if g is None:
+                assert w is None
+                continue
+            # only layer 1's leaves change; layer 0's pass through
+            assert g[0] is before[0], name
+            # the dump page holds whatever: it is never read unmasked
+            np.testing.assert_array_equal(
+                np.asarray(g[1])[1:], w[1][1:], err_msg=name)
     def test_quantize_rows_roundtrip(self):
         x = jax.random.normal(jax.random.PRNGKey(3), (4, 3, 16)) * 5.0
         q, s = quantize_kv_rows(x)

@@ -349,13 +349,32 @@ class TestPrefixSharingEngine:
         hit = eng.manager.match_prefix(SYS + [0])
         assert hit.warm and hit.cow
         shared = list(hit.blocks[:-1])      # the CoW page may rewrite
-        before = np.asarray(eng.cache.k[:, shared])
+        before = np.stack([np.asarray(a)[shared] for a in eng.cache.k])
         eng.submit(Request(rid="warm", prompt=SYS + [0],
                            max_new_tokens=6))
         s = eng.run()
         assert s.cow_copies >= 1
-        after = np.asarray(eng.cache.k[:, shared])
+        after = np.stack([np.asarray(a)[shared] for a in eng.cache.k])
         np.testing.assert_array_equal(before, after)
+
+    @pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+    def test_copy_cache_block_copies_every_leaf(self, kv_dtype):
+        # the copy-on-write program: block src -> dst in every layer's
+        # k, v (and scales), nothing else touched
+        from apex_tpu.serving import copy_cache_block, init_cache
+        cc = KVCacheConfig(num_layers=3, num_heads=2, head_dim=8,
+                           num_blocks=6, block_size=4, kv_dtype=kv_dtype)
+        rng = np.random.RandomState(0)
+        cache = jax.tree.map(
+            lambda a: jnp.asarray(rng.randint(-90, 90, a.shape)
+                                  ).astype(a.dtype), init_cache(cc))
+        out = copy_cache_block(cache, 2, 5)
+        leaves, got = jax.tree.leaves(cache), jax.tree.leaves(out)
+        assert len(got) == len(leaves) == (12 if cc.quantized else 6)
+        for a, b in zip(leaves, got):
+            a, b = np.asarray(a), np.asarray(b)
+            np.testing.assert_array_equal(b[5], a[2])
+            np.testing.assert_array_equal(b[:5], a[:5])
 
     def test_warm_admission_prefills_only_tail(self, tiny):
         model, params = tiny
@@ -427,6 +446,22 @@ class TestChunkedPrefill:
                       ladder=BucketLadder(batch=(2, 4), pages=(2, 4),
                                           chunks=(4,)),
                       prefill_chunk=4)
+        s, tokens = _run(eng, PROMPTS)
+        assert tokens == baseline
+        assert s.prefill_chunks > 0
+
+    @pytest.mark.parametrize("chunk", [2, 3, 6])
+    def test_chunks_against_the_page_size(self, tiny, baseline, chunk):
+        # pages hold 4 tokens: chunks of 2 put several tokens of a chunk
+        # on one page, chunks of 3 cross a page edge every other time,
+        # chunks of 6 cover two or three pages; the last chunk of a
+        # prompt is front-padded onto the dump page.  The page write
+        # must lose none of them.
+        model, params = tiny
+        eng = _engine(model, params,
+                      ladder=BucketLadder(batch=(2, 4), pages=(2, 4),
+                                          chunks=(chunk,)),
+                      prefill_chunk=chunk)
         s, tokens = _run(eng, PROMPTS)
         assert tokens == baseline
         assert s.prefill_chunks > 0

@@ -106,9 +106,18 @@ class TestTensorParallel:
         assert plan.spec_for("in0.layers[0].fc2_k") == ("tensor", None)
         assert plan.spec_for("in0.wte") is None          # replicated
         assert plan.spec_for("in0.layers[0].dense_b") is None
-        assert plan.spec_for("in1.k") == (None, None, "tensor")
-        assert plan.spec_for("out0") == (None, None, "tensor")
-        assert plan.spec_for("out2") == ()
+        # the cache: one (nb, hk, bs, dk) leaf a layer, heads on axis 1;
+        # 3 layers' k then v are flat outputs 0-5, the tokens the 6th
+        assert plan.spec_for("in1.k[0]") == (None, "tensor")
+        assert plan.spec_for("in1.v[2]") == (None, "tensor")
+        assert plan.spec_for("in1.k_scale[0]") is None   # bf16 plan
+        assert plan.spec_for("out0") == (None, "tensor")
+        assert plan.spec_for("out5") == (None, "tensor")
+        assert plan.spec_for("out6") == ()
+        q8 = serving_tp_plan(2, num_layers=3, quantized=True)
+        assert q8.spec_for("in1.v_scale[1]") == (None, "tensor")
+        assert q8.spec_for("out11") == (None, "tensor")
+        assert q8.spec_for("out12") == ()
 
     def test_context_validation(self, smoke_weights):
         cfg, _, _ = smoke_weights
@@ -176,6 +185,27 @@ class TestTensorParallel:
         assert got == want
         assert s.requests_done == 5
 
+    def test_tp_cache_leaves_shard_on_heads(self, smoke_weights):
+        # one (nb, hk, bs, dk) leaf a layer, each split on its head
+        # axis, before a step and after (the steps' out_shardings)
+        cfg, weights, _ = smoke_weights
+        e = make_engine(cfg, weights, tp=2)
+        nb, hk, bs, dk = e.cache_cfg.kv_shape
+
+        def held():
+            assert len(e.cache.k) == len(e.cache.v) == LAYERS
+            for leaf in e.cache.k + e.cache.v:
+                assert leaf.shape == (nb, hk, bs, dk)
+                assert tuple(leaf.sharding.spec) == (None, "tensor")
+                assert {s.data.shape for s in
+                        leaf.addressable_shards} == {(nb, hk // 2, bs, dk)}
+
+        held()
+        for r in make_requests(3, seed=4):
+            e.submit(r)
+        e.run()
+        held()
+
     def test_tp_swap_keeps_ladder(self, smoke_weights):
         cfg, weights, weights2 = smoke_weights
         e = make_engine(cfg, weights, tp=2,
@@ -202,27 +232,34 @@ class TestKVTransfer:
                            num_blocks=8, block_size=4,
                            kv_dtype=kv_dtype)
         src = init_cache(cc)
-        key = jax.random.PRNGKey(0)
-        fill = jax.random.normal(key, cc.kv_shape, jnp.float32) \
-            .astype(cc.storage_dtype)
-        src = src._replace(k=fill, v=fill * 2 if kv_dtype != "int8"
-                           else fill)
+        keys = jax.random.split(jax.random.PRNGKey(0), cc.num_layers)
+        fill = tuple(jax.random.normal(key, cc.kv_shape, jnp.float32)
+                     .astype(cc.storage_dtype) for key in keys)
+        src = src._replace(k=fill, v=fill if kv_dtype == "int8"
+                           else tuple(f * 2 for f in fill))
         if cc.quantized:
-            sc = jax.random.uniform(key, cc.scale_shape, jnp.float32)
-            src = src._replace(k_scale=sc, v_scale=sc * 0.5)
+            sc = tuple(jax.random.uniform(key, cc.scale_shape,
+                                          jnp.float32) for key in keys)
+            src = src._replace(k_scale=sc,
+                               v_scale=tuple(x * 0.5 for x in sc))
         blocks = jnp.asarray([3, 1, 5], jnp.int32)
         k, v, ks, vs = gather_cache_blocks(src, blocks)
-        assert k.shape == (2, 3) + cc.kv_shape[2:]
+        # the wire format stacks the layers' page spans
+        assert k.shape == (2, 3) + cc.kv_shape[1:]
         dst = scatter_cache_blocks(init_cache(cc), k, v, ks, vs,
                                    jnp.asarray([2, 4, 6], jnp.int32))
-        np.testing.assert_array_equal(
-            np.asarray(dst.k[:, 2]), np.asarray(src.k[:, 3]))
-        np.testing.assert_array_equal(
-            np.asarray(dst.v[:, 6]), np.asarray(src.v[:, 5]))
-        if cc.quantized:
+        for i in range(cc.num_layers):
             np.testing.assert_array_equal(
-                np.asarray(dst.k_scale[:, 4]),
-                np.asarray(src.k_scale[:, 1]))
+                np.asarray(dst.k[i][2]), np.asarray(src.k[i][3]))
+            np.testing.assert_array_equal(
+                np.asarray(dst.v[i][6]), np.asarray(src.v[i][5]))
+            # pages the import did not name stay as they were
+            assert not np.asarray(dst.k[i])[[1, 3, 5]].any()
+            if cc.quantized:
+                assert ks.shape == (2, 3) + cc.scale_shape[1:]
+                np.testing.assert_array_equal(
+                    np.asarray(dst.k_scale[i][4]),
+                    np.asarray(src.k_scale[i][1]))
 
     def test_register_external_parks_idle_and_admits_warm(self):
         cc = KVCacheConfig(num_layers=1, num_heads=2, head_dim=8,
@@ -290,6 +327,74 @@ class TestKVTransfer:
         assert got == want
         assert s.warm_prefix_admissions == 3
         assert s.prefix_hit_tokens > 0
+
+    @pytest.mark.parametrize("src_tp,dst_tp", [(None, 2), (2, None),
+                                               (2, 2)])
+    def test_transfer_across_tp_layouts(self, smoke_weights, src_tp,
+                                        dst_tp):
+        # the wire format is the layers' page spans stacked, whatever
+        # the two pools' shardings: a prompt prefilled on one layout
+        # decodes warm on the other, token for token
+        cfg, weights, _ = smoke_weights
+        prompt = make_requests(1, seed=21, min_len=7)[0].prompt
+        solo = make_engine(cfg, weights, prefix_share=True)
+        solo.submit(Request(rid="x", prompt=list(prompt),
+                            max_new_tokens=4))
+        solo.run()
+        pf = make_engine(cfg, weights, prefix_share=True, tp=src_tp)
+        dec = make_engine(cfg, weights, prefix_share=True, tp=dst_tp)
+        pf.submit(Request(rid="pf", prompt=list(prompt),
+                          max_new_tokens=1))
+        pf.run()
+        n = transfer_prefix(pf, dec, prompt)
+        assert n == dec.cache_cfg.blocks_for(len(prompt))
+        src_blocks = pf.manager.resident_prefix(prompt)
+        dst_blocks = dec.manager.resident_prefix(prompt)
+        for a, b in zip(pf.cache.k + pf.cache.v,
+                        dec.cache.k + dec.cache.v):
+            np.testing.assert_array_equal(
+                np.asarray(a)[src_blocks], np.asarray(b)[dst_blocks])
+        dec.submit(Request(rid="x", prompt=list(prompt),
+                           max_new_tokens=4))
+        s = dec.run()
+        assert s.warm_prefix_admissions == 1
+        assert dec.done[0].out_tokens == solo.done[0].out_tokens
+
+    @pytest.mark.parametrize("kv_dtype", ["model", "int8"])
+    def test_host_payload_roundtrip(self, smoke_weights, kv_dtype):
+        # the socket handoff: export to host arrays in the wire shape
+        # (L, n, hk, bs, dk), import on another replica, decode warm
+        from apex_tpu.serving.fleet import (export_prefix_payload,
+                                            import_prefix_payload)
+        cfg, weights, _ = smoke_weights
+        cc = default_cache_config(cfg, num_blocks=32, block_size=4,
+                                  kv_dtype=kv_dtype)
+
+        def engine():
+            return ServingEngine(
+                weights, cfg, cc, prefix_share=True,
+                ladder=BucketLadder(batch=(2, 4), pages=(2, 4)))
+
+        prompt = make_requests(1, seed=23, min_len=6)[0].prompt
+        solo, pf, dec = engine(), engine(), engine()
+        for e, new in ((solo, 4), (pf, 1)):
+            e.submit(Request(rid="x", prompt=list(prompt),
+                             max_new_tokens=new))
+            e.run()
+        n, arrays = export_prefix_payload(pf, prompt)
+        pn = pf.ladder.pick_pages(n)
+        assert arrays["k"].shape == (LAYERS, pn) + cc.kv_shape[1:]
+        assert arrays["v"].dtype == np.dtype(cc.storage_dtype)
+        assert ("ks" in arrays) == cc.quantized
+        if cc.quantized:
+            assert arrays["vs"].shape == (LAYERS, pn) + cc.scale_shape[1:]
+        assert import_prefix_payload(dec, prompt, n, arrays) == n
+        assert import_prefix_payload(dec, prompt, n, arrays) == 0
+        dec.submit(Request(rid="x", prompt=list(prompt),
+                           max_new_tokens=4))
+        s = dec.run()
+        assert s.warm_prefix_admissions == 1
+        assert dec.done[0].out_tokens == solo.done[0].out_tokens
 
     def test_transfer_unresident_returns_none(self, smoke_weights):
         cfg, weights, _ = smoke_weights

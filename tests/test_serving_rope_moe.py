@@ -189,6 +189,49 @@ class TestAgainstTheReference:
                     assert np.abs(got - want).max() < F32_TOL, (i, done)
                     done[i] += n
 
+    @pytest.mark.parametrize("t", [3, 8])
+    def test_chunks_leave_the_cache_a_prefill_leaves(self, t):
+        """The page write of the extend step, chunks of 3 (several
+        tokens on a page of 4, every other chunk across an edge) and of
+        8 (three pages a chunk once the front-padded first chunk has
+        shifted them), against the prefill's whole-page scatter: the same
+        rows in the same slots of every layer's k and v."""
+        cfg, w = model(decode_attention="reference")
+        ccfg, empty = cache_for(cfg)
+        assert len(empty.k) == len(empty.v) == TINY["num_hidden_layers"]
+        assert all(a.shape == (40, 2, BLOCK, 16) for a in empty.k + empty.v)
+        seq = tokens_of(22, seed=5)
+        blocks = np.array([5, 17, 2, 31, 8, 12])
+        whole, _ = prefill(cfg, ccfg, empty, w, seq, blocks, 8)
+        cache = cache_for(cfg)[1]
+        table = np.zeros((1, 8), np.int32)
+        table[0, :len(blocks)] = blocks
+        done = 0
+        while done < len(seq):
+            n = min(t - (1 if done == 0 else 0), len(seq) - done)
+            toks, wb, wo = (np.zeros((1, t), np.int32) for _ in range(3))
+            for j in range(n):
+                p = done + j
+                toks[0, t - n + j] = seq[p]
+                wb[0, t - n + j] = blocks[p // BLOCK]
+                wo[0, t - n + j] = p % BLOCK
+            done += n
+            cache, _ = extend_logits(
+                w, cfg, ccfg, cache, jnp.asarray(toks), jnp.asarray(table),
+                jnp.asarray([done], jnp.int32), jnp.asarray(wb),
+                jnp.asarray(wo))
+        rows = np.arange(len(seq))
+        for got, want in zip(cache.k + cache.v, whole.k + whole.v):
+            got, want = np.asarray(got), np.asarray(want)
+            # (tokens, heads, d) of the prompt's slots, in position order
+            g = got[blocks[rows // BLOCK], :, rows % BLOCK]
+            x = want[blocks[rows // BLOCK], :, rows % BLOCK]
+            assert np.abs(x).max() > 0.01
+            np.testing.assert_allclose(g, x, rtol=0, atol=1e-5)
+            # and no page that is not the prompt's (or the dump) touched
+            others = np.setdiff1d(np.arange(1, 40), blocks)
+            assert not got[others].any()
+
     def test_a_window_edge_off_by_one_fails_the_tolerance(self):
         """The tolerance has teeth: the same forward with the window one
         key short or one key long is far outside it."""

@@ -12,14 +12,18 @@ The topology is described inside a fixture: only one process may load
 libtpu, and xdist workers all import this file.  All compile cases live
 in this one file for the same reason.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from apex_tpu import serving
 from apex_tpu.ops import (flash_attention as fa, flash_decode as fd,
                           fused_pipeline, layer_norm as ln, moe_routing,
                           quant_matmul as qm, scaled_softmax)
+from apex_tpu.serving import model as serving_model
 
 # GPT-2 345M: 16 heads of 64, hidden 1024, vocab 50304; train batch 8 x
 # seq 1024; serving batch 8, KV block 16, 64 pages (= 1024 tokens).
@@ -303,3 +307,160 @@ def test_kernel_compiles_for_v5e(name, one_chip, mosaic,
             for s, dt in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# --- whole serving programs: the paged cache keeps the kernel's layout ----
+#
+# A decode tick once spent four fifths of its time copying the KV cache
+# from the layout the Pallas kernel reads to the one XLA's row scatter
+# wanted, and back (PERF.md, PR 30).  The compiled program shows such a
+# copy without a chip, so here the decode and the extend step are
+# compiled whole at the benchmark cells' sizes, cache donated, and their
+# optimized HLO is held to: nothing the size of a layer's cache array
+# but the in-place page writes, every cache leaf aliased to its output,
+# and temporaries far under one leaf.
+
+TEMP_LIMIT = 64 << 20
+# what may produce something cache-sized: a parameter, a view of one,
+# and the kernel-free bookkeeping of a tuple
+_FREE_OPS = {"parameter", "bitcast", "get-tuple-element", "tuple"}
+_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%\S+ = (?P<type>\(?\w+\[[\d,]*\]\S*) (?P<op>[\w-]+)\(")
+# a fusion's backend config where the compiler writes the result over
+# operand 0
+_OVER_OPERAND_0 = '"aliasing_operands":{"lists":[{"indices":["0",'
+
+
+def _holds_leaf(result_type: str, leaf_shape) -> bool:
+    """Whether an HLO result type has an array of a cache leaf's shape,
+    alone or stacked (``[nb,hk,bs,dk]``, ``[1,nb,hk,bs,dk]``, ...)."""
+    tail = ",".join(map(str, leaf_shape))
+    return any(dims == tail or dims.endswith("," + tail)
+               for dims in re.findall(r"\w+\[([\d,]*)\]", result_type))
+
+
+def cache_sized_ops(hlo_text: str, leaf_shape):
+    """Every instruction of the entry computation whose result is a
+    whole cache leaf ``leaf_shape`` = (nb, hk, bs, dk) or a stack of
+    them, as ``(opcode, result type, in place)``: ``in place`` where the
+    compiler says the result aliases operand 0 (``aliasing_operands``
+    of a fusion's backend config), as a page scatter into a donated
+    cache array does.  What a reader wants of a serving program's text:
+    a ``copy``, ``slice`` or ``fusion`` here that is not in place moves
+    a whole layer's cache."""
+    entry = hlo_text[hlo_text.index("\nENTRY "):]
+    found = []
+    for line in entry.splitlines():
+        m = _INSTRUCTION.match(line)
+        if m and m["op"] not in _FREE_OPS \
+                and _holds_leaf(m["type"], leaf_shape):
+            found.append((m["op"], m["type"].split("{")[0],
+                          _OVER_OPERAND_0 in line))
+    return found
+
+
+def _on(tree, sharding):
+    return jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                       sharding=sharding), tree)
+
+
+def _gpt2_345m():
+    """GPT-2 345M as the serving cells run it, its weights in the
+    compute dtype: float32 masters add a cast of the embedding table
+    (103 MB of temporaries a step) that is not the cache's."""
+    cfg = serving.ServingModelConfig(
+        vocab_size=VOCAB, hidden_size=HID, num_heads=H, num_layers=24,
+        max_seq=S, dtype=BF16)
+
+    def w(*shape):
+        return jax.ShapeDtypeStruct(shape, BF16)
+
+    layer = serving_model.LayerWeights(
+        ln1_w=w(HID), ln1_b=w(HID), qkv_k=w(HID, 3 * HID),
+        qkv_b=w(3 * HID), dense_k=w(HID, HID), dense_b=w(HID),
+        ln2_w=w(HID), ln2_b=w(HID), fc1_k=w(HID, 4 * HID),
+        fc1_b=w(4 * HID), fc2_k=w(4 * HID, HID), fc2_b=w(HID))
+    return cfg, serving.GPTServingWeights(
+        wte=w(VOCAB, HID), wpe=w(S, HID), layers=(layer,) * 24,
+        lnf_w=w(HID), lnf_b=w(HID))
+
+
+def _laguna_two_layers():
+    """The ``rope_moe`` family at Laguna XS.2's widths, a full dense
+    layer and a windowed layer of 256 experts."""
+    layers = (
+        serving.LayerSpec(num_heads=48, window=None, moe=False,
+                          rope=serving.RopeSpec(theta=5e5, rotary_dim=64)),
+        serving.LayerSpec(num_heads=64, window=GQ_WINDOW, moe=True,
+                          rope=serving.RopeSpec(theta=1e4,
+                                                rotary_dim=GQ_D)))
+    cfg = serving.ServingModelConfig(
+        vocab_size=100352, hidden_size=2048, num_heads=48, num_layers=2,
+        max_seq=8704, dtype=BF16, layernorm_eps=1e-6, num_experts=256,
+        head_dim=GQ_D, num_kv_heads=GQ_KV, family="rope_moe",
+        layers=layers, experts_per_token=8, routed_scaling=2.5)
+    weights = jax.eval_shape(lambda: serving.init_rope_moe_weights(
+        jax.random.PRNGKey(0), cfg, dense_ffn=8192, expert_ffn=512,
+        shared_ffn=512))
+    return cfg, weights
+
+
+# name -> (model, pool blocks, step, batch rung, page rung, chunk)
+PROGRAMS = {
+    "gpt2_decode_b16_p64": (_gpt2_345m, CELL_BLOCKS, "decode", 16, 64, 0),
+    "gpt2_decode_b32_p64": (_gpt2_345m, CELL_BLOCKS, "decode", 32, 64, 0),
+    "gpt2_extend_b8_t8_p64": (_gpt2_345m, CELL_BLOCKS, "extend", 8, 64, 8),
+    "gpt2_extend_b1_t256_p64": (_gpt2_345m, CELL_BLOCKS, "extend",
+                                1, 64, 256),
+    "laguna_decode_b32_p544": (_laguna_two_layers, GQ_BLOCKS, "decode",
+                               32, 544, 0),
+    "laguna_extend_b8_t8_p544": (_laguna_two_layers, GQ_BLOCKS, "extend",
+                                 8, 544, 8),
+}
+
+
+def compile_serving_step(name, sharding):
+    """``PROGRAMS[name]`` compiled for the chip ``sharding`` describes,
+    the cache donated: ``(compiled, cache config, number of weight
+    leaves)``; the cache's leaves are the parameters after those."""
+    make, blocks, step, bb, pb, t = PROGRAMS[name]
+    cfg, weights = make()
+    ccfg = serving.default_cache_config(cfg, num_blocks=blocks,
+                                        block_size=KV_BLOCK,
+                                        kv_dtype="bf16")
+    cache = jax.eval_shape(lambda: serving.init_cache(ccfg))
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, I32, sharding=sharding)
+
+    if step == "decode":
+        fn, data = serving_model.gpt_decode_step, (
+            ints(bb), ints(bb), ints(bb, pb), ints(bb), ints(bb), ints(bb))
+    else:
+        fn, data = serving_model.gpt_extend_step, (
+            ints(bb, t), ints(bb, pb), ints(bb), ints(bb, t), ints(bb, t))
+    jitted = jax.jit(lambda w, c, *a: fn(w, cfg, ccfg, c, *a),
+                     donate_argnums=(1,))
+    compiled = jitted.lower(_on(weights, sharding), _on(cache, sharding),
+                            *data).compile()
+    return compiled, ccfg, len(jax.tree.leaves(weights))
+
+
+@pytest.mark.parametrize("name", list(PROGRAMS))
+def test_serving_step_keeps_cache_layout(name, one_chip, mosaic,
+                                         no_persistent_cache):
+    compiled, ccfg, n_weights = compile_serving_step(name, one_chip)
+    text = compiled.as_text()
+    leaves = 2 * ccfg.num_layers
+    ops = cache_sized_ops(text, ccfg.kv_shape)
+    moved = [op for op in ops if not op[2]]
+    assert not moved, f"cache-sized ops that are not in place: {moved}"
+    # a layer's k and v are each written once, where they lie
+    assert len(ops) == leaves, ops
+    header = text[:text.index("\n")]
+    aliased = {int(p) for p in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, may-alias\)", header)}
+    assert aliased == set(range(n_weights, n_weights + leaves)), header
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < TEMP_LIMIT, f"{temp / 2**20:.0f} MiB of temporaries"
