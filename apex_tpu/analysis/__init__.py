@@ -46,7 +46,7 @@ generated from it):
   background-thread crash is a failure, not a vanished thread.
 
 CLI: ``python -m apex_tpu.analysis --check`` / ``--check-hlo`` /
-``--check-sharding`` (self-hosted in tools/ci.sh steps 7, 8, and 12;
+``--check-sharding`` (self-hosted in tools/ci.sh steps 6, 7, and 11;
 see ``--help`` for the rest).
 """
 # flags is the one submodule production code imports at module scope

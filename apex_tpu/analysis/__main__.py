@@ -196,8 +196,7 @@ def main(argv=None) -> int:
 
         if args.entry:
             # a typo'd name must not produce a do-nothing audit that
-            # exits 0 claiming "hlo clean" (same guard bench.py gives
-            # --sections)
+            # exits 0 claiming "hlo clean"
             unknown = sorted(set(args.entry) - set(ENTRY_POINTS))
             if unknown:
                 ap.error(f"unknown entry point(s) {unknown}; "

@@ -156,7 +156,7 @@ def flag_str(name: str) -> Optional[str]:
 def render_flag_table() -> str:
     """Markdown table of the registry, stable ordering — embedded in
     docs/api/ops.md between the flag-table markers and drift-guarded by
-    ci.sh step 7."""
+    ci.sh step 6."""
     lines = ["| Flag | Type | Default | Constraints | Meaning |",
              "|---|---|---|---|---|"]
     for name in sorted(FLAGS):
@@ -301,12 +301,6 @@ register_flag(
     "stop polluting wall rows.  One `python -m "
     "apex_tpu.testing.entry_points --aot` run pre-populates it for "
     "every registered entry point.")
-register_flag(
-    "APEX_TPU_BENCH_GATE_RATIO", "bool", False,
-    "tools/bench_gate.py: escalate the wall_device_ratio check on the "
-    "long_context and optimizer-pipeline rows from WARN to a gating "
-    "regression (--ratio-min, default 0.9 — ROADMAP item 2's exit "
-    "bar).  Off by default so the nightly bench tier arms it first.")
 register_flag(
     "APEX_TPU_SERVE_KV_BLOCK", "int", 16,
     "Tokens per KV-cache block in the serving stack "
@@ -464,7 +458,7 @@ register_flag(
     "key intersection with each replica's shared index), then pool "
     "headroom net of in-flight reservations, then smallest backlog, "
     "avoiding shed-engaged replicas; 'round_robin' ignores all "
-    "signals (the A/B control the bench row compares against).")
+    "signals (the A/B control).")
 register_flag(
     "APEX_TPU_METRICS_PORT", "int", 0,
     "Live metrics plane (monitor/export.py): >0 starts the stdlib "
@@ -557,7 +551,7 @@ register_flag(
 register_flag(
     "APEX_TPU_SCHED_SEEDS", "int", 5,
     "Seed count for the deterministic-schedule fleet stress harness "
-    "(python -m apex_tpu.analysis.schedule, ci.sh step 14): each "
+    "(python -m apex_tpu.analysis.schedule, ci.sh step 13): each "
     "seed serves the same request trace on the threaded fleet under "
     "a different reproducible thread interleaving; the terminal "
     "fleet digest must be identical across all of them, with zero "
@@ -571,8 +565,3 @@ register_flag(
     "APEX_TPU_L1_FULL", "bool", False,
     "Run the full L1 amp x optimizer cross-product grid instead of "
     "the CI slice.")
-register_flag(
-    "APEX_TPU_BENCH_GATE", "bool", False,
-    "tools/ci.sh step 8: also run `bench.py --quick` and gate the "
-    "fresh artifact with tools/bench_gate.py (for bench hosts; the "
-    "gate's self-test runs in CI regardless).")

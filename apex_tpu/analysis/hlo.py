@@ -51,7 +51,7 @@ Suppression uses the PR-5 machinery: the committed findings baseline
 ``tools/hlo_findings.txt`` (same ``path:RULE:symbol  # reason`` format,
 empty — every finding at introduction was fixed), stale entries fail.
 CLI: ``python -m apex_tpu.analysis --check-hlo`` /
-``--update-hlo-baseline`` (tools/ci.sh step 8, on CPU lowerings with
+``--update-hlo-baseline`` (tools/ci.sh step 7, on CPU lowerings with
 an 8-device host-platform mesh for the multichip entries).
 """
 from __future__ import annotations
@@ -519,7 +519,7 @@ def write_hlo_baseline(audits: Dict[str, EntryAudit],
             "Regenerate with: python -m apex_tpu.analysis "
             "--update-hlo-baseline",
             "(CPU lowerings, 8 host-platform devices — the tools/"
-            "ci.sh step 8 configuration).",
+            "ci.sh step 7 configuration).",
             "APX603/APX605 gate every entry against these rows at "
             "+/-10%.",
         ],

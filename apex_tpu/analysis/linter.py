@@ -553,8 +553,7 @@ def _iter_py(root: Path) -> Iterable[Path]:
 # (APX501 only — tests/benches legitimately read env vars and catch
 # broadly): the old-jax tier-1 failures this repo cleared come back
 # the moment a test reintroduces a bare jax.shard_map.
-COMPAT_SCAN_PATHS = ("tests", "examples", "bench.py",
-                     "__graft_entry__.py")
+COMPAT_SCAN_PATHS = ("tests", "examples", "__graft_entry__.py")
 
 
 def lint_paths(package_root: str = "apex_tpu", *,

@@ -61,7 +61,7 @@ KERNEL_TWINS: Dict[Tuple[str, str], TwinSpec] = {
                   "_flash_bwd_e_blocked")},
     # flash decode: the paged single-query serving kernel is specified
     # by the dense gather-and-softmax reference (also the naive decode
-    # baseline the serving bench row measures against)
+    # baseline, ``decode_attention="reference"``)
     ("flash_decode.py", "_decode_paged"): _spec(
         "flash_decode", "paged_attention_reference",
         "apex_tpu/ops/flash_decode.py", "tests/test_serving.py"),

@@ -21,7 +21,7 @@ either:
   ``recompile_budget``; exceeding it raises
   :class:`RecompileBudgetExceeded` naming every offending computation.
 
-:func:`sanitize_smoke` is the CI acceptance path (tools/ci.sh step 7):
+:func:`sanitize_smoke` is the CI acceptance path (tools/ci.sh step 6):
 it drives the standalone-GPT train step under
 ``sanitize(recompile_budget=0, warmup_steps=1)`` and proves the step
 function compiles exactly once after warmup.

@@ -27,7 +27,7 @@ Three pieces:
   serve one fixed request trace per seed under the gate, and report
   per-seed digests plus any :class:`~apex_tpu.monitor.events.
   ThreadExceptionCapture` failures.
-* the CLI — ``python -m apex_tpu.analysis.schedule`` (ci.sh step 14):
+* the CLI — ``python -m apex_tpu.analysis.schedule`` (ci.sh step 13):
   N seeds (``APEX_TPU_SCHED_SEEDS``) x the 2-replica threaded fleet,
   asserting identical digests, zero lost requests, and zero uncaught
   thread exceptions.
@@ -287,7 +287,7 @@ def process_sweep(seeds: Sequence[int], **kw) -> SweepReport:
 
 
 # ---------------------------------------------------------------------------
-# CLI — ci.sh step 14's stress leg
+# CLI — ci.sh step 13's stress leg
 # ---------------------------------------------------------------------------
 
 def main(argv=None) -> int:

@@ -49,7 +49,7 @@ path.  APX701–703 findings suppress through
 committed EMPTY — the real finding at introduction, the ZeRO bench
 driver's replicated state boundary, was FIXED).  CLI:
 ``python -m apex_tpu.analysis --check-sharding`` /
-``--update-sharding-baseline`` (tools/ci.sh step 12, CPU lowerings on
+``--update-sharding-baseline`` (tools/ci.sh step 11, CPU lowerings on
 the 8-device host-platform mesh).
 """
 from __future__ import annotations
@@ -522,7 +522,7 @@ def write_sharding_baseline(audits: Dict[str, ShardingAudit],
             "Regenerate with: python -m apex_tpu.analysis "
             "--update-sharding-baseline",
             "(CPU lowerings, 8 host-platform devices — the tools/"
-            "ci.sh step 12 configuration).",
+            "ci.sh step 11 configuration).",
             "A plan diff here IS the topology review; APX705 gates "
             "per_device_bytes at +/-10%.",
         ],

@@ -3,8 +3,8 @@
 The run-health spine the reference never had: its observability ships as
 three disconnected pieces (pyprof's nvtx->parse->prof device-time
 pipeline, Megatron-style ``Timers``, ad-hoc ``print_rank_last`` loss
-lines).  This package gives drivers, amp, the pipeline schedules, and
-bench.py ONE structured emission path, in three layers:
+lines).  This package gives drivers, amp, the pipeline schedules and
+the serving engine ONE structured emission path, in three layers:
 
 1. **Events + sinks** (:mod:`.events`) — a frozen :class:`Event` record
    (``time``, ``step``, ``kind``, ``name``, ``value``, ``attrs``) with

@@ -4,15 +4,15 @@ One frozen :class:`Event` record and pluggable :class:`Sink` targets.
 The reference ships run observability as disconnected fragments (pyprof's
 nvtx->parse->prof pipeline, Megatron ``Timers``, ad-hoc
 ``print_rank_last`` loss lines); every emitter here — step metrics, amp
-scale transitions, watchdog alarms, pipeline phase timers, bench
-sections — flows through the same record type into the same sink, so a
-killed or stalled run leaves one inspectable log instead of scattered
-prints.
+scale transitions, watchdog alarms, pipeline phase timers, serving
+request lifecycles — flows through the same record type into the same
+sink, so a killed or stalled run leaves one inspectable log instead of
+scattered prints.
 
 :class:`JsonlSink` is crash-safe *by construction*: append-only, one
 event per line, flushed per event — every committed line is valid JSON
 on its own and there is no end-of-run rewrite to lose (the failure mode
-that twice clobbered bench artifacts; see bench.py ``_ArtifactWriter``).
+that twice clobbered a results file rewritten whole at the end of a run).
 """
 from __future__ import annotations
 
@@ -42,8 +42,6 @@ SCHEMA_VERSION = 1
 #:               per-component ms + ``wall_device_ratio``)
 #:   ``trace``   on-demand capture lifecycle (``capture_requested`` /
 #:               ``capture_started`` / ``capture_stopped``)
-#:   ``section`` bench/driver section lifecycle (``section_start`` /
-#:               ``section_done`` / ``section_error``)
 #:   ``resilience`` preemption / restart / checkpoint-integrity
 #:               lifecycle (``termination_requested``, ``clean_exit``,
 #:               ``run_resumed``, ``preempt_exit``, ``attempt_start`` /
@@ -86,7 +84,7 @@ SCHEMA_VERSION = 1
 #:   ``metrics``  exporter lifecycle (``metrics_server_started`` /
 #:               ``metrics_server_stopped`` — trace_check pairs them)
 KINDS = ("run", "metric", "scale", "alarm", "timer", "span", "attr",
-         "trace", "section", "resilience", "telemetry", "serving",
+         "trace", "resilience", "telemetry", "serving",
          "serve_tick", "fleet_tick", "slo", "metrics")
 
 

@@ -11,8 +11,7 @@ checkpoint-integrity skips), phase-timer totals, wall-time attribution
 component + worst-step pointer), the captured-traces index, the
 serving digest (request lifecycle outcomes, queue-wait/TTFT/ITL
 percentiles, rejection reasons, pool high-water, per-bucket tick
-counts, engine snapshots), bench section outcomes — and
-:func:`render` prints it as tables.
+counts, engine snapshots) — and :func:`render` prints it as tables.
 ``tools/monitor_summary.py`` is the CLI wrapper (``--chrome OUT.json``
 additionally rebuilds a Perfetto-loadable Chrome trace from the log's
 span/timer events).
@@ -348,8 +347,7 @@ def summarize(events: List[Event], malformed: int = 0) -> dict:
         # (queue wait / TTFT) and the decode ticks.  ITL is the tick
         # wall weighted by the tick's batch — every active request
         # gains one token per tick, so this is the same population as
-        # the per-request samples ServeSummary.itl_p99_ms (and the
-        # bench_gate serving_itl_p99_ms headline) draw from
+        # the per-request samples ServeSummary.itl_p99_ms draws from
         itl: List[float] = []
         for e in srv:
             if e.name == "decode_step" \
@@ -448,26 +446,6 @@ def summarize(events: List[Event], malformed: int = 0) -> dict:
                                "metrics_server_stopped"),
             }
         out["serving"] = digest
-
-    # bench/driver sections ----------------------------------------------
-    sections: Dict[str, Dict[str, object]] = {}
-    for e in events:
-        if e.kind != "section":
-            continue
-        s = sections.setdefault(e.attrs.get("section", e.name), {})
-        if e.name == "section_start":
-            s.setdefault("status", "started")
-        elif e.name == "section_done":
-            s["status"] = "done"
-            if isinstance(e.value, (int, float)):
-                s["seconds"] = float(e.value)
-        elif e.name == "section_error":
-            s["status"] = "error"
-            s["error"] = e.attrs.get("error", "")
-            if isinstance(e.value, (int, float)):
-                s["seconds"] = float(e.value)
-    if sections:
-        out["sections"] = sections
     return out
 
 
@@ -741,17 +719,6 @@ def render(summary: dict) -> str:
             t = timers[name]
             lines.append(f"{name:<24} {t['count']:>6} "
                          f"{t['total_s']:>10.3f} {t['mean_ms']:>10.2f}")
-
-    sections = summary.get("sections")
-    if sections:
-        lines.append("")
-        lines.append(f"{'section':<24} {'status':<8} {'seconds':>10}")
-        for name, s in sections.items():
-            sec = s.get("seconds")
-            lines.append(
-                f"{name:<24} {s.get('status', '?'):<8} "
-                f"{'' if sec is None else f'{sec:>10.2f}'}"
-                + (f"  {s['error']}" if s.get("error") else ""))
     return "\n".join(lines)
 
 

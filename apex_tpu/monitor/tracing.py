@@ -284,8 +284,8 @@ class SpanTracer:
 
     def write_chrome_trace(self, path: str,
                            spans: Optional[List[Span]] = None) -> str:
-        """Write :meth:`chrome_trace` atomically (scratch + rename —
-        the bench-artifact commit protocol) and return ``path``."""
+        """Write :meth:`chrome_trace` atomically (scratch + rename)
+        and return ``path``."""
         return write_chrome_trace(path, self.chrome_trace(spans))
 
 
@@ -522,7 +522,7 @@ def check_serve_trace(jsonl_path,
                       chrome_path: Optional[str] = None, *,
                       tolerance: float = 0.02) -> List[str]:
     """Validate a serve run's telemetry (``tools/trace_check.py
-    --serve``, ci.sh step 11).  ``jsonl_path`` may be ONE path or a
+    --serve``, ci.sh step 10).  ``jsonl_path`` may be ONE path or a
     sequence of per-replica paths (``trace_check --serve
     serve-r0.jsonl serve-r1.jsonl ...`` — the ISSUE-14 fleet form):
     events merge before checking, so *N submitted ⇒ N terminal* holds
@@ -1349,7 +1349,7 @@ class TraceSession:
 
 
 # ---------------------------------------------------------------------------
-# Trace-smoke checker (tools/ci.sh step 9)
+# Trace-smoke checker (tools/ci.sh step 8)
 # ---------------------------------------------------------------------------
 
 def check_trace(jsonl_path: str, chrome_path: Optional[str] = None, *,

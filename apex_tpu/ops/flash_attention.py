@@ -75,7 +75,7 @@ DEFAULT_BLOCK_K = flag_int("APEX_TPU_FLASH_BLOCK_K")
 #
 # A d=64 head fills only HALF the 128-wide MXU lane tile: q k^T contracts
 # 64 of 128 lanes and p v emits 64 of 128 output lanes, so the unpacked
-# kernels cap near half the d=128 rate (round-5 BENCH_FULL.json:
+# kernels cap near half the d=128 rate (docs/ROUND6_NOTES.md, round 5:
 # 52.6/52.8 TF/s device at s=8192/16384 vs 97.3-98.2 at d=128) — at the
 # reference FMHA's ONLY supported head dim (ref: setup.py:408-424).
 #

@@ -10,8 +10,8 @@ request never moves another request's bytes — and the kernel gathers a
 sequence's pages through its **block table** with a scalar-prefetched
 index map: page ``j`` of batch row ``b`` is fetched from cache block
 ``block_tables[b, j]`` directly by the Pallas pipeline, no materialized
-(b, pages, bs, d) copy anywhere (the naive decode baseline bench.py's
-``serving`` section measures against does exactly that copy).
+(b, pages, bs, d) copy anywhere (the dense gather twin behind
+``decode_attention="reference"`` does exactly that copy).
 
 Layouts (``bs`` = tokens per cache block, the APEX_TPU_SERVE_KV_BLOCK
 grain):
@@ -597,8 +597,8 @@ def paged_attention_reference(q, k_cache, v_cache, block_tables,
     """Dense jnp twin of :func:`flash_decode`: gather every row's pages
     into contiguous (b, h, pages*bs, d) k/v, mask by global position
     (and by the ``window``), fp32 softmax.  The parity oracle and the
-    naive full-gather decode baseline the serving bench row compares
-    the kernel against."""
+    naive full-gather decode baseline the kernel is held against
+    (chip_smoke.py, the parity tests)."""
     b, h, d = q.shape
     if scale is None:
         scale = d ** -0.5

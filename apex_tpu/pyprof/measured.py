@@ -169,9 +169,8 @@ def profile_call(thunk: Callable[[], object], iters: int = 1,
 
     Unlike :func:`collect_device_ops` this wraps nothing in a new
     ``jax.jit`` — use it to profile an existing executable with its
-    live (possibly donated) buffers without paying a retrace/recompile
-    (the bench's optimizer rows re-used their timed executables this
-    way).  The caller is responsible for warmup (typically the timing
+    live (possibly donated) buffers without paying a retrace/recompile.
+    The caller is responsible for warmup (typically the timing
     loop that just ran).
 
     .. note:: With ``iters > 1`` a thunk over a DONATING executable

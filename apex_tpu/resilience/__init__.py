@@ -27,7 +27,7 @@ checkpoint, monitor, and driver layers:
    (``crash@K`` / ``kill@K`` / ``sigterm@K`` / ``nan@K`` / ``stall@K``
    and on-disk checkpoint corruption) proving kill-at-K + resume
    reproduces the uninterrupted run bitwise (tests/test_resilience.py,
-   ``--fault`` on the smoke drivers, tools/ci.sh step 5).
+   ``--fault`` on the smoke drivers, tools/ci.sh step 4).
 
 Full lifecycle walkthrough + escalation table: docs/api/resilience.md.
 """

@@ -43,7 +43,7 @@ Drive modes mirror the in-process fleet: the deterministic **stepped**
 loop (faults, autoscale, QoS, handoffs; one supervisor round ticks
 every replica once over RPC) and **freerun** (submit everything, send
 one ``run`` RPC per replica, children decode concurrently in their own
-processes — the scaling mode the bench row measures).
+processes — the scaling mode).
 
 Supervision tree and the worked kill-9 walkthrough:
 docs/api/resilience.md#distributed-control-plane.

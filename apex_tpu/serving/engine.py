@@ -21,7 +21,7 @@ multiples of the block size — so the set of compiled programs is the
 under :func:`apex_tpu.analysis.sanitize` therefore compiles exactly
 once per bucket and never again — the same recompile budget the
 training smoke enforces, now on the serving path (the tests and
-tools/ci.sh step 11 prove it).
+tools/ci.sh step 10 prove it).
 
 Admission control is **reservation-based**: a request is admitted only
 when the pool can cover its whole worst case (prompt + max new
@@ -33,7 +33,7 @@ pool primitives; this engine ships the safe policy.
 Per-token latency is the engine tick wall (each active request gains
 one token per tick); the run summary reports p50/p99 over every
 generated token plus decode tokens/s — the rows ``standalone_gpt
---serve`` prints and bench.py's ``serving`` section commits.
+--serve`` prints.
 """
 from __future__ import annotations
 
@@ -194,7 +194,7 @@ class Request:
 
 @dataclasses.dataclass
 class ServeSummary:
-    """What a serve run measured (the --serve / bench row source)."""
+    """What a serve run measured (the --serve row source)."""
 
     requests_done: int
     requests_preempted: int

@@ -549,7 +549,7 @@ class FleetRouter:
         (free + idle blocks net of in-flight reservations), then the
         smallest backlog; shed-engaged replicas are avoided while any
         alternative exists.  ``round_robin`` ignores all signals (the
-        A/B control the bench row compares against)."""
+        A/B control)."""
         candidates = [r for r in self.serve_replicas if r.routable]
         if not candidates:
             raise RuntimeError(

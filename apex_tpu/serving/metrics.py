@@ -562,7 +562,7 @@ class ServeMetrics:
     hook is host-only bookkeeping (clock reads + dict/deque updates)
     and emission goes through the engine's monitor (anything with the
     ``StepMonitor.event`` signature; None records distributions but
-    emits nothing — the bench path).  Timestamps use the engine's
+    emits nothing).  Timestamps use the engine's
     injectable monotonic clock, wall-anchored once at construction
     (the :class:`~apex_tpu.monitor.tracing.SpanTracer` trick) so
     exported Chrome lanes line up with device traces captured in the
@@ -813,8 +813,8 @@ class ServeMetrics:
         return self._pct_cache
 
     def distributions(self) -> Dict[str, Dict[str, float]]:
-        """Full p50/p90/p99 digest for every series (the bench row /
-        docs surface; richer than the summary fields)."""
+        """Full p50/p90/p99 digest for every series (richer than the
+        summary fields)."""
         out: Dict[str, Dict[str, float]] = {}
         for name, xs in (("queue_wait_ms", self._queue_wait_ms),
                          ("ttft_ms", self._ttft_ms),

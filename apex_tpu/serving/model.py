@@ -122,7 +122,7 @@ class ServingModelConfig:
     prefill_flash: bool = True
     # decode attention: 'kernel' = the Pallas flash-decode kernel;
     # 'reference' = the dense gather twin — the naive full-attention
-    # baseline bench.py's serving section measures the kernel against
+    # baseline the kernel is held against (chip_smoke.py, the tests)
     decode_attention: str = "kernel"
     # tensor-parallel axis name (serving/tp.py): when set, the step
     # functions run PER-SHARD math — heads/ffn columns local, hidden

@@ -1,4 +1,1 @@
 """apex_tpu.testing — test/bench harness (ref: apex/transformer/testing)."""
-from .timing import bench_chained
-
-__all__ = ["bench_chained"]

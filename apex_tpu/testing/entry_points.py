@@ -6,7 +6,7 @@ programs this framework actually compiles":
 * the compiled-graph auditor (:mod:`apex_tpu.analysis.hlo`) lowers each
   entry and checks donation, dtype promotion, the collective census,
   host transfers, and peak live memory against the committed baseline
-  (``python -m apex_tpu.analysis --check-hlo``, tools/ci.sh step 8);
+  (``python -m apex_tpu.analysis --check-hlo``, tools/ci.sh step 7);
 * the sanitizer smoke drives the GPT entry's exact step function;
 * the train-smoke drivers build their steps through the same
   ``make_smoke_setup``/``build_train_step`` pair the entries here use.
@@ -600,8 +600,8 @@ def _build_zero_dp8_adam_step():
     that IS ZeRO), enter and leave the step as ``P('zero')`` globals,
     and the in/out specs derive from :func:`zero_adam_plan` — the same
     object the SPMD auditor checks.  A builder change that stops
-    consulting the plan (the bench-driver bug this PR fixed carried
-    the state as ``P()``) makes the state replicated and fires
+    consulting the plan (the driver bug the auditor first caught
+    carried the state as ``P()``) makes the state replicated and fires
     APX701 here instead of surfacing as a TPU bill."""
     import functools
 
@@ -861,7 +861,7 @@ def aot_warmup(names=None, *, configure_cache: bool = True):
 def _main(argv=None):
     """CLI: ``python -m apex_tpu.testing.entry_points --aot`` —
     pre-compile the registry into the persistent cache (tools/ci.sh
-    step 10 proves the second process warm-starts from it)."""
+    step 9 proves the second process warm-starts from it)."""
     import argparse
     import sys
 

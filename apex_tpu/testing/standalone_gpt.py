@@ -1184,7 +1184,7 @@ def serve_smoke(num_requests: int = 6, *, jsonl: Optional[str] = None,
     evictions interleaving with jitted decode steps — and reports
     decode tokens/s plus p50/p99 per-token latency through the
     monitor stack (the ``--serve`` acceptance path, tools/ci.sh step
-    11).
+    10).
 
     ``sanitize=True`` proves the bucket-ladder compile discipline:
     every (batch, pages) bucket is AOT-compiled by ``engine.warmup()``
@@ -1196,8 +1196,8 @@ def serve_smoke(num_requests: int = 6, *, jsonl: Optional[str] = None,
     admissions, frees every block, marks in-flight requests
     preempted, and still returns a full summary — the clean-drain
     contract.  ``decode_attention="reference"`` swaps the kernel for
-    the dense gather twin (the naive decode baseline bench.py's
-    serving section measures against).
+    the dense gather twin (the naive decode baseline the kernel is
+    held against).
 
     The ISSUE-12 decode fast path rides the same smoke:
     ``speculate_k=K`` builds a draft GPT (``draft="self"`` reuses the
@@ -1264,7 +1264,7 @@ def serve_smoke(num_requests: int = 6, *, jsonl: Optional[str] = None,
     SLO objectives come from the ``APEX_TPU_SLO_*`` flags
     (``ServingEngine(slo="auto")``).  ``metrics_linger`` keeps the
     server up that many seconds after the drain so an external probe
-    (tools/metrics_probe.py, ci.sh step 16) can observe the
+    (tools/metrics_probe.py, ci.sh step 15) can observe the
     ``/healthz`` flip before teardown.
 
     Returns the :class:`~apex_tpu.serving.ServeSummary` (with
@@ -1556,7 +1556,7 @@ def fleet_smoke(num_requests: int = 8, *, replicas: Optional[int] = None,
     """Multi-replica serving smoke: N :class:`~apex_tpu.serving.
     ServingEngine` replicas behind the gauge-fed
     :class:`~apex_tpu.serving.FleetRouter` (the ``--serve-fleet``
-    acceptance path, tools/ci.sh step 13).
+    acceptance path, tools/ci.sh step 12).
 
     ``replicas``/``tp``/``disaggregate``/``policy`` default to the
     ``APEX_TPU_SERVE_REPLICAS``/``_TP``/``_DISAGGREGATE``/``_ROUTER``
@@ -1914,7 +1914,7 @@ def fleet_procs_smoke(num_requests: int = 8, *, replicas: int = 2,
                       heartbeat_misses: Optional[int] = None,
                       return_fleet: bool = False):
     """Process-isolated fleet smoke (``--serve-fleet --procs``,
-    tools/ci.sh step 17): ``replicas`` supervised subprocesses, each
+    tools/ci.sh step 16): ``replicas`` supervised subprocesses, each
     a full :func:`build_fleet_engine` replica on its own device,
     driven over local sockets by :class:`~apex_tpu.serving.
     ProcessFleet` — heartbeat liveness, ``fault="kill9@K"`` SIGKILL

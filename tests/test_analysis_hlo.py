@@ -6,7 +6,7 @@ callback — each asserting the exact rule and provenance, plus the
 repo self-check: the committed tools/hlo_baseline.json must be
 current against fresh lowerings of every registered entry point
 (the conftest provides the 8-device host-platform mesh the multichip
-entries need, same as tools/ci.sh step 8).
+entries need, same as tools/ci.sh step 7).
 """
 import dataclasses
 import functools

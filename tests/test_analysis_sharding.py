@@ -5,7 +5,7 @@ rule FIRES with exact rule id + provenance — plus the acceptance bar:
 ``run_sharding_check`` green on every planned multichip entry against
 the committed ``tools/sharding_baseline.json``, and the
 deliberately-reintroduced ZeRO replicated-state bug (the real finding
-this PR fixed in bench.py) caught as APX701.
+the auditor turned up at introduction) caught as APX701.
 """
 import dataclasses
 import json
@@ -340,7 +340,7 @@ class TestRuleFixtures:
 class TestZeroRegressionCaught:
     def test_replicated_state_boundary_fires_apx701(self):
         """Rebuild the zero_dp8_adam_step with the exact bug the SPMD
-        auditor shipped against (bench.py carried the ZeRO state
+        auditor shipped against (a driver carried the ZeRO state
         through its shard_map boundary as P()): the m/v buffers come
         out shard-sized-but-replicated and APX701 names them."""
         from apex_tpu.contrib.optimizers import (
@@ -537,16 +537,6 @@ class TestPathsFilter:
 
 
 class TestTopologyColumn:
-    def _readme_numbers(self):
-        import importlib.util
-
-        spec = importlib.util.spec_from_file_location(
-            "readme_numbers",
-            os.path.join(REPO, "tools", "readme_numbers.py"))
-        mod = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(mod)
-        return mod
-
     def test_plans_match_committed_topology_file(self):
         import importlib.util
 
@@ -563,82 +553,8 @@ class TestTopologyColumn:
         assert committed["legs"]["zero_adam"]["describe"] == \
             "data=8(zero)"
 
-    def test_topology_rows_prefer_multichip_tail(self, tmp_path):
-        rn = self._readme_numbers()
-        (tmp_path / "MULTICHIP_r07.json").write_text(json.dumps({
-            "n_devices": 8, "tail":
-                "[dryrun] GPT 3D train step OK: loss=4.2\n"
-                "[dryrun] plan gpt_3d: pipe=2(pipeline) x "
-                "data=2(data) x tensor=2(tensor)\n"
-                "[dryrun] plan zero_adam: data=8(zero)\n"}))
-        rows = rn.topology_rows(str(tmp_path))
-        assert rows == [
-            ("gpt_3d",
-             "pipe=2(pipeline) x data=2(data) x tensor=2(tensor)"),
-            ("zero_adam", "data=8(zero)")]
-
-    def test_topology_rows_fall_back_to_topology_file(self, tmp_path):
-        rn = self._readme_numbers()
-        # a pre-plan-line artifact (old tail) + the committed topology
-        (tmp_path / "MULTICHIP_r05.json").write_text(json.dumps({
-            "n_devices": 8, "tail": "[dryrun] OK on 8 devices\n"}))
-        (tmp_path / "MULTICHIP_TOPOLOGY.json").write_text(json.dumps({
-            "legs": {"gpt_3d": {"describe": "pipe=2(pipeline)"},
-                     "ulysses": {"describe": "sequence=4(sequence)"}}}))
-        assert rn.topology_rows(str(tmp_path)) == [
-            ("gpt_3d", "pipe=2(pipeline)"),
-            ("ulysses", "sequence=4(sequence)")]
-        # neither source: no rows, no crash
-        empty = tmp_path / "empty"
-        empty.mkdir()
-        assert rn.topology_rows(str(empty)) == []
-
-    def test_render_includes_topology_rows(self):
-        rn = self._readme_numbers()
-        block = rn.render({}, "X.json",
-                          topo=[("gpt_3d", "pipe=2(pipeline)")])
-        assert "| multichip topology — gpt_3d | `pipe=2(pipeline)` |" \
-            in block
-
-    def test_moe_perf_rows_from_multichip_tail(self, tmp_path):
-        """ISSUE-19: the '[dryrun] perf moe_ep <topology>: ...' lines
-        parse into (topology, step_ms, tokens_s) triples and render as
-        README rows; artifacts predating the perf lines yield none."""
-        rn = self._readme_numbers()
-        (tmp_path / "MULTICHIP_r07.json").write_text(json.dumps({
-            "n_devices": 8, "tail":
-                "[dryrun] expert-parallel MoE OK over expert=4\n"
-                "[dryrun] perf moe_ep expert=2: step_ms=3.821 "
-                "tokens_s=268015 (fused dispatch, a2a_chunks=2)\n"
-                "[dryrun] perf moe_ep expert=4: step_ms=4.787 "
-                "tokens_s=213927 (fused dispatch, a2a_chunks=2)\n"}))
-        rows = rn.moe_perf_rows(str(tmp_path))
-        assert rows == [("expert=2", "3.821", "268015"),
-                        ("expert=4", "4.787", "213927")]
-        block = rn.render({}, "X.json", moe_perf=rows)
-        assert ("| multichip MoE layer — expert=2 (host substrate) | "
-                "3.821 ms/step, 268015 tok/s |") in block
-        # pre-perf-line artifact: no rows, no crash
-        (tmp_path / "MULTICHIP_r07.json").write_text(json.dumps({
-            "n_devices": 8, "tail": "[dryrun] OK on 8 devices\n"}))
-        assert rn.moe_perf_rows(str(tmp_path)) == []
-
-    def test_render_includes_moe_ep_bench_rows(self):
-        """The bench moe_ep section's headline rows render from the
-        artifact: fused-vs-onehot speedup and EP decode tokens/s."""
-        rn = self._readme_numbers()
-        block = rn.render({"extras": {"moe_ep": {
-            "shape": {"capacity_factor": 1.25},
-            "moe_layer": {"fused_vs_onehot": 4.487,
-                          "fused_vs_dense": 1.332},
-            "ep_decode": {"tokens_per_sec": 600.67}}}}, "X.json")
-        assert "4.487x faster" in block
-        assert "600.67 tok/s" in block
-        assert "cf 1.25 padding" in block
-
     def test_dryrun_prints_one_plan_line_per_leg(self):
-        """The stdout contract the MULTICHIP_rNN.json tail records:
-        sorted '[dryrun] plan <leg>: <axes>' lines derived from the
+        """The dryrun's stdout contract: sorted '[dryrun] plan <leg>: <axes>' lines derived from the
         canonical constructors (no subprocess — the print loop's
         source of truth is multichip_plans, asserted directly)."""
         import importlib.util

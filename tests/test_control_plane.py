@@ -8,7 +8,7 @@ checks, and the monitor-summary control-plane digest.
 The heavy end-to-end drills — kill-9 + journal replay across a real
 process boundary, rpc_timeout no-stall, the tick-seed process sweep —
 spawn real subprocesses (each ~15 s of jax import + warmup on CPU)
-and are marked ``slow``; ci.sh step 17 runs the kill-9 drill on every
+and are marked ``slow``; ci.sh step 16 runs the kill-9 drill on every
 push regardless.
 """
 import json

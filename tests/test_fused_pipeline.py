@@ -29,12 +29,27 @@ from apex_tpu.optimizers import fused_adam, fused_lamb, fused_sgd
 from apex_tpu.optimizers.fused_adam import _grad_clip_factor
 
 
-def tree_bitwise(a, b, msg=""):
+def tree_bitwise(a, b, msg="", max_ulp=0):
     for x, y in zip(jax.tree_util.tree_leaves(a),
                     jax.tree_util.tree_leaves(b)):
-        np.testing.assert_array_equal(
-            np.asarray(x, np.float32), np.asarray(y, np.float32),
-            err_msg=msg)
+        x, y = np.asarray(x, np.float32), np.asarray(y, np.float32)
+        if max_ulp:
+            np.testing.assert_array_max_ulp(x, y, maxulp=max_ulp)
+        else:
+            np.testing.assert_array_equal(x, y, err_msg=msg)
+
+
+# L2-mode weight decay over a low-precision model, ``g + wd * p`` ahead
+# of the moments: the packed pipeline and the staged path write the same
+# arithmetic, and jax 0.9.0's CPU compiler contracts that multiply-add
+# into one rounding in the staged graph and not in the packed one.  An
+# emulation in numpy with the one fused multiply-add reproduces the
+# staged masters bit for bit and the packed ones without it (SGD, all
+# 145 elements, three steps; Adam's L2 mode writes the same expression
+# and shows the same gap), so the fp32 masters of those configurations
+# are held to one ulp; the model copy and every other configuration
+# stay bitwise.
+L2_CONTRACTION_ULP = 1
 
 
 def make_params(dtype=jnp.float32, seed=0):
@@ -318,7 +333,8 @@ class TestAdamPipelineParity:
                                 adam_w_mode=False)
         m1, s1, _ = run_amp(mk, policy, params, pipeline=True)
         m0, s0, _ = run_amp(mk, policy, params, pipeline=False)
-        tree_bitwise(unpacked_masters(s1, params), s0.master_params)
+        tree_bitwise(unpacked_masters(s1, params), s0.master_params,
+                     max_ulp=L2_CONTRACTION_ULP)
         tree_bitwise(m1, m0)
 
     def test_optax_chain_cross_check(self):
@@ -372,8 +388,11 @@ class TestSgdPipelineParity:
             mk = lambda: fused_sgd(0.05, **kw)
             m1, s1, _ = run_amp(mk, policy, params, pipeline=True)
             m0, s0, _ = run_amp(mk, policy, params, pipeline=False)
+            l2_lowp = (dtype != jnp.float32 and "weight_decay" in kw
+                       and not kw.get("wd_after_momentum"))
             tree_bitwise(unpacked_masters(s1, params),
-                         s0.master_params, msg=f"{dtype} {kw}")
+                         s0.master_params, msg=f"{dtype} {kw}",
+                         max_ulp=L2_CONTRACTION_ULP if l2_lowp else 0)
             tree_bitwise(m1, m0, msg=f"model {kw}")
 
 
@@ -586,15 +605,6 @@ class TestWiring:
         with pytest.raises(ValueError, match="pipeline=True"):
             amp.AmpOptimizer(fused_adam(1e-3), amp.get_policy("O3"),
                              pipeline=True)
-
-    def test_bench_sections_rejects_unknown_names(self):
-        import bench
-
-        with pytest.raises(SystemExit):
-            bench._parse_args(["--sections", "optimiser_step"])
-        args = bench._parse_args(["--sections",
-                                  "optimizer_step,resnet50"])
-        assert args.sections == "optimizer_step,resnet50"
 
     def test_step_info_grad_norm_reused_by_monitor(self):
         from apex_tpu.amp.mixed_precision import StepInfo

@@ -11,7 +11,6 @@ paths) proving:
 - amp scale telemetry from both StepInfo and bare ScalerState;
 - Timers: the never-started-name KeyError fix, the add_scalar adapter,
   and the events() export;
-- bench section events flow through the same sink (_run_section);
 - logging consolidation: exactly one handler on the apex_tpu root.
 """
 import json
@@ -343,59 +342,6 @@ class TestTimers:
         # missing names skipped here too
         t.events(mem, iteration=3, names=["nope"])
         assert len(mem.events) == 2
-
-
-# ---------------------------------------------------------------------------
-# bench section events through the same sink
-# ---------------------------------------------------------------------------
-
-class TestBenchSectionEvents:
-    def test_done_and_error_sections(self, tmp_path, capsys):
-        import bench
-
-        full = {"metric": "m", "value": 1.0, "unit": "u",
-                "vs_baseline": 1.0, "extras": {}}
-        w = bench._ArtifactWriter(full, str(tmp_path / "B.json"))
-        mem = MemorySink()
-        bench._run_section(full["extras"], "ok", lambda: {"x": 1}, w,
-                           mem)
-        bench._run_section(full["extras"], "boom", lambda: 1 / 0, w,
-                           mem)
-        names = [(e.name, e.attrs.get("section"))
-                 for e in mem.by_kind("section")]
-        assert names == [("section_start", "ok"), ("section_done", "ok"),
-                         ("section_start", "boom"),
-                         ("section_error", "boom")]
-        err = mem.by_name("section_error")[0]
-        assert "division" in err.attrs["error"]
-
-    def test_driver_kill_is_recorded_and_propagates(self, tmp_path,
-                                                    capsys):
-        import bench
-
-        full = {"metric": "m", "value": 1.0, "unit": "u",
-                "vs_baseline": 1.0, "extras": {}}
-        w = bench._ArtifactWriter(full, str(tmp_path / "B.json"))
-        mem = MemorySink()
-
-        def killed():
-            raise KeyboardInterrupt
-
-        with pytest.raises(KeyboardInterrupt):
-            bench._run_section(full["extras"], "gpt", killed, w, mem)
-        err = mem.by_name("section_error")[0]
-        assert err.attrs["error"] == "KeyboardInterrupt"
-        assert "gpt" not in full["extras"]   # no fake {"error"} row
-
-    def test_sinkless_call_still_works(self, tmp_path, capsys):
-        """The pre-telemetry signature (no sink) must keep working."""
-        import bench
-
-        full = {"metric": "m", "value": 1.0, "unit": "u",
-                "vs_baseline": 1.0, "extras": {}}
-        w = bench._ArtifactWriter(full, str(tmp_path / "B.json"))
-        bench._run_section(full["extras"], "ok", lambda: {"x": 1}, w)
-        assert full["extras"]["ok"] == {"x": 1}
 
 
 # ---------------------------------------------------------------------------

@@ -162,7 +162,8 @@ class TestTensorParallel:
         cfg, weights, _ = smoke_weights
         cc = default_cache_config(cfg, num_blocks=16, block_size=4)
         tp = TPContext(cfg, cc, 2)
-        with pytest.raises(ValueError, match="not both"):
+        with pytest.raises(ValueError,
+                           match="at most one of tp, ep, device"):
             ServingEngine(weights, cfg, cc, tp=tp,
                           device=jax.devices()[0],
                           ladder=BucketLadder(batch=(2,), pages=(2,)))

@@ -9,29 +9,26 @@
 #   1. default test tier   — CPU backend, 8 virtual devices, slow tier
 #                            skipped (APEX_TPU_FULL=1 upgrades to the
 #                            full tier, the builder's verify flow)
-#   2. README drift guard  — the closing-numbers block must byte-match
-#                            what tools/readme_numbers.py renders from
-#                            the committed BENCH_FULL.json
-#   3. 8-device dryrun     — the multichip legs (GPT 3D DP x TP x PP,
+#   2. 8-device dryrun     — the multichip legs (GPT 3D DP x TP x PP,
 #                            ResNet DP, SP/MoE/ZeRO) on a virtual mesh
-#   4. monitor smoke       — a tiny standalone_gpt train run writes a
+#   3. monitor smoke       — a tiny standalone_gpt train run writes a
 #                            JSONL event log through apex_tpu.monitor
 #                            and tools/monitor_summary.py renders it,
 #                            so the telemetry path is exercised on
 #                            every CI run, not only under a TPU bench
-#   5. kill->resume smoke  — the resilience acceptance path end to end:
+#   4. kill->resume smoke  — the resilience acceptance path end to end:
 #                            a checkpointed standalone_gpt run is
 #                            SIGTERM'd at step 4 (--fault sigterm@4),
 #                            must exit 0 with a CLEAN_EXIT.json marker,
 #                            then the same command resumes to step 8;
 #                            the shared JSONL must carry the
 #                            preempt_exit and run_resumed events
-#   6. pipeline kernels    — the fused-pipeline Pallas sweeps run in
+#   5. pipeline kernels    — the fused-pipeline Pallas sweeps run in
 #                            interpret mode on CPU (tiny tree, 3
 #                            steps) and must match the per-stage path,
 #                            so kernel regressions are caught without
 #                            a TPU (ops/fused_pipeline.self_check)
-#   7. static analysis     — the self-hosted trace-safety lint +
+#   6. static analysis     — the self-hosted trace-safety lint +
 #                            kernel-parity audit must report zero
 #                            unsuppressed findings, the generated
 #                            doc tables (env flags, APX rules) must
@@ -39,19 +36,15 @@
 #                            smoke must prove the GPT step compiles
 #                            exactly once after warmup
 #                            (docs/api/analysis.md)
-#   8. compiled-graph audit — python -m apex_tpu.analysis --check-hlo
+#   7. compiled-graph audit — python -m apex_tpu.analysis --check-hlo
 #                            lowers every registered entry point on
 #                            CPU (8 host-platform devices, so the
 #                            multichip entries' collective census is
 #                            covered) and checks donation, dtype
 #                            promotion, the collective census, host
 #                            transfers, and peak live memory against
-#                            tools/hlo_baseline.json; plus the bench
-#                            regression gate's self-test (and, with
-#                            APEX_TPU_BENCH_GATE=1 on a bench host,
-#                            a quick-tier bench run through
-#                            tools/bench_gate.py)
-#   9. trace smoke          — a 3-step standalone_gpt run with
+#                            tools/hlo_baseline.json
+#   8. trace smoke          — a 3-step standalone_gpt run with
 #                            --trace must emit the canonical wall-time
 #                            waterfall (data_load/dispatch/
 #                            device_compute/telemetry_drain/ckpt_io +
@@ -64,7 +57,7 @@
 #                            host transfers, metrics drained through
 #                            the device ring (docs/api/
 #                            observability.md)
-#  10. scan-driver smoke     — the ISSUE-8 batched-step driver: a
+#   9. scan-driver smoke     — the ISSUE-8 batched-step driver: a
 #                            2-window x K=3 standalone_gpt run under
 #                            --sanitize must prove ONE compile for
 #                            all 6 steps (AOT window + recompile
@@ -78,7 +71,7 @@
 #                            APEX_TPU_COMPILE_CACHE_DIR and the second
 #                            process must warm-start from the cache
 #                            (--expect-cache-hits)
-#  11. serving smoke         — the ISSUE-9 continuous-batching stack:
+#  10. serving smoke         — the ISSUE-9 continuous-batching stack:
 #                            a sanitized `--serve` run (mixed-length
 #                            requests, prefill via the flash fwd
 #                            kernel, decode via the paged flash-decode
@@ -121,7 +114,7 @@
 #                            snapshot-then-drain escalation exactly
 #                            once (one engine_snapshot, clean drain,
 #                            chains complete)
-#  12. SPMD sharding audit   — python -m apex_tpu.analysis
+#  11. SPMD sharding audit   — python -m apex_tpu.analysis
 #                            --check-sharding compiles every
 #                            plan-carrying multichip entry point under
 #                            its MeshPlan's mesh (8 host-platform
@@ -135,7 +128,7 @@
 #                            MULTICHIP_TOPOLOGY.json must match the
 #                            canonical MeshPlan constructors
 #                            (docs/api/analysis.md)
-#  13. fleet serving smoke   — the ISSUE-14 multi-replica stack: a
+#  12. fleet serving smoke   — the ISSUE-14 multi-replica stack: a
 #                            sanitized 2-replica `--serve-fleet` run
 #                            with one mid-serve rolling weight swap
 #                            must lose ZERO requests (every submitted
@@ -151,7 +144,7 @@
 #                            replay (restarts>=1, replayed>0) while
 #                            the fleet still completes every request
 #                            (docs/api/serving.md#fleet-serving)
-#  14. host-concurrency audit — the ISSUE-15 APX8xx family:
+#  13. host-concurrency audit — the ISSUE-15 APX8xx family:
 #                            python -m apex_tpu.analysis
 #                            --check-concurrency audits lock
 #                            discipline (guard inference over
@@ -170,7 +163,7 @@
 #                            requests and zero uncaught background-
 #                            thread exceptions
 #                            (docs/api/analysis.md)
-#  15. Q8 quantized serving  — the ISSUE-16 int8 weight-only tier:
+#  14. Q8 quantized serving  — the ISSUE-16 int8 weight-only tier:
 #                            ops/quant_matmul.self_check() runs the
 #                            interpret-mode parity sweep (GEMV +
 #                            tiled paths vs the jnp twin, the
@@ -181,7 +174,7 @@
 #                            per bucket, zero post-warmup recompiles,
 #                            tokens/s > 0 (docs/api/serving.md
 #                            #weight-quantization)
-#  16. live metrics plane   — the ISSUE-17 exporter end to end: a
+#  15. live metrics plane   — the ISSUE-17 exporter end to end: a
 #                            live probe scrapes /metrics off a
 #                            serving fleet (per-replica labeled
 #                            counters + fleet gauges), a SIGTERM
@@ -189,7 +182,7 @@
 #                            teardown, and a forced TTFT breach emits
 #                            exactly one slo_burn episode traced back
 #                            to its objective definition
-#  17. process-isolated fleet — the ISSUE-18 control plane: a
+#  16. process-isolated fleet — the ISSUE-18 control plane: a
 #                            2-process supervised fleet run twice,
 #                            uninterrupted and with replica r0
 #                            SIGKILL'd mid-serve (kill9@2); the
@@ -207,7 +200,7 @@
 #                            by monitor_summary
 #                            (docs/api/resilience.md
 #                            #distributed-control-plane)
-#  18. expert-parallel serving — the ISSUE-19 MoE decode fast path:
+#  17. expert-parallel serving — the ISSUE-19 MoE decode fast path:
 #                            ops/moe_routing.self_check() runs the
 #                            fused routing kernel's interpret-mode
 #                            parity sweep, then a sanitized
@@ -221,7 +214,7 @@
 #                            bucket, zero post-warmup recompiles)
 #                            and tokens/s > 0 (docs/api/serving.md
 #                            #expert-parallel-decode)
-#  19. wire-protocol audit  — `--check-protocol` (APX901-905):
+#  18. wire-protocol audit  — `--check-protocol` (APX901-905):
 #                            serving/ + resilience/ audited against
 #                            the ProtocolSpec registry in
 #                            serving/control_plane.py — deadline
@@ -240,23 +233,20 @@ cd "$(dirname "${BASH_SOURCE[0]}")/.."
 
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 
-echo "[ci] 1/19 default test tier"
+echo "[ci] 1/18 default test tier"
 python -m pytest tests/ -q -m 'not slow' -p no:cacheprovider
 
-echo "[ci] 2/19 README drift guard"
-python tools/readme_numbers.py --check
-
-echo "[ci] 3/19 8-device multichip dryrun"
+echo "[ci] 2/18 8-device multichip dryrun"
 python -c "import __graft_entry__; __graft_entry__.dryrun_multichip(8)"
 
-echo "[ci] 4/19 monitor smoke"
+echo "[ci] 3/18 monitor smoke"
 MONITOR_SMOKE_JSONL="$(mktemp -t apex_tpu_monitor_smoke.XXXXXX.jsonl)"
 python -m apex_tpu.testing.standalone_gpt --steps 3 \
     --jsonl "$MONITOR_SMOKE_JSONL"
 python tools/monitor_summary.py "$MONITOR_SMOKE_JSONL"
 rm -f "$MONITOR_SMOKE_JSONL"
 
-echo "[ci] 5/19 kill->resume smoke"
+echo "[ci] 4/18 kill->resume smoke"
 RESIL_DIR="$(mktemp -d -t apex_tpu_resilience.XXXXXX)"
 RESIL_JSONL="$RESIL_DIR/events.jsonl"
 # leg 1: preempted at step 4 — must exit 0 via the graceful path
@@ -276,25 +266,20 @@ grep -q '"name":"preempt_exit"' "$RESIL_JSONL" \
 python tools/monitor_summary.py "$RESIL_JSONL"
 rm -rf "$RESIL_DIR"
 
-echo "[ci] 6/19 fused-pipeline kernel parity (Pallas interpret mode)"
+echo "[ci] 5/18 fused-pipeline kernel parity (Pallas interpret mode)"
 python -c "from apex_tpu.ops import fused_pipeline; \
 fused_pipeline.self_check()"
 
-echo "[ci] 7/19 static analysis (self-hosted lint + docs drift + sanitizer)"
+echo "[ci] 6/18 static analysis (self-hosted lint + docs drift + sanitizer)"
 python -m apex_tpu.analysis --check
 python -m apex_tpu.analysis --check-docs
 python -m apex_tpu.analysis --smoke
 
-echo "[ci] 8/19 compiled-graph audit (--check-hlo) + bench gate"
+echo "[ci] 7/18 compiled-graph audit (--check-hlo)"
 XLA_FLAGS="${XLA_FLAGS:-} --xla_force_host_platform_device_count=8" \
     python -m apex_tpu.analysis --check-hlo
-python tools/bench_gate.py --self-test
-if [ "${APEX_TPU_BENCH_GATE:-0}" = "1" ]; then
-    python bench.py --quick
-    python tools/bench_gate.py
-fi
 
-echo "[ci] 9/19 trace smoke (waterfall + chrome + deferred telemetry)"
+echo "[ci] 8/18 trace smoke (waterfall + chrome + deferred telemetry)"
 TRACE_DIR="$(mktemp -d -t apex_tpu_trace.XXXXXX)"
 # leg 1: traced run — canonical spans, waterfall rows summing to
 # wall_ms, and a parseable Chrome artifact
@@ -315,7 +300,7 @@ grep -q '"name":"loss"' "$TRACE_DIR/deferred.jsonl" \
          exit 1; }
 rm -rf "$TRACE_DIR"
 
-echo "[ci] 10/19 scan-driver smoke (K-batched steps + AOT compile cache)"
+echo "[ci] 9/18 scan-driver smoke (K-batched steps + AOT compile cache)"
 SCAN_DIR="$(mktemp -d -t apex_tpu_scan.XXXXXX)"
 # leg 1: 6 steps as 2 windows of K=3 under the sanitizer — one compile
 # after warmup, d->h transfer guard armed (scan mode is deferred-
@@ -339,7 +324,7 @@ APEX_TPU_COMPILE_CACHE_DIR="$SCAN_DIR/cc" \
     --expect-cache-hits
 rm -rf "$SCAN_DIR"
 
-echo "[ci] 11/19 serving smoke (continuous batching + clean drain)"
+echo "[ci] 10/18 serving smoke (continuous batching + clean drain)"
 SERVE_DIR="$(mktemp -d -t apex_tpu_serve.XXXXXX)"
 # leg 1: sanitized serve — a pinned 2x1 ladder AOT-compiles in warmup
 # (2 decode buckets + 1 prefill = 3 programs) and the whole run holds
@@ -463,7 +448,7 @@ grep -q '"name":"escalation_drain"' "$SERVE_DIR/stall.jsonl" \
 python tools/trace_check.py "$SERVE_DIR/stall.jsonl" --serve
 rm -rf "$SERVE_DIR"
 
-echo "[ci] 12/19 SPMD sharding audit (--check-sharding) + topology drift"
+echo "[ci] 11/18 SPMD sharding audit (--check-sharding) + topology drift"
 # Compile every plan-carrying multichip entry under its mesh on the
 # same 8-device host-platform trick the multichip tests use; fails on
 # APX701-703 findings, per-device-memory drift vs the committed
@@ -475,7 +460,7 @@ XLA_FLAGS="${XLA_FLAGS:-} --xla_force_host_platform_device_count=8" \
     python -m apex_tpu.analysis --check-sharding
 python __graft_entry__.py --plans 8
 
-echo "[ci] 13/19 fleet serving smoke (multi-replica + swap + disagg + crash replay)"
+echo "[ci] 12/18 fleet serving smoke (multi-replica + swap + disagg + crash replay)"
 FLEET_DIR="$(mktemp -d -t apex_tpu_fleet.XXXXXX)"
 # leg 1: sanitized 2-replica fleet with ONE rolling weight swap
 # mid-serve — zero lost requests fleet-wide, zero compiles after
@@ -531,7 +516,7 @@ echo "$FLEET_OUT" | grep -q "done=8" \
 python tools/trace_check.py "$FLEET_DIR"/crash/serve-*.jsonl --serve
 rm -rf "$FLEET_DIR"
 
-echo "[ci] 14/19 host-concurrency audit (--check-concurrency) + schedule stress"
+echo "[ci] 13/18 host-concurrency audit (--check-concurrency) + schedule stress"
 # static half: APX801-805 over the whole package against the
 # committed EMPTY baseline (a stale entry fails like the linter's)
 python -m apex_tpu.analysis --check-concurrency
@@ -542,7 +527,7 @@ XLA_FLAGS="${XLA_FLAGS:-} --xla_force_host_platform_device_count=8" \
     python -m apex_tpu.analysis.schedule --seeds 5 --replicas 2 \
     --requests 6 --new-tokens 4
 
-echo "[ci] 15/19 Q8 quantized serving smoke (int8 weight-only decode)"
+echo "[ci] 14/18 Q8 quantized serving smoke (int8 weight-only decode)"
 # kernel half: the quant matmul's interpret-mode parity sweep — GEMV
 # and tiled paths vs the jnp twin, plus the zero-channel round-trip
 python -c "from apex_tpu.ops import quant_matmul; \
@@ -563,7 +548,7 @@ echo "$Q8_OUT" | grep -q "compiles=2 " \
 echo "$Q8_OUT" | grep -Eq "tokens_s=[1-9]" \
     || { echo "[ci] FAIL: Q8 serve reported zero tokens/s"; exit 1; }
 
-echo "[ci] 16/19 live metrics plane (exporter + /healthz flip + SLO burn)"
+echo "[ci] 15/18 live metrics plane (exporter + /healthz flip + SLO burn)"
 METRICS_DIR="$(mktemp -d -t apex_tpu_metrics.XXXXXX)"
 METRICS_PORT=$((19300 + RANDOM % 500))
 # leg 1: sanitized 2-replica fleet with the exporter attached — the
@@ -624,7 +609,7 @@ python tools/monitor_summary.py "$METRICS_DIR/slo.jsonl" \
     || { echo "[ci] FAIL: monitor_summary did not render the SLO section"; exit 1; }
 rm -rf "$METRICS_DIR"
 
-echo "[ci] 17/19 process-isolated fleet (kill -9 drill + journal replay + autoscale trace)"
+echo "[ci] 16/18 process-isolated fleet (kill -9 drill + journal replay + autoscale trace)"
 CP_DIR="$(mktemp -d -t apex_tpu_cp.XXXXXX)"
 # leg 1: the uninterrupted 2-process reference — every replica is a
 # supervised subprocess behind the socket control plane; its digest
@@ -686,7 +671,7 @@ python tools/monitor_summary.py "$CP_DIR"/scale-logs/*.jsonl \
     || { echo "[ci] FAIL: monitor_summary did not render the autoscale trace"; exit 1; }
 rm -rf "$CP_DIR"
 
-echo "[ci] 18/19 expert-parallel serving smoke (MoE decode fast path)"
+echo "[ci] 17/18 expert-parallel serving smoke (MoE decode fast path)"
 # kernel half: the fused routing kernel's interpret-mode parity sweep
 # — Pallas top-k route/dispatch vs the jnp twin, keep/slot bit-exact
 python -c "from apex_tpu.ops import moe_routing; \
@@ -709,7 +694,7 @@ echo "$EP_OUT" | grep -q "compiles=2 " \
 echo "$EP_OUT" | grep -Eq "tokens_s=[1-9]" \
     || { echo "[ci] FAIL: EP serve reported zero tokens/s"; exit 1; }
 
-echo "[ci] 19/19 wire-protocol audit (--check-protocol)"
+echo "[ci] 18/18 wire-protocol audit (--check-protocol)"
 # the APX9xx family: serving/ + resilience/ audited against the
 # declared ProtocolSpec registry — the baseline is committed EMPTY
 # (every finding at introduction was fixed), so any output here is a

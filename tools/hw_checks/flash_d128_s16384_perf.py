@@ -2,7 +2,7 @@
 
 Round-5 recorded output on the v5e bench chip:
     wall slope: 45.6 ms -> 84.4 TF/s ; device: 39.5 ms -> 97.4 TF/s
-(bench.py's long_context d128_s16384 row is the artifact of record.)
+(No cell of the benchmark runs this shape; PERF.md section 7.)
 """
 import time, functools, jax, jax.numpy as jnp
 from apex_tpu.ops.flash_attention import flash_attention

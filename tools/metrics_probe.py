@@ -4,7 +4,7 @@
     python tools/metrics_probe.py --port P --out DIR [--host H]
         [--interval S] [--timeout S] [--settle N]
 
-The external half of the ci.sh step-16 smoke: started BEFORE the
+The external half of the ci.sh step-15 smoke: started BEFORE the
 serve (``standalone_gpt --serve[-fleet] --metrics-port P``), it polls
 ``/healthz`` + ``/metrics`` + ``/varz`` until the server goes away
 (``--settle`` consecutive connection failures after at least one

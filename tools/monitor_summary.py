@@ -4,7 +4,7 @@
     python tools/monitor_summary.py RUN.jsonl
 
 Prints throughput / loss trajectory / amp overflow history / watchdog
-alarms / phase-timer totals / bench section outcomes.  Exit 0 on a
+alarms / phase-timer totals.  Exit 0 on a
 parseable log (alarms are reported, not fatal), non-zero on a missing
 or empty one — CI keys off that (tools/ci.sh monitor smoke).  See
 docs/api/observability.md for the schema.
