@@ -317,6 +317,10 @@ class _Taint:
             return
         is_tainted = self.expr_tainted(value)
         for t in targets:
+            # a store into ``ref[i, lo:hi]`` assigns ``ref``: the index
+            # names are read there, not written
+            while isinstance(t, (ast.Subscript, ast.Attribute)):
+                t = t.value
             for name in ast.walk(t):
                 if isinstance(name, ast.Name):
                     if is_tainted:

@@ -56,8 +56,8 @@ KERNEL_TWINS: Dict[Tuple[str, str], TwinSpec] = {
         "apex_tpu/ops/flash_attention.py",
         "tests/test_flash_attention.py")
        for fn in ("_flash_fwd", "_flash_fwd_packed", "_flash_bwd",
-                  "_flash_bwd_packed", "_flash_fwd_e",
-                  "_flash_fwd_e_blocked", "_flash_bwd_e",
+                  "_flash_bwd_packed", "_fwd_e_driver",
+                  "_flash_fwd_e_blocked", "_bwd_e_driver",
                   "_flash_bwd_e_blocked")},
     # flash decode: the paged single-query serving kernel is specified
     # by the dense gather-and-softmax reference (also the naive decode

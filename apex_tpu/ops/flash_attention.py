@@ -46,8 +46,9 @@ probabilities from the saved per-row logsumexp.
 from __future__ import annotations
 
 import functools
+import itertools
 import math
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -60,9 +61,14 @@ _BIG = 1e30
 _LOG2E = math.log2(math.e)
 # Tuned on v5e via the GPT-345M train-step profile (b=8, h=16, s=1024,
 # d=64; device-time deltas are stable run-to-run even when wall clock is
-# not): (1024, 1024) beats (512, 1024) — 56.4 vs 62.2 ms/step of kernel
-# time across fwd+bwd — and (512, 512) loses despite its finer causal
-# block skipping; wide lanes win on the MXU.  VMEM at (1024, 1024),
+# not), on the grid-blocked (b, h, s, d) drivers below, before the E
+# layout existed: (1024, 1024) beats (512, 1024) — 56.4 vs 62.2 ms/step
+# of kernel time across fwd+bwd — and (512, 512) loses despite its finer
+# causal block skipping; wide lanes win on the MXU, and on a grid a
+# finer tile pays grid steps and online-softmax rescales.  Inside one
+# VMEM block it pays neither: the one-block E-layout kernels (what
+# training runs) chunk the causal triangle by rows instead, see
+# ``_e_chunk``.  VMEM at (1024, 1024),
 # d<=256: q/k/v/acc blocks + fp32 scores ~7 MB, within the 16 MB
 # budget (at d > 64 block_q is halved — see _clamp_blocks).  Env
 # overrides (read at import) for bench-driven re-tuning.
@@ -1804,10 +1810,13 @@ def flash_attention_partial(q: jnp.ndarray, k: jnp.ndarray,
 # (the head-group microbench beat the per-head grid: lane slices
 # pipeline behind the MXU).
 #
-# Single-block only: the whole (128-aligned) sequence must fit one
-# q/k-block (ps <= 1024 keeps the fp32 score temporaries inside VMEM).
-# Longer sequences keep the transposing path — `flash_e_supported`
-# tells callers which side they're on.
+# One block: the whole (128-aligned) sequence in VMEM, ps <= 1024 (the
+# fp32 score temporaries of the non-causal square set that bound).
+# Causal, its kernels walk the lower triangle in row chunks of
+# `_e_chunk(ps)` rows against keys [0, end of chunk): 10 of the 16
+# (256, 256) tiles at ps=1024, none of the masked half.  Longer
+# sequences take the blocked walk below — `flash_e_plan` tells callers
+# what runs for a shape, `flash_e_supported` whether anything does.
 
 _E_MAX_SEQ = 1024
 # Blocked sequence walk: sequences whose 128-aligned padding exceeds
@@ -1893,9 +1902,64 @@ def flash_e_supported(s: int, h: int, d: int) -> bool:
     return _e_mode(s, h, d)[0] is not None
 
 
-def _rand_keep(shape, seed, salt_b, salt_head, salt_i, salt_j, rate):
+def _e_chunk(ps: int, causal: bool) -> int:
+    """Query rows a chunk of the one-block kernels' walk over the
+    (ps, ps) score square.  A causal chunk at rows [lo, lo + c) needs
+    keys [0, lo + c) alone, so the kernels compute the lower triangle
+    in row chunks and never the masked half; inside one VMEM block a
+    finer chunk costs neither grid steps nor online-softmax rescales.
+    From the padded length alone: 256 where it divides (10 of 16 tiles
+    at ps=1024), else 128; one chunk (the whole square) when non-causal
+    or when ps has no second chunk.  Measured on v5e at b=8 h=16 d=64
+    ps=1024, us a call forward + backward (PERF.md, PR 32): the square
+    410 + 919, chunks of 512 330 + 691, of 256 350 + 636, of 128
+    412 + 629: a chunk costs a fixed ~0.25 us a head beside its tiles,
+    so 128's fewer tiles do not pay for its eight chunks.  At the
+    other padded lengths forward + backward win everywhere but ps=384
+    (+4.6%); the forward alone loses at 384-640 (PERF.md section 7)."""
+    if not causal or ps <= 128:
+        return ps
+    return 256 if ps % 256 == 0 and ps > 256 else 128
+
+
+class FlashEPlan(NamedTuple):
+    """What :func:`flash_attention_e` runs for a shape: the kernels are
+    static per shape, so their gauge is a plan and not a counter."""
+    mode: Optional[str]      # 'single' | 'blocked' | None (transposing)
+    hg: Optional[int]        # heads a grid step
+    chunk: Optional[int]     # query rows a chunk (single) or tile (blocked)
+    share: Optional[float]   # of the (ps, ps) score square computed
+
+
+def flash_e_plan(s: int, h: int, d: int, causal: bool,
+                 drop: bool = False) -> FlashEPlan:
+    """The E-layout plan for ``(s, h, d, causal)``, from the arithmetic
+    the drivers use (:func:`_e_mode`, :func:`_e_chunk`).  ``share`` is
+    the part of the padded score square whose matmuls and softmax run:
+    (n + 1) / 2n over n causal chunks or tiles, 1.0 non-causal.  The
+    blocked walk is given by its backward's tiles; its forward may
+    widen them (see :func:`_flash_fwd_e_blocked`)."""
+    mode, hg = _e_mode(s, h, d, drop=drop)
+    if mode is None:
+        return FlashEPlan(None, None, None, None)
+    ps = -(-s // 128) * 128
+    if mode == "single":
+        chunk = _e_chunk(ps, causal)
+    else:
+        chunk = min(_E_BLOCK, ps)
+        ps = -(-ps // chunk) * chunk
+    n = ps // chunk
+    return FlashEPlan(mode, hg, chunk,
+                      (n + 1) / (2 * n) if causal else 1.0)
+
+
+def _rand_keep(shape, seed, salt_b, salt_head, salt_i, salt_j, rate,
+               row0=0, width=None):
     """Deterministic dropout keep-mask from a counter-based hash
     (murmur3 fmix32 over per-element counters + call-site salts).
+    ``shape`` may be the rows from ``row0`` on of the first
+    ``shape[1]`` columns of a ``width``-wide tile (the causal row
+    chunks): an element's bit is the whole tile's.
 
     Plain jnp uint32 ops — no pltpu PRNG — so the SAME bits come out on
     TPU hardware and in interpret mode, and the backward regenerates the
@@ -1917,8 +1981,10 @@ def _rand_keep(shape, seed, salt_b, salt_head, salt_i, salt_j, rate):
             ^ _u(salt_i) * u32(0x165667B1)
             ^ _u(salt_j) * u32(0x9E3779B9))
     r = jax.lax.broadcasted_iota(jnp.uint32, shape, 0)
+    if row0:
+        r = r + u32(row0)
     c = jax.lax.broadcasted_iota(jnp.uint32, shape, 1)
-    x = r * u32(shape[1]) + c + salt
+    x = r * u32(width or shape[1]) + c + salt
     x = (x ^ (x >> 16)) * u32(0x85EBCA6B)
     x = (x ^ (x >> 13)) * u32(0xC2B2AE35)
     x = x ^ (x >> 16)
@@ -1931,6 +1997,12 @@ def _rand_keep(shape, seed, salt_b, salt_head, salt_i, salt_j, rate):
 
 def _fwd_e_kernel(scale, a, causal, has_kvm, drop, kpad, s_real, hg, d,
                   *refs):
+    """One-block E-layout forward: the whole padded sequence of ``hg``
+    heads a grid step.  Each head walks its score square in row chunks
+    of :func:`_e_chunk` rows: causal chunk [lo, hi) multiplies its
+    queries with keys [0, hi) alone and sees every key it may attend to
+    at once, so its softmax is one pass (no running max, no rescale).
+    Non-causal there is one chunk, the square."""
     if drop > 0.0:
         seed_ref, *refs = refs
     qkv_ref, *rest = refs
@@ -1940,24 +2012,31 @@ def _fwd_e_kernel(scale, a, causal, has_kvm, drop, kpad, s_real, hg, d,
         kvm_ref = None
         o_ref, lse_ref = rest
     blk = qkv_ref[0]                       # (ps, hg*3*d)
+    ps = blk.shape[0]
+    c = _e_chunk(ps, causal)
     if has_kvm:
         vm = kvm_ref[0, 0, 0, :][None, :] > 0
     bidx = pl.program_id(0)
     gidx = pl.program_id(1)
-    for j in range(hg):
+    # chunk-major: the same chunk of the hg heads back to back, equal
+    # shapes whose MXU and VPU phases overlap best (v5e, ps=1024, c=256:
+    # 331 us a call against 350 head-major; the backward, whose dk/dv
+    # sums run along a head's chunks, reads 636 head-major against 643)
+    for lo, j in itertools.product(range(0, ps, c), range(hg)):
         off = j * 3 * d
-        qh = blk[:, off:off + d]
-        kh = blk[:, off + d:off + 2 * d]
-        vh = blk[:, off + 2 * d:off + 3 * d]
-        s = _dot(qh, kh, trans_b=True)     # (ps, ps) raw logits, fp32
+        hi = lo + c
+        qh = blk[lo:hi, off:off + d]
+        kh = blk[:hi, off + d:off + 2 * d]
+        vh = blk[:hi, off + 2 * d:off + 3 * d]
+        s = _dot(qh, kh, trans_b=True)     # (c, hi) raw logits, fp32
         mask = None
         if causal:
-            mask = _tri_mask(s.shape, 0, 0)
-        if kpad and not has_kvm:
+            mask = _tri_mask(s.shape, lo, 0)
+        if kpad and not has_kvm and hi > s_real:
             km = _kcol_mask(s.shape, 0, s_real)
             mask = km if mask is None else (mask & km)
         if has_kvm:
-            mask = vm if mask is None else (mask & vm)
+            mask = vm[:, :hi] if mask is None else (mask & vm[:, :hi])
         if mask is not None:
             s = jnp.where(mask, s, _NEG)
         m = jnp.max(s, axis=1, keepdims=True)
@@ -1970,31 +2049,56 @@ def _fwd_e_kernel(scale, a, causal, has_kvm, drop, kpad, s_real, hg, d,
         if drop > 0.0:
             # l comes from the UNDROPPED p (normalization is by the true
             # softmax denominator); only the accumulated values drop.
-            keep = _rand_keep(p.shape, seed_ref[0], bidx,
-                              gidx * hg + j, 0, 0, drop)
+            keep = _rand_keep(p.shape, seed_ref[0], bidx, gidx * hg + j,
+                              0, 0, drop, row0=lo, width=ps)
             pa = jnp.where(keep, p, 0.0) * (1.0 / (1.0 - drop))
         acc = _dot(pa.astype(blk.dtype), vh)
         safe_l = jnp.where(l == 0.0, 1.0, l)
         o = acc / safe_l
         if has_kvm:
             o = jnp.where(dead, 0.0, o)
-        o_ref[0, :, j * d:(j + 1) * d] = o.astype(o_ref.dtype)
+        o_ref[0, lo:hi, j * d:(j + 1) * d] = o.astype(o_ref.dtype)
         lse = m * scale + jnp.log(safe_l)
-        lse_ref[0, j] = jnp.broadcast_to(lse[:, 0][None, :],
-                                         lse_ref.shape[2:])
+        lse_ref[0, j, :, lo:hi] = jnp.broadcast_to(
+            lse[:, 0][None, :], (lse_ref.shape[2], c))
+
+
+# The E drivers are jitted: a train step calls each once a layer with
+# the same shapes, and under the outer jit an inner one is traced and
+# lowered once a program, not once a layer.  That Pallas -> Mosaic
+# lowering is Python time on every start, compile cache hit or not, and
+# the chunked causal bodies have four times the equations (PERF.md,
+# PR 28 and PR 32).  ``interpret`` is an argument so that it keys the
+# trace: the callers below read it at call time.
+_jit_e_driver = functools.partial(
+    jax.jit, static_argnames=("h", "scale", "causal", "drop", "interpret"))
 
 
 def _flash_fwd_e(qkv_e, h, scale, causal, kv_mask=None, drop=0.0,
                  seed=None):
+    return _fwd_e_driver(qkv_e, kv_mask, seed, h=h, scale=scale,
+                         causal=causal, drop=drop, interpret=_interpret())
+
+
+def _flash_bwd_e(h, scale, causal, res, do, kv_mask=None, drop=0.0,
+                 seed=None):
+    qkv3, o3, lse = res                    # qkv3/o3 already ps-padded
+    return _bwd_e_driver(qkv3, o3, lse, do, kv_mask, seed, h=h,
+                         scale=scale, causal=causal, drop=drop,
+                         interpret=_interpret())
+
+
+@_jit_e_driver
+def _fwd_e_driver(qkv_e, kv_mask, seed, h, scale, causal, drop,
+                  interpret):
     b, s, width = qkv_e.shape
     d = width // (3 * h)
     ps = -(-s // 128) * 128
     hg = _pick_heads_per_group(h, d, ps, drop=drop > 0.0) \
         if ps <= _E_MAX_SEQ else None
     if hg is None:                   # matches _e_mode's 'blocked' arm
-        return _flash_fwd_e_blocked(qkv_e, h, scale, causal,
-                                    kv_mask=kv_mask, drop=drop,
-                                    seed=seed)
+        return _flash_fwd_e_blocked(qkv_e, h, scale, causal, kv_mask,
+                                    drop, seed, interpret)
     g = h // hg
     qkv3 = _pad_to(qkv_e, 1, ps)
     a = scale * _LOG2E
@@ -2032,7 +2136,7 @@ def _flash_fwd_e(qkv_e, h, scale, causal, kv_mask=None, drop=0.0,
             jax.ShapeDtypeStruct((b, h, 8, ps), jnp.float32),
         ],
         name="flash_attention_fwd",
-        interpret=_interpret(),
+        interpret=interpret,
     )(*operands)
     lse = lse8[:, :, 0, :s]                # (b, h, s)
     return o[:, :s], lse
@@ -2125,8 +2229,8 @@ def _fwd_e_blocked_kernel(scale, a, causal, has_kvm, drop, kpad, s_real,
                                               lse_ref.shape[2:])
 
 
-def _flash_fwd_e_blocked(qkv_e, h, scale, causal, kv_mask=None,
-                         drop=0.0, seed=None):
+def _flash_fwd_e_blocked(qkv_e, h, scale, causal, kv_mask, drop, seed,
+                         interpret):
     b, s, width = qkv_e.shape
     d = width // (3 * h)
     ps128 = -(-s // 128) * 128
@@ -2199,14 +2303,28 @@ def _flash_fwd_e_blocked(qkv_e, h, scale, causal, kv_mask=None,
             pltpu.VMEM((bs, 128), jnp.float32),
         ],
         name="flash_attention_fwd",
-        interpret=_interpret(),
+        interpret=interpret,
     )(*operands)
     lse = lse8[:, :, 0, :s]                # (b, h, s)
     return o[:, :s], lse
 
 
+def _add_to_head_rows(acc, part):
+    """``acc`` with its rows added to the leading rows of ``part``,
+    which has more: a causal chunk's dk/dv rows [0, hi) meet the sum of
+    the chunks before it, which reached [0, lo).  The cut is a multiple
+    of 128 rows, so slices and concatenate move no data."""
+    n = acc.shape[0]
+    return jnp.concatenate([acc + part[:n], part[n:]], axis=0)
+
+
 def _bwd_e_kernel(a, vscale, causal, has_kvm, drop, kpad, s_real, hg, d,
                   *refs):
+    """One-block E-layout backward, one kernel, five matmuls a chunk,
+    ``s`` recomputed once: the row chunks of :func:`_fwd_e_kernel`.
+    A chunk's dq rows are final and stored at once; its dk/dv reach
+    keys [0, hi) and are summed over the chunks in fp32 values, cast
+    and stored after a head's last chunk."""
     if drop > 0.0:
         seed_ref, *refs = refs
     qkv_ref, do_ref, lse2_ref, delta_ref, *rest = refs
@@ -2217,32 +2335,35 @@ def _bwd_e_kernel(a, vscale, causal, has_kvm, drop, kpad, s_real, hg, d,
         (dqkv_ref,) = rest
     blk = qkv_ref[0]                       # (ps, hg*3*d)
     do_blk = do_ref[0]                     # (ps, hg*d)
+    ps = blk.shape[0]
+    c = _e_chunk(ps, causal)
     if has_kvm:
         vm = kvm_ref[0, 0, 0, :][None, :] > 0
     bidx = pl.program_id(0)
     gidx = pl.program_id(1)
-    for j in range(hg):
+    for j, lo in itertools.product(range(hg), range(0, ps, c)):
         off = j * 3 * d
-        qh = blk[:, off:off + d]
-        kh = blk[:, off + d:off + 2 * d]
-        vh = blk[:, off + 2 * d:off + 3 * d]
-        doh = do_blk[:, j * d:(j + 1) * d]
-        s = _dot(qh, kh, trans_b=True)
+        hi = lo + c
+        qh = blk[lo:hi, off:off + d]
+        kh = blk[:hi, off + d:off + 2 * d]
+        vh = blk[:hi, off + 2 * d:off + 3 * d]
+        doh = do_blk[lo:hi, j * d:(j + 1) * d]
+        s = _dot(qh, kh, trans_b=True)     # (c, hi)
         # NOTE: unlike _bwd_fused_kernel, dp is NOT hoisted before the
-        # softmax here — a third live fp32 score buffer puts the kernel
-        # ~124 KB over the VMEM stack limit at hg=4/ps=1024, and the
-        # unrolled head loop already overlaps head j's VPU work with
-        # head j+1's MXU passes.
-        lse2 = lse2_ref[0, j, 0, :][:, None]
+        # softmax here — on the non-causal square a third live fp32
+        # score buffer puts the kernel ~124 KB over the VMEM stack limit
+        # at hg=4/ps=1024.  The causal chunks have the room and read
+        # 606 us a call for 636 with it (v5e, PERF.md PR 32): ROADMAP S3.
+        lse2 = lse2_ref[0, j, 0, lo:hi][:, None]
         arg = s * a - lse2
         mask = None
         if causal:
-            mask = _tri_mask(s.shape, 0, 0)
-        if kpad and not has_kvm:
+            mask = _tri_mask(s.shape, lo, 0)
+        if kpad and not has_kvm and hi > s_real:
             km = _kcol_mask(s.shape, 0, s_real)
             mask = km if mask is None else (mask & km)
         if has_kvm:
-            mask = vm if mask is None else (mask & vm)
+            mask = vm[:, :hi] if mask is None else (mask & vm[:, :hi])
         if mask is not None:
             arg = jnp.where(mask, arg, _NEG)
         p = jnp.exp2(arg)
@@ -2250,37 +2371,43 @@ def _bwd_e_kernel(a, vscale, causal, has_kvm, drop, kpad, s_real, hg, d,
             # regenerate the forward's keep mask; dv consumes the
             # dropped/rescaled probabilities, ds the undropped p with
             # the mask applied to dp (dS = P*(dP@M/(1-r) - delta))
-            keep = _rand_keep(p.shape, seed_ref[0], bidx,
-                              gidx * hg + j, 0, 0, drop)
+            keep = _rand_keep(p.shape, seed_ref[0], bidx, gidx * hg + j,
+                              0, 0, drop, row0=lo, width=ps)
             pa = jnp.where(keep, p, 0.0) * (1.0 / (1.0 - drop))
         else:
             pa = p
-        dv = _dot_t0(pa.astype(doh.dtype), doh)
+        dv_c = _dot_t0(pa.astype(doh.dtype), doh)      # (hi, d)
         vs = vh * jnp.asarray(vscale, vh.dtype)
         dp = _dot(doh, vs, trans_b=True)
         if drop > 0.0:
             dp = jnp.where(keep, dp, 0.0) * (1.0 / (1.0 - drop))
-        delta = delta_ref[0, j, 0, :][:, None]
+        delta = delta_ref[0, j, 0, lo:hi][:, None]
         ds = p * (dp - delta)
         dq = _dot(ds.astype(kh.dtype), kh)
-        dk = _dot_t0(ds.astype(qh.dtype), qh)
-        dqkv_ref[0, :, off:off + d] = dq.astype(dqkv_ref.dtype)
-        dqkv_ref[0, :, off + d:off + 2 * d] = dk.astype(dqkv_ref.dtype)
-        dqkv_ref[0, :, off + 2 * d:off + 3 * d] = \
-            dv.astype(dqkv_ref.dtype)
+        dk_c = _dot_t0(ds.astype(qh.dtype), qh)        # (hi, d)
+        dqkv_ref[0, lo:hi, off:off + d] = dq.astype(dqkv_ref.dtype)
+        if lo == 0:
+            dk, dv = dk_c, dv_c
+        else:
+            dk = _add_to_head_rows(dk, dk_c)
+            dv = _add_to_head_rows(dv, dv_c)
+        if hi == ps:
+            dqkv_ref[0, :, off + d:off + 2 * d] = dk.astype(dqkv_ref.dtype)
+            dqkv_ref[0, :, off + 2 * d:off + 3 * d] = \
+                dv.astype(dqkv_ref.dtype)
 
 
-def _flash_bwd_e(h, scale, causal, res, do, kv_mask=None, drop=0.0,
-                 seed=None):
-    qkv3, o3, lse, b, s = res              # qkv3/o3 already ps-padded
-    ps, width = qkv3.shape[1], qkv3.shape[2]
+@_jit_e_driver
+def _bwd_e_driver(qkv3, o3, lse, do, kv_mask, seed, h, scale, causal,
+                  drop, interpret):
+    b, ps, width = qkv3.shape
+    s = do.shape[1]                        # qkv3/o3 are ps-padded
     d = width // (3 * h)
     hg = _pick_heads_per_group(h, d, ps, drop=drop > 0.0) \
         if ps <= _E_MAX_SEQ else None
-    if hg is None:                   # same dispatch as _flash_fwd_e
-        return _flash_bwd_e_blocked(h, scale, causal, res, do,
-                                    kv_mask=kv_mask, drop=drop,
-                                    seed=seed)
+    if hg is None:                   # same dispatch as _fwd_e_driver
+        return _flash_bwd_e_blocked(qkv3, o3, lse, do, kv_mask, seed, h,
+                                    scale, causal, drop, interpret)
     g = h // hg
     a = scale * _LOG2E
     kpad = ps != s
@@ -2319,7 +2446,7 @@ def _flash_bwd_e(h, scale, causal, res, do, kv_mask=None, drop=0.0,
         out_specs=qkv_spec,
         out_shape=jax.ShapeDtypeStruct((b, ps, width), qkv3.dtype),
         name="flash_attention_bwd",
-        interpret=_interpret(),
+        interpret=interpret,
     )(*operands)
     return dqkv[:, :s]
 
@@ -2464,10 +2591,10 @@ def _bwd_e_blocked_kernel(a, vscale, causal, has_kvm, drop, kpad,
                 dv_acc[:, sl].astype(dqkv_ref.dtype)
 
 
-def _flash_bwd_e_blocked(h, scale, causal, res, do, kv_mask=None,
-                         drop=0.0, seed=None):
-    qkv3, o3, lse, b, s = res              # 128-aligned from the vjp fwd
-    width = qkv3.shape[2]
+def _flash_bwd_e_blocked(qkv3, o3, lse, do, kv_mask, seed, h, scale,
+                         causal, drop, interpret):
+    b, _, width = qkv3.shape               # 128-aligned from the vjp fwd
+    s = do.shape[1]
     d = width // (3 * h)
     bs = min(_E_BLOCK, -(-s // 128) * 128)
     # residuals are 128-aligned; the blocked walk needs bs multiples
@@ -2546,7 +2673,7 @@ def _flash_bwd_e_blocked(h, scale, causal, res, do, kv_mask=None,
             pltpu.VMEM((bs, hg * d), jnp.float32),
         ],
         name="flash_attention_bwd",
-        interpret=_interpret(),
+        interpret=interpret,
     )(*operands)
     return dqkv[:, :s]
 
@@ -2557,11 +2684,10 @@ def _flash_e_fused(qkv_e, h, scale, causal):
 
 
 def _flash_e_vjp_fwd(qkv_e, h, scale, causal):
-    b, s, _ = qkv_e.shape
-    ps = -(-s // 128) * 128
+    ps = -(-qkv_e.shape[1] // 128) * 128
     o, lse = _flash_fwd_e(qkv_e, h, scale, causal)
     o3 = _pad_to(o, 1, ps)
-    return o, (_pad_to(qkv_e, 1, ps), o3, lse, b, s)
+    return o, (_pad_to(qkv_e, 1, ps), o3, lse)
 
 
 def _flash_e_vjp_bwd(h, scale, causal, res, do):
@@ -2577,11 +2703,10 @@ def _flash_e_masked(qkv_e, kv_mask, h, scale, causal):
 
 
 def _flash_e_masked_vjp_fwd(qkv_e, kv_mask, h, scale, causal):
-    b, s, _ = qkv_e.shape
-    ps = -(-s // 128) * 128
+    ps = -(-qkv_e.shape[1] // 128) * 128
     o, lse = _flash_fwd_e(qkv_e, h, scale, causal, kv_mask=kv_mask)
     o3 = _pad_to(o, 1, ps)
-    return o, (_pad_to(qkv_e, 1, ps), o3, lse, b, s, kv_mask)
+    return o, (_pad_to(qkv_e, 1, ps), o3, lse, kv_mask)
 
 
 def _flash_e_masked_vjp_bwd(h, scale, causal, res, do):
@@ -2601,11 +2726,10 @@ def _flash_e_drop(qkv_e, seed, h, scale, causal, rate):
 
 
 def _flash_e_drop_vjp_fwd(qkv_e, seed, h, scale, causal, rate):
-    b, s, _ = qkv_e.shape
-    ps = -(-s // 128) * 128
+    ps = -(-qkv_e.shape[1] // 128) * 128
     o, lse = _flash_fwd_e(qkv_e, h, scale, causal, drop=rate, seed=seed)
     o3 = _pad_to(o, 1, ps)
-    return o, (_pad_to(qkv_e, 1, ps), o3, lse, b, s, seed)
+    return o, (_pad_to(qkv_e, 1, ps), o3, lse, seed)
 
 
 def _flash_e_drop_vjp_bwd(h, scale, causal, rate, res, do):
@@ -2626,12 +2750,11 @@ def _flash_e_masked_drop(qkv_e, kv_mask, seed, h, scale, causal, rate):
 
 def _flash_e_masked_drop_vjp_fwd(qkv_e, kv_mask, seed, h, scale, causal,
                                  rate):
-    b, s, _ = qkv_e.shape
-    ps = -(-s // 128) * 128
+    ps = -(-qkv_e.shape[1] // 128) * 128
     o, lse = _flash_fwd_e(qkv_e, h, scale, causal, kv_mask=kv_mask,
                           drop=rate, seed=seed)
     o3 = _pad_to(o, 1, ps)
-    return o, (_pad_to(qkv_e, 1, ps), o3, lse, b, s, kv_mask, seed)
+    return o, (_pad_to(qkv_e, 1, ps), o3, lse, kv_mask, seed)
 
 
 def _flash_e_masked_drop_vjp_bwd(h, scale, causal, rate, res, do):

@@ -114,6 +114,29 @@ class TestLinterRules:
         """)
         assert fs == []
 
+    def test_apx102_store_index_names_are_not_assigned(self):
+        """``ref[0, lo:hi] = traced`` writes ``ref``; ``lo`` and ``hi``
+        are read, and a static branch on them stays legal (the row
+        chunks of the flash kernels)."""
+        fs = _lint("""
+            import functools
+            import jax
+            import jax.numpy as jnp
+            from jax.experimental import pallas as pl
+
+            def kernel(n, x_ref, o_ref):
+                for lo in range(0, n, 8):
+                    hi = lo + 8
+                    o_ref[0, lo:hi] = jnp.exp(x_ref[0, lo:hi])
+                    if hi == n:
+                        o_ref[1, :] = jnp.zeros((n,))
+
+            def f(x):
+                return pl.pallas_call(functools.partial(kernel, 16),
+                                      out_shape=x)(x)
+        """)
+        assert fs == []
+
     def test_apx102_shape_branch_is_exempt(self):
         fs = _lint("""
             import jax

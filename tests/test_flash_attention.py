@@ -523,6 +523,133 @@ class TestELayout:
         np.testing.assert_allclose(np.asarray(ge), np.asarray(gr),
                                    rtol=2e-4, atol=2e-4)
 
+    @pytest.mark.parametrize("shape", [(1, 512, 4, 64),    # 2 x 256
+                                       (1, 640, 2, 64),    # 5 x 128
+                                       (1, 1000, 2, 64),   # padded: 4 x 256
+                                       (1, 1024, 4, 64),   # GPT-2's block
+                                       (1, 768, 8, 32)])   # 3 x 256, hg=8
+    def test_causal_row_chunks_parity(self, shape):
+        """Causal one-block kernels walk the triangle in row chunks
+        (``_e_chunk``): same outputs and gradients as the dense
+        reference at sizes with two to five chunks."""
+        from apex_tpu.ops.flash_attention import (flash_attention_e,
+                                                  flash_e_plan)
+        b, s, h, d = shape
+        plan = flash_e_plan(s, h, d, True)
+        assert plan.mode == "single" and plan.share < 1.0
+        qkv = jax.random.normal(jax.random.PRNGKey(0),
+                                (b, s, h, 3 * d)) * 0.5
+        w = jax.random.normal(jax.random.PRNGKey(1), (b, s, h * d))
+        got = flash_attention_e(qkv, causal=True)
+        want = self._ref(qkv, causal=True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+        ge = jax.grad(lambda q: jnp.sum(
+            flash_attention_e(q, causal=True) * w))(qkv)
+        gr = jax.grad(lambda q: jnp.sum(
+            self._ref(q, causal=True) * w))(qkv)
+        np.testing.assert_allclose(np.asarray(ge), np.asarray(gr),
+                                   rtol=2e-4, atol=2e-4)
+
+    def test_causal_row_chunks_kv_mask(self):
+        from apex_tpu.ops.flash_attention import flash_attention_e
+        b, s, h, d = 2, 512, 2, 64
+        qkv = jax.random.normal(jax.random.PRNGKey(0),
+                                (b, s, h, 3 * d)) * 0.5
+        lens = jnp.array([300, s])         # the cut inside chunk 1
+        m = jnp.arange(s)[None, :] < lens[:, None]
+        w = jax.random.normal(jax.random.PRNGKey(1), (b, s, h * d))
+        got = flash_attention_e(qkv, causal=True, kv_mask=m)
+        want = self._ref(qkv, causal=True, kv_mask=m)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+        ge = jax.grad(lambda q: jnp.sum(flash_attention_e(
+            q, causal=True, kv_mask=m) * w))(qkv)
+        gr = jax.grad(lambda q: jnp.sum(self._ref(
+            q, causal=True, kv_mask=m) * w))(qkv)
+        np.testing.assert_allclose(np.asarray(ge), np.asarray(gr),
+                                   rtol=2e-4, atol=2e-4)
+
+    @pytest.mark.parametrize("shape,causal,want", [
+        # the benchmark's training cells
+        ((1024, 16, 64), True, ("single", 4, 256, 0.625)),
+        ((512, 16, 64), False, ("single", 4, 512, 1.0)),
+        ((512, 16, 64), True, ("single", 4, 256, 0.75)),
+        ((640, 16, 64), True, ("single", 4, 128, 0.6)),
+        ((1000, 16, 64), True, ("single", 4, 256, 0.625)),
+        ((256, 8, 32), True, ("single", 8, 128, 0.75)),
+        ((128, 4, 64), True, ("single", 4, 128, 1.0)),
+        ((2048, 16, 64), True, ("blocked", 4, 512, 0.625)),
+        ((2048, 16, 64), False, ("blocked", 4, 512, 1.0)),
+        ((65536, 16, 64), True, (None, None, None, None)),
+    ])
+    def test_plan(self, shape, causal, want):
+        from apex_tpu.ops.flash_attention import flash_e_plan
+        assert tuple(flash_e_plan(*shape, causal)) == want
+        if want[0] == "single" and shape[0] == 1024:
+            # in-kernel dropout halves the heads a step, as _e_mode says
+            assert flash_e_plan(*shape, causal, drop=True).hg == 2
+
+    @staticmethod
+    def _kernel_jaxprs(s, h, d, causal, masked=False):
+        """(forward, backward) kernel jaxprs of one flash_attention_e
+        call under ``jax.grad``, traced and never run."""
+        from apex_tpu.ops.flash_attention import flash_attention_e
+        m = jnp.ones((1, s), bool) if masked else None
+
+        def found(jaxpr, into):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    into.append(eqn.params["jaxpr"])
+                for v in eqn.params.values():
+                    inner = getattr(v, "jaxpr", v)
+                    if hasattr(inner, "eqns"):
+                        found(inner, into)
+            return into
+
+        top = jax.make_jaxpr(jax.grad(lambda x: flash_attention_e(
+            x, causal=causal, kv_mask=m).astype(jnp.float32).sum()))(
+            jax.ShapeDtypeStruct((1, s, h, 3 * d), jnp.bfloat16))
+        fwd, bwd = found(top.jaxpr, [])
+        return fwd, bwd
+
+    @staticmethod
+    def _multiply_adds(jaxpr):
+        total = 0
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "dot_general":
+                (lc, rc), _ = eqn.params["dimension_numbers"]
+                lhs, rhs = (v.aval.shape for v in eqn.invars)
+                total += int(np.prod(lhs)) * int(np.prod(
+                    [n for i, n in enumerate(rhs) if i not in rc]))
+        return total
+
+    @pytest.mark.parametrize("s", [1024, 1000, 640])
+    def test_causal_kernels_do_the_plans_share_of_the_work(self, s):
+        """The matmuls of the causal kernels are the plan's share of the
+        square's: two (c, hi, d) products a chunk forward, five
+        backward."""
+        from apex_tpu.ops.flash_attention import flash_e_plan
+        h, d = 16, 64
+        plan = flash_e_plan(s, h, d, True)
+        ps = -(-s // 128) * 128
+        fwd, bwd = self._kernel_jaxprs(s, h, d, True)
+        square = plan.hg * ps * ps * d
+        assert self._multiply_adds(fwd) == round(2 * square * plan.share)
+        assert self._multiply_adds(bwd) == round(5 * square * plan.share)
+
+    @pytest.mark.parametrize("masked,eqns", [(False, (107, 116)),
+                                             (True, (126, 123))])
+    def test_noncausal_kernels_keep_their_bodies(self, masked, eqns):
+        """``causal=False`` (BERT's route) runs the one-chunk body: the
+        equation counts read on the parent of PR 32, and the whole
+        square's multiply-adds."""
+        s, h, d = 512, 16, 64
+        fwd, bwd = self._kernel_jaxprs(s, h, d, False, masked)
+        assert (len(fwd.eqns), len(bwd.eqns)) == eqns
+        assert self._multiply_adds(fwd) == 2 * 4 * s * s * d
+        assert self._multiply_adds(bwd) == 5 * 4 * s * s * d
+
     @pytest.mark.parametrize("causal", [False, True])
     @pytest.mark.parametrize("shape", [(1, 1152, 2, 64),   # padded s
                                        (1, 2048, 4, 64),
@@ -666,6 +793,30 @@ class TestELayoutDropout:
             * w))(qkv)
         gr = jax.grad(lambda x: jnp.sum(self._dense_with_mask(
             x, keep, rate, causal) * w))(qkv)
+        np.testing.assert_allclose(np.asarray(ge), np.asarray(gr),
+                                   rtol=5e-4, atol=5e-4)
+
+    def test_causal_row_chunks_dropout_parity(self):
+        """Two row chunks at s=512: forward, backward and the dense
+        reference agree on ONE keep mask, the (ps, ps) tile's of a
+        kernel that computes the whole square."""
+        from apex_tpu.ops.flash_attention import flash_attention_e
+        b, s, h, d, rate = 1, 512, 2, 64, 0.3
+        qkv = jax.random.normal(jax.random.PRNGKey(0),
+                                (b, s, h, 3 * d)) * 0.5
+        w = jax.random.normal(jax.random.PRNGKey(1), (b, s, h * d))
+        seed = 4321
+        keep = self._expected_keep(b, h, s, seed, rate, bs=s)
+        got = flash_attention_e(qkv, causal=True, dropout_rate=rate,
+                                dropout_seed=seed)
+        want = self._dense_with_mask(qkv, keep, rate, True)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-4, atol=2e-4)
+        ge = jax.grad(lambda x: jnp.sum(flash_attention_e(
+            x, causal=True, dropout_rate=rate, dropout_seed=seed)
+            * w))(qkv)
+        gr = jax.grad(lambda x: jnp.sum(self._dense_with_mask(
+            x, keep, rate, True) * w))(qkv)
         np.testing.assert_allclose(np.asarray(ge), np.asarray(gr),
                                    rtol=5e-4, atol=5e-4)
 

@@ -87,6 +87,10 @@ def _flash_e(qkv):
     return fa.flash_attention_e(qkv, causal=True)
 
 
+def _flash_e_bert(qkv, kv_mask):
+    return fa.flash_attention_e(qkv, kv_mask=kv_mask)
+
+
 def _flash_e_drop(qkv, seed):
     return fa.flash_attention_e(qkv, causal=True, dropout_rate=0.1,
                                 dropout_seed=seed)
@@ -214,6 +218,13 @@ CASES = {
                               (((B, S, H, 3 * D), BF16),)),
     "flash_e_fwd_bwd_s2048": (_grad_sum(_flash_e, 1),
                               (((4, 2048, H, 3 * D), BF16),)),
+    # the causal row chunks on a padded sequence, and BERT's route
+    # (non-causal through the kv_mask lane, batch 16 x 512)
+    "flash_e_fwd_bwd_s1000": (_grad_sum(_flash_e, 1),
+                              (((B, 1000, H, 3 * D), BF16),)),
+    "flash_e_fwd_bwd_s512_noncausal": (
+        _grad_sum(_flash_e_bert, 1),
+        (((16, 512, H, 3 * D), BF16), ((16, 512), jnp.bool_))),
     "flash_e_dropout_fwd_bwd": (_grad_sum(_flash_e_drop, 1),
                                 (((B, S, H, 3 * D), BF16), ((), I32))),
     "layer_norm_fwd_bwd": (_grad_sum(_layer_norm, 3),
@@ -307,6 +318,23 @@ def test_kernel_compiles_for_v5e(name, one_chip, mosaic,
             for s, dt in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_e_drivers_lower_one_body_a_kernel(one_chip, mosaic):
+    """The E drivers are jitted, so a program that calls
+    ``flash_attention_e`` once a layer lowers each distinct kernel once
+    (a train step of 24 layers: 2 bodies, not 48).  Lowered for the
+    described chip, never compiled."""
+    def four_layers(qkv):
+        return sum(_flash_e(qkv * k).astype(F32).sum()
+                   for k in (1.0, 2.0, 3.0, 4.0))
+
+    qkv = jax.ShapeDtypeStruct((2, S, H, 3 * D), BF16, sharding=one_chip)
+    text = jax.jit(jax.grad(four_layers)).lower(qkv).as_text()
+    bodies = re.findall(r'kernel_name = "(\w+)"', text)
+    assert sorted(bodies) == ["flash_attention_bwd", "flash_attention_fwd"]
+    assert text.count("call @_fwd_e_driver") == 4
+    assert text.count("call @_bwd_e_driver") == 4
 
 
 # --- whole serving programs: the paged cache keeps the kernel's layout ----
