@@ -71,6 +71,12 @@ KERNEL_TWINS: Dict[Tuple[str, str], TwinSpec] = {
     ("flash_decode.py", "_decode_paged_multi"): _spec(
         "flash_decode_multi", "paged_attention_multi_reference",
         "apex_tpu/ops/flash_decode.py", "tests/test_serving.py"),
+    # latent decode (ISSUE-34): paged attention over a latent cache,
+    # one driver for the single-token and the t-token form, specified
+    # by the dense gather twins
+    ("latent_decode.py", "_latent_paged"): _spec(
+        "latent_decode", "latent_attention_reference",
+        "apex_tpu/ops/latent_decode.py", "tests/test_serving_mla_moe.py"),
     ("layer_norm.py", "_ln_forward"): _spec(
         "layer_norm", "_layer_norm_reference",
         "apex_tpu/ops/layer_norm.py", "tests/test_layer_norm.py"),

@@ -581,24 +581,28 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_mask=None,
                offsets=None, drop=0.0, dsalt=None, window=None):
     b, h, sq, d = q.shape
     sk = k.shape[2]
+    # a value width of its own (latent attention's expanded heads score
+    # on 192 dims and carry 128): v, o and the accumulator take it
+    dv = v.shape[-1]
     # grouped-query attention: ``groups`` query heads read each of k/v's
     # heads (query head j reads head j // groups), through the k/v
     # index maps alone -- no repeated copy of k or v is made
     groups = h // k.shape[1]
-    pack = groups == 1 and _use_head_packing(h, d)
+    pack = groups == 1 and dv == d and _use_head_packing(h, d)
     if pack:
         # d=64 head-pair packing (module note): adjacent heads share a
         # 128-lane tile; h counts PAIRS below, lse carries 2 sublane
         # groups per q-block and unpacks to per-head order at the end.
         q, k, v = (_pack_head_pairs(x) for x in (q, k, v))
         h, d = h // 2, 2 * d
+        dv = d
     g = 2 if pack else 1
     block_q, block_k = _clamp_blocks(block_q, block_k, d)
     bq = min(block_q, max(8, sq))
     bk = min(block_k, max(128, sk))
     q3 = _pad_to(q.reshape(b * h, sq, d), 1, bq)
     k3 = _pad_to(k.reshape(b * h // groups, sk, d), 1, bk)
-    v3 = _pad_to(v.reshape(b * h // groups, sk, d), 1, bk)
+    v3 = _pad_to(v.reshape(b * h // groups, sk, dv), 1, bk)
     bh, psq, _ = q3.shape
     psk = k3.shape[1]
     nq, nk = psq // bq, psk // bk
@@ -608,7 +612,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_mask=None,
     def _unpack(o, lse8):
         lse = lse8[:, :, 0, :].reshape(bh, psq)[:, :sq]
         if not pack:
-            return o[:, :sq].reshape(b, h, sq, d), lse
+            return o[:, :sq].reshape(b, h, sq, dv), lse
         o4 = _unpack_head_pairs(o[:, :sq].reshape(b, h, sq, d))
         lse1 = lse8[:, :, 8, :].reshape(bh, psq)[:, :sq]
         # (bh_pairs, 2, sq) flattens straight to global head order:
@@ -628,7 +632,13 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_mask=None,
         lse_spec = pl.BlockSpec((1, 1, 8 * g, bq),
                                 lambda b_: (b_, 0, 0, 0),
                                 memory_space=pltpu.VMEM)
-        in_specs = [qb_spec, kb_spec, kb_spec]
+        vb_spec, ob_spec = kb_spec, qb_spec
+        if dv != d:
+            vb_spec = pl.BlockSpec((1, psk, dv), kb_spec.index_map,
+                                   memory_space=pltpu.VMEM)
+            ob_spec = pl.BlockSpec((1, psq, dv), qb_spec.index_map,
+                                   memory_space=pltpu.VMEM)
+        in_specs = [qb_spec, kb_spec, vb_spec]
         operands = [q3, k3, v3]
         if drop > 0.0:
             in_specs.insert(0, pl.BlockSpec(memory_space=pltpu.SMEM))
@@ -647,9 +657,9 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_mask=None,
                               drop=drop, h=h, pack=pack, window=window),
             grid=(bh,),
             in_specs=in_specs,
-            out_specs=[qb_spec, lse_spec],
+            out_specs=[ob_spec, lse_spec],
             out_shape=[
-                jax.ShapeDtypeStruct((bh, psq, d), q.dtype),
+                jax.ShapeDtypeStruct((bh, psq, dv), q.dtype),
                 jax.ShapeDtypeStruct((bh, 1, 8 * g, bq), jnp.float32),
             ],
             name="flash_attention_fwd",
@@ -674,7 +684,12 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_mask=None,
     lse_spec = pl.BlockSpec((1, 1, 8 * g, bq),
                             lambda b_, i, j: (b_, i, 0, 0),
                             memory_space=pltpu.VMEM)
-    in_specs = [q_spec, k_spec, k_spec]
+    v_spec, o_spec = k_spec, q_spec
+    if dv != d:
+        v_spec = pl.BlockSpec((1, bk, dv), k_map, memory_space=pltpu.VMEM)
+        o_spec = pl.BlockSpec((1, bq, dv), q_spec.index_map,
+                              memory_space=pltpu.VMEM)
+    in_specs = [q_spec, k_spec, v_spec]
     operands = [q3, k3, v3]
     if drop > 0.0:
         in_specs.insert(0, pl.BlockSpec(memory_space=pltpu.SMEM))
@@ -694,13 +709,13 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_mask=None,
                           drop=drop, h=h, pack=pack, window=window),
         grid=(bh, nq, nk),
         in_specs=in_specs,
-        out_specs=[q_spec, lse_spec],
+        out_specs=[o_spec, lse_spec],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, psq, d), q.dtype),
+            jax.ShapeDtypeStruct((bh, psq, dv), q.dtype),
             jax.ShapeDtypeStruct((bh, nq, 8 * g, bq), jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((bq, d), jnp.float32),
+            pltpu.VMEM((bq, dv), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
@@ -1422,9 +1437,11 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
 
     Forward only (serving's prefill): ``k``/``v`` with fewer heads than
     ``q`` is grouped-query attention (query head ``j`` reads head
-    ``j // (h // hk)``), and ``window`` with ``causal`` keeps for each
+    ``j // (h // hk)``), ``window`` with ``causal`` keeps for each
     query the ``window`` keys ending at its own position, whole key
-    blocks outside that band neither fetched nor computed.  Neither
+    blocks outside that band neither fetched nor computed, and ``v``
+    may have a last dimension of its own (latent attention's expanded
+    heads: q, k of 192, v and the result of 128).  None of the three
     has a backward pass yet.
     """
     from ._context import in_manual_axis_context
@@ -1440,10 +1457,12 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     if in_manual_axis_context(q, k, v):
         return mha_reference(q, k, v, scale=scale, causal=causal,
                              kv_mask=kv_mask, window=window)
-    if window is not None or k.shape[1] != q.shape[1]:
+    if window is not None or k.shape[1] != q.shape[1] \
+            or v.shape[-1] != q.shape[-1]:
         if kv_mask is not None or (window is not None and not causal):
-            raise ValueError("grouped heads and a window run the causal "
-                             "or the unmasked forward only")
+            raise ValueError("grouped heads, a window and a value width "
+                             "of its own run the causal or the unmasked "
+                             "forward only")
         if scale is None:
             scale = q.shape[-1] ** -0.5
         return _flash_fwd(q, k, v, scale, causal, block_q, block_k,

@@ -298,8 +298,10 @@ def quantize_weights(weights) -> QuantGPTServingWeights:
     if not hasattr(weights, "wpe"):
         raise ValueError(
             f"quantize_weights: {type(weights).__name__} is not the "
-            f"'gpt2' family's weights; the 'rope_moe' family has no Q8 "
-            f"layout yet (its expert stacks have no int8 kernel)")
+            f"'gpt2' family's weights; the 'rope_moe' and 'mla_moe' "
+            f"families have no Q8 layout yet (their expert stacks, and "
+            f"latent attention's absorbed projections, have no int8 "
+            f"kernel)")
     layers = []
     for lw in weights.layers:
         qkv_k, qkv_s = quantize_weight(lw.qkv_k)

@@ -7,6 +7,11 @@ The serving counterpart of the training pipeline (ROADMAP item 1):
   (:class:`KVCacheManager`), bf16/int8 storage.
 * :mod:`.model` — pure-function GPT prefill + paged decode over the
   extracted :class:`GPTServingWeights`.
+  Three families of model (``ServingModelConfig.family``): GPT-2's
+  block, :mod:`.rope_moe` (RMSNorm, rotary, grouped heads, windows, a
+  dropless mixture of experts) and :mod:`.mla_moe` (latent attention
+  over a paged latent cache, sandwich norms, a held share of the
+  experts, the model's own MTP module as the engine's draft).
 * :mod:`.engine` — continuous batching: bucket-laddered jitted steps,
   reservation admission, SIGTERM clean drain, tokens/s + p50/p99
   metrics (:class:`ServingEngine`), plus the decode fast path
@@ -63,10 +68,11 @@ from .metrics import (EngineGauges, ReplicaMonitor, RequestTrace,
 from .ep import (SERVING_EP_AXIS, EPContext, expand_moe_weights,
                  serving_ep_plan)
 from .model import (MOE_TICK_COUNTERS, GPTServingWeights, LayerSpec,
-                    LayerWeights, MoELayerWeights,
+                    LayerWeights, MlaSpec, MoELayerWeights,
                     QuantGPTServingWeights, QuantLayerWeights,
                     RopeMoEWeights, RopeSpec, ServingModelConfig,
-                    copy_cache_block, init_rope_moe_weights,
+                    copy_cache_block, init_mla_moe_weights,
+                    init_rope_moe_weights,
                     extract_serving_weights, gather_cache_blocks,
                     gpt_decode_step, gpt_extend_step,
                     gpt_prefill_step, gpt_sequence_logits,
@@ -92,6 +98,7 @@ __all__ = [
     "QuantGPTServingWeights", "QuantLayerWeights",
     "ServingModelConfig",
     "RopeMoEWeights", "LayerSpec", "RopeSpec", "init_rope_moe_weights",
+    "MlaSpec", "init_mla_moe_weights",
     "MOE_TICK_COUNTERS",
     "copy_cache_block", "extract_serving_weights",
     "gather_cache_blocks", "gpt_decode_step", "gpt_extend_step",

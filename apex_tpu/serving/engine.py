@@ -58,7 +58,8 @@ from ..ops.quant_matmul import is_quantized_weights
 from .model import (MOE_TICK_COUNTERS, GPTServingWeights,
                     ServingModelConfig,
                     copy_cache_block, gpt_decode_step,
-                    gpt_extend_step, gpt_prefill_step)
+                    gpt_extend_step, gpt_prefill_step,
+                    mtp_extend_step, mtp_prefill_step)
 from .resilience import RequestJournal, ShedPolicy, SpeculationGovernor
 
 logger = get_logger(__name__)
@@ -183,6 +184,8 @@ class Request:
     submit_t: Optional[float] = None     # engine-clock submit instant
     terminal: Optional[str] = None       # finished | preempted |
     # deadline | deadline_exceeded | shed — set exactly once
+    draft: Optional[int] = None          # the model's own MTP module's
+    # proposal for the token after the next (speculation by MTP)
 
     @property
     def done(self) -> bool:
@@ -393,6 +396,12 @@ class ServingEngine:
             raise ValueError(
                 "family 'rope_moe' does not serve from an int8 cache "
                 "yet: its grouped-head kernel path is unproven there")
+        if cache_cfg.latent != (model_cfg.mla is not None):
+            raise ValueError(
+                f"family {model_cfg.family!r} and a cache with "
+                f"value_dim={cache_cfg.value_dim}: latent attention "
+                f"(family 'mla_moe'), and it alone, serves from the "
+                f"latent cache (default_cache_config makes it)")
         self.weights = weights
         self.model_cfg = model_cfg
         self.cache_cfg = cache_cfg
@@ -440,11 +449,34 @@ class ServingEngine:
         # set on the first submit carrying a deadline: the per-tick
         # enforcement scan is skipped entirely while no request has one
         self._deadlines_active = False
-        if self.speculate_k > 0 and draft_weights is None:
+        # a model that brings its own draft (family 'mla_moe' with its
+        # multi-token-prediction module) needs no second model: its
+        # prefill and verify programs run the module too, on the hidden
+        # states they end in, and a tick is ONE program
+        self._mtp = (self.speculate_k > 0 and draft_weights is None
+                     and model_cfg.mtp_layers > 0)
+        if self._mtp:
+            if getattr(weights, "mtp", None) is None \
+                    or cache_cfg.num_layers != model_cfg.num_layers + 1:
+                raise ValueError(
+                    "mtp_layers=1 serves weights that hold the MTP "
+                    "module (init_mla_moe_weights(mtp=True)) from a "
+                    "cache with a latent layer for it "
+                    "(default_cache_config)")
+            if self.speculate_k != 1 or self.prefill_chunk > 0 \
+                    or self.prefix_share:
+                raise ValueError(
+                    "the MTP draft proposes one token a tick "
+                    "(speculate_k=1) after whole-prompt prefills: "
+                    "chunked prefill and prefix sharing do not feed the "
+                    "module yet")
+        elif self.speculate_k > 0 and draft_weights is None:
             raise ValueError(
-                "speculate_k > 0 needs a draft model: pass "
-                "draft_weights (+ draft_cfg) — e.g. "
-                "extract_serving_weights of a narrower GPT")
+                "speculate_k > 0 needs a draft: pass draft_weights (+ "
+                "draft_cfg) — e.g. extract_serving_weights of a "
+                "narrower GPT — or serve a model that brings its own "
+                "(family 'mla_moe' with mtp_layers=1: its "
+                "multi-token-prediction module)")
         self.draft_weights = draft_weights
         self.draft_cfg = draft_cfg
         self.draft_cache_cfg: Optional[KVCacheConfig] = None
@@ -519,6 +551,7 @@ class ServingEngine:
         # token — percentiles read the most recent window only
         self._latencies: deque = deque(maxlen=_LATENCY_WINDOW)
         self._tick_levels: Optional[Dict[str, Any]] = None
+        self._tick_mtp: Dict[str, int] = {}    # a tick's MTP drafts
         self._done_count = 0
         self._preempted_count = 0
         self._done_tokens = 0
@@ -538,6 +571,9 @@ class ServingEngine:
         self.tick_sums: Dict[str, int] = {}
         # {window or None: layers of that kind}, for the pages a tick's
         # attention reads (families with windowed layers only)
+        self._experts_slots = sum(
+            lw.e1.shape[0] for lw in weights.layers
+            if getattr(lw, "e1", None) is not None)
         windows = [s.window for s in model_cfg.layers]
         self._layer_windows = {w: windows.count(w) for w in set(windows)} \
             if any(windows) else {}
@@ -592,10 +628,12 @@ class ServingEngine:
         cfg = self.draft_cfg if draft else self.model_cfg
         ccfg = self.draft_cache_cfg if draft else self.cache_cfg
 
+        prefill = mtp_prefill_step if self._mtp else gpt_prefill_step
+
         @functools.partial(jax.jit, donate_argnums=(1,))
         def step(weights, cache, tokens, length, blocks):
-            return gpt_prefill_step(weights, cfg, ccfg, cache, tokens,
-                                    length, blocks)
+            return prefill(weights, cfg, ccfg, cache, tokens, length,
+                           blocks)
 
         return step
 
@@ -605,12 +643,13 @@ class ServingEngine:
         cfg = self.draft_cfg if draft else self.model_cfg
         ccfg = self.draft_cache_cfg if draft else self.cache_cfg
 
+        extend = mtp_extend_step if self._mtp else gpt_extend_step
+
         @functools.partial(jax.jit, donate_argnums=(1,))
         def step(weights, cache, tokens, block_tables, seq_lens,
                  write_blocks, write_offsets):
-            return gpt_extend_step(weights, cfg, ccfg, cache, tokens,
-                                   block_tables, seq_lens,
-                                   write_blocks, write_offsets)
+            return extend(weights, cfg, ccfg, cache, tokens, block_tables,
+                          seq_lens, write_blocks, write_offsets)
 
         return step
 
@@ -727,22 +766,24 @@ class ServingEngine:
         warmup)."""
         bs = self.cache_cfg.block_size
         spec = self.speculate_k > 0
+        draft = spec and not self._mtp    # a second model's mirror programs
         if not self._chunking:
             for pb in self.ladder.pages:
                 self._prefill_fn(pb * bs)
-                if spec:
+                if draft:
                     self._draft_prefill_fn(pb * bs)
         if self._chunking or self.prefix_share:
             for ct in self.ladder.chunk_rungs(bs):
                 for pb in self.ladder.pages:
                     self._extend_fn(1, ct, pb)
-                    if spec:
+                    if draft:
                         self._draft_extend_fn(1, ct, pb)
         for bb in self.ladder.batch:
             for pb in self.ladder.pages:
                 self._decode_fn(bb, pb)
-                if spec:
+                if draft:
                     self._draft_decode_fn(bb, pb)
+                if spec:
                     self._extend_fn(bb, self.speculate_k + 1, pb)
         if self.prefix_share:
             self._cow_fn("target")
@@ -925,7 +966,11 @@ class ServingEngine:
                         self.draft_weights, self.draft_cache,
                         jnp.asarray(tokens), jnp.int32(p_len),
                         jnp.asarray(bt))
-                first = int(next_token)      # explicit host sync: the
+                if self._mtp:                # [first token, its draft]
+                    first, req.draft = (int(t) for t in
+                                        np.asarray(next_token))
+                else:
+                    first = int(next_token)  # explicit host sync: the
                 # admission boundary needs the token to seed the decode
             dt = self._clock() - t0
             req.out_tokens.append(first)
@@ -1245,11 +1290,12 @@ class ServingEngine:
         generated this tick."""
         with span("apex.serve.step") as tick:
             self._tick_levels = None
+            self._tick_mtp = {}
             gained, admitted = self._tick()
             if self._tick_levels is not None and recording():
                 # the tick's counters ride on its span: an operator
                 # reads them in xprof on the step they belong to
-                tick.set(admitted=admitted,
+                tick.set(admitted=admitted, **self._tick_mtp,
                          **{k: self._tick_levels[k]
                             for k in _STEP_SPAN_COUNTERS})
             return gained
@@ -1386,7 +1432,8 @@ class ServingEngine:
         while :func:`~..monitor.tracing.recording`, added into
         :attr:`tick_sums`; empty for GPT-2 and where neither a monitor
         nor a recorder looks.  ``extra`` is what the step appended to
-        the tick's tokens (:data:`~.model.MOE_TICK_COUNTERS`).  The pages are the host's
+        the tick's tokens (:data:`~.model.MOE_TICK_COUNTERS`, as many
+        of them as the step's layers count).  The pages are the host's
         own bookkeeping, summed over the layers of each kind:
         ``pages_full`` / ``tokens_full`` the live pages and positions
         the full layers read, ``pages_window`` / ``tokens_window``
@@ -1414,6 +1461,20 @@ class ServingEngine:
                     (pages - dead).sum())
                 counts["tokens_window"] += layers * int(
                     np.minimum(lens, window).sum())
+        if self.cache_cfg.latent:
+            # a latent layer's row is read whole by every head:
+            # ``latent_pages`` / ``latent_tokens`` the live pages and
+            # positions, summed over the model's latent layers;
+            # ``experts_slots`` the experts held here, summed over the
+            # MoE layers (what ``experts_hit`` is a share of)
+            lens = seq_lens[:n].astype(np.int64)
+            layers = self.model_cfg.num_layers
+            counts.update(
+                ticks=1, rows=n,
+                latent_pages=layers * int(
+                    (-(-lens // self.cache_cfg.block_size)).sum()),
+                latent_tokens=layers * int(lens.sum()),
+                experts_slots=self._experts_slots)
         if record:
             for key, value in counts.items():
                 self.tick_sums[key] = self.tick_sums.get(key, 0) + value
@@ -1461,12 +1522,16 @@ class ServingEngine:
                 bt[i] = self.manager.block_table(q.rid, pb)
             bt_j = jnp.asarray(bt)
         t0 = self._clock()
-        # --- draft proposals: K sequential single-token steps -------
+        # --- draft proposals: K sequential single-token steps, or the
+        # one token the model's own MTP module proposed when the last
+        # tick (or the prefill) ran it
         d = np.zeros((bb, K), np.int32)
         prev = np.zeros(bb, np.int32)
         for i, q in enumerate(reqs):
             prev[i] = q.out_tokens[-1]
-        for k in range(1, K + 1):
+            if self._mtp:
+                d[i, 0] = q.draft
+        for k in (() if self._mtp else range(1, K + 1)):
             with span("apex.serve.decode.build"):
                 toks = prev if k == 1 else d[:, k - 2]
                 pos = np.zeros(bb, np.int32)
@@ -1530,6 +1595,8 @@ class ServingEngine:
                 if keep == T:
                     full_rows.append(i)
                 q.out_tokens.extend(emit)
+                if self._mtp:      # the draft of the last slot kept
+                    q.draft = int(a[i, T + keep - 1])
                 keeps.append(keep)
                 gained += keep
             dt = self._clock() - t0
@@ -1544,6 +1611,12 @@ class ServingEngine:
                     self._latencies.append(share)
             self.spec_proposed += tick_proposed
             self.spec_accepted += tick_accepted
+            if self._mtp and recording():
+                self._tick_mtp = dict(mtp_proposed=tick_proposed,
+                                      mtp_accepted=tick_accepted)
+                for key, value in self._tick_mtp.items():
+                    self.tick_sums[key] = self.tick_sums.get(key, 0) \
+                        + value
             self.metrics.gauges.on_spec(tick_proposed, tick_accepted)
             if self.spec_governor is not None \
                     and self.spec_governor.observe(tick_proposed,
@@ -1563,7 +1636,8 @@ class ServingEngine:
         # --- draft catch-up: on full acceptance the draft never wrote
         # position base + K (the target's verify did) — one masked
         # draft step fills it so next tick's proposals read real k/v
-        if full_rows:
+        if full_rows and not self._mtp:     # (the module caught up in
+            # the verify program, on the hidden states it ended in)
             with span("apex.serve.decode.build"):
                 toks = np.zeros(bb, np.int32)
                 pos = np.zeros(bb, np.int32)
@@ -2147,8 +2221,12 @@ def default_cache_config(model_cfg: ServingModelConfig,
     """Cache plan from the registered serving flags
     (``APEX_TPU_SERVE_KV_BLOCK`` / ``APEX_TPU_SERVE_KV_DTYPE`` /
     ``APEX_TPU_SERVE_BLOCKS``); explicit arguments override."""
+    mla = model_cfg.mla
     return KVCacheConfig(
-        num_layers=model_cfg.num_layers,
+        # latent attention: one latent row a token and layer, and one
+        # more layer for an MTP module that is served
+        num_layers=model_cfg.num_layers + model_cfg.mtp_layers,
+        value_dim=None if mla is None else mla.kv_rank,
         num_heads=model_cfg.num_kv_heads,
         head_dim=model_cfg.head_dim,
         num_blocks=(num_blocks if num_blocks is not None

@@ -159,7 +159,10 @@ class EPContext:
         if model_cfg.family != "gpt2":
             raise ValueError(
                 f"family {model_cfg.family!r} has no expert-parallel "
-                f"serving forward yet; serve it on one chip")
+                f"serving forward yet; serve it on one chip"
+                + (" (it computes the share of the experts that "
+                   "expert_first and its stacks name, and no exchange)"
+                   if model_cfg.mla is not None else ""))
         if ep < 2:
             raise ValueError(f"ep {ep} must be >= 2 (ep=1 is the "
                              f"single-chip engine, no context needed)")

@@ -14,8 +14,11 @@ Two cleanly separated halves:
 
 * :class:`PagedKVCache` — the DEVICE state: one k and one v block
   array a layer, ``(nb, hk, bs, dk)`` each (tuples of ``num_layers``
-  arrays), plus optional int8 per-row scales ``(nb, h, bs)``.  A
-  pytree, threaded through the jitted prefill/decode steps and
+  arrays), plus optional int8 per-row scales ``(nb, h, bs)``; the
+  **latent** kind (``KVCacheConfig.value_dim``, latent attention) one
+  ``(nb, 1, bs, d_latent)`` array a layer and no v array, a token's row
+  ``[c_kv ; k_rope]`` being its key and, in its first ``value_dim``
+  values, its value.  A pytree, threaded through the jitted prefill/decode steps and
   **donated** every step, every leaf its own buffer (the same carry
   discipline as the scan driver's amp state — the cache is the
   largest buffer in the serving process, double-buffering it halves
@@ -92,6 +95,12 @@ class KVCacheConfig:
     block_size: int
     kv_dtype: str = "model"  # 'model' | 'bf16' | 'int8'
     model_dtype: jnp.dtype = jnp.float32
+    # the LATENT kind (latent attention, MLA): one array a layer and no
+    # v array.  A cached token is one row of ``head_dim`` values shared
+    # by every query head (``num_heads`` is 1), ``[c_kv ; k_rope]``,
+    # whose first ``value_dim`` are also its value: a page is read once
+    # for both.  None: the k and v arrays of the module docstring.
+    value_dim: Optional[int] = None
 
     def __post_init__(self):
         if self.kv_dtype not in _KV_DTYPES:
@@ -102,6 +111,22 @@ class KVCacheConfig:
                              "reserved dump page)")
         if self.block_size < 1:
             raise ValueError("block_size must be >= 1")
+        if self.latent:
+            if self.num_heads != 1 or not 0 < self.value_dim <= self.head_dim:
+                raise ValueError(
+                    f"a latent cache holds one row a token (num_heads 1, "
+                    f"not {self.num_heads}) whose first value_dim "
+                    f"({self.value_dim}) of head_dim ({self.head_dim}) "
+                    f"values are its value")
+            if self.quantized:
+                raise ValueError(
+                    "the latent cache has no int8 storage yet: a latent "
+                    "row is every head's key and value at once, and one "
+                    "scale a row is unproven there")
+
+    @property
+    def latent(self) -> bool:
+        return self.value_dim is not None
 
     @property
     def packed(self) -> bool:
@@ -141,7 +166,7 @@ class KVCacheConfig:
 
     def cache_nbytes(self) -> int:
         per = np.dtype(self.storage_dtype).itemsize
-        n = 2 * int(np.prod(self.kv_shape)) * per
+        n = (1 if self.latent else 2) * int(np.prod(self.kv_shape)) * per
         if self.quantized:
             n += 2 * int(np.prod(self.scale_shape)) * 4
         return self.num_layers * n
@@ -152,13 +177,13 @@ class PagedKVCache(NamedTuple):
     ``num_layers`` arrays a field, one a layer."""
 
     k: Tuple[jnp.ndarray, ...]               # each (nb, hk, bs, dk)
-    v: Tuple[jnp.ndarray, ...]
+    v: Optional[Tuple[jnp.ndarray, ...]]     # None: the latent kind
     k_scale: Optional[Tuple[jnp.ndarray, ...]]  # each (nb, h, bs) fp32
     v_scale: Optional[Tuple[jnp.ndarray, ...]]
 
     def layer(self, i: int):
         """Layer ``i``'s (k, v, k_scale, v_scale) arrays."""
-        return (self.k[i], self.v[i],
+        return (self.k[i], None if self.v is None else self.v[i],
                 None if self.k_scale is None else self.k_scale[i],
                 None if self.v_scale is None else self.v_scale[i])
 
@@ -186,6 +211,8 @@ def init_cache(config: KVCacheConfig) -> PagedKVCache:
                      for _ in range(config.num_layers))
 
     k = leaves(config.kv_shape, config.storage_dtype)
+    if config.latent:
+        return PagedKVCache(k, None, None, None)
     v = leaves(config.kv_shape, config.storage_dtype)
     if config.quantized:
         return PagedKVCache(k, v,
@@ -297,7 +324,8 @@ def write_token_kv(cache: PagedKVCache, config: KVCacheConfig,
     ``k_new``/``v_new`` in model dtype, (b, h, d) for one token a row
     (the decode step) or (b, t, h, d) for a chunk a row (the extend
     step); ``plan`` is :func:`plan_page_write` of the step's write
-    slots.
+    slots.  The latent kind takes its rows ``(..., 1, head_dim)`` as
+    ``k_new`` and ``v_new`` None.
 
     The write is page-granular (module docstring): the touched pages
     are gathered, the new rows selected in, and whole pages scattered
@@ -315,8 +343,12 @@ def write_token_kv(cache: PagedKVCache, config: KVCacheConfig,
     inside the jitted step; the cache argument is donated by the caller
     so the page scatter is in-place on device."""
     if k_new.ndim == 3:
-        k_new, v_new = k_new[:, None], v_new[:, None]
+        k_new = k_new[:, None]
+        v_new = None if v_new is None else v_new[:, None]
     kq, ks = _to_storage(k_new, config)
+    if config.latent:          # the rows ARE the values: no v array
+        return cache.with_layer(
+            layer, _write_pages(cache.k[layer], kq, plan), None)
     vq, vs = _to_storage(v_new, config)
     kc, vc, kc_scale, vc_scale = cache.layer(layer)
     kc = _write_pages(kc, kq, plan)
@@ -334,7 +366,8 @@ def write_prefill_kv(cache: PagedKVCache, config: KVCacheConfig,
     """Scatter a prefilled prompt's whole k/v for one layer into its
     pages.
 
-    ``k_all``/``v_all`` (s_pad, h, d) with ``s_pad = len(blocks) *
+    ``k_all``/``v_all`` (s_pad, h, d) (the latent kind: its rows and
+    None) with ``s_pad = len(blocks) *
     block_size``; ``blocks`` (n_pages,) int32 — pages past the
     request's owned tail point at the dump block (duplicate dump
     writes race harmlessly: the dump page is never read unmasked)."""
@@ -351,6 +384,9 @@ def write_prefill_kv(cache: PagedKVCache, config: KVCacheConfig,
         return q, scale
 
     kq, ks = paged(k_all)
+    if config.latent:
+        return cache.with_layer(layer, cache.k[layer].at[blocks].set(kq),
+                                None)
     vq, vs = paged(v_all)
     kc, vc, kc_scale, vc_scale = cache.layer(layer)
     kc = kc.at[blocks].set(kq)

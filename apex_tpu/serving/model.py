@@ -38,15 +38,18 @@ from ..ops.layer_norm import layer_norm
 from ..ops.quant_matmul import (QuantGPTServingWeights,
                                 QuantLayerWeights, quant_matmul,
                                 quantize_weights)
-from . import rope_moe
+from . import mla_moe, rope_moe
 from .kv_cache import (KVCacheConfig, PagedKVCache, plan_page_write,
                        write_prefill_kv, write_token_kv)
+from .mla_moe import MlaSpec, init_mla_moe_weights
 from .rope_moe import (MOE_TICK_COUNTERS, LayerSpec, RopeMoEWeights,
                        RopeSpec, init_rope_moe_weights)
 
 __all__ = ["GPTServingWeights", "LayerWeights", "MoELayerWeights",
            "ServingModelConfig", "RopeMoEWeights", "LayerSpec",
            "RopeSpec", "init_rope_moe_weights", "MOE_TICK_COUNTERS",
+           "MlaSpec", "init_mla_moe_weights", "mtp_prefill_step",
+           "mtp_extend_step",
            "QuantGPTServingWeights", "QuantLayerWeights",
            "quantize_weights", "extract_serving_weights",
            "gpt_prefill_step", "gpt_decode_step", "gpt_extend_step",
@@ -160,12 +163,27 @@ class ServingModelConfig:
     # SwiGLU MLP that is dense or a dropless top-k MoE beside a shared
     # expert, untied head -- one LayerSpec a layer in ``layers``
     # (query heads differ by layer there; ``num_heads`` is unused)
+    # 'mla_moe' (serving/mla_moe.py): that block with latent attention
+    # (``mla``: its widths; the cache is the latent kind, one row a
+    # token: ``num_kv_heads`` 1, ``head_dim`` the stored row's width),
+    # sandwich norms, and the model's own multi-token-prediction module
     family: str = "gpt2"
     layers: Tuple[LayerSpec, ...] = ()
     experts_per_token: int = 1
     routed_scaling: float = 1.0
+    # the first expert a MoE layer of this chip holds, of the
+    # ``num_experts`` its router scores; how many it holds is the
+    # expert stacks' own leading dimension (0, and all: one chip)
+    expert_first: int = 0
+    mla: Optional[MlaSpec] = None
+    # MTP modules served as the engine's draft (0 or 1): the cache
+    # holds a latent layer for each after the model's own
+    mtp_layers: int = 0
 
     def __post_init__(self):
+        if self.mla is not None:
+            object.__setattr__(self, "head_dim", self.mla.row_dim)
+            object.__setattr__(self, "num_kv_heads", 1)
         if self.head_dim is None:
             if self.hidden_size % self.num_heads:
                 raise ValueError(
@@ -175,18 +193,25 @@ class ServingModelConfig:
                                self.hidden_size // self.num_heads)
         if self.num_kv_heads is None:
             object.__setattr__(self, "num_kv_heads", self.num_heads)
-        if self.family not in ("gpt2", "rope_moe"):
+        if self.family not in ("gpt2", "rope_moe", "mla_moe"):
             raise ValueError(f"family {self.family!r} not in "
-                             f"('gpt2', 'rope_moe')")
-        if self.family == "rope_moe":
+                             f"('gpt2', 'rope_moe', 'mla_moe')")
+        if (self.family == "mla_moe") != (self.mla is not None):
+            raise ValueError("family 'mla_moe', and no other, states "
+                             "its latent attention's widths (mla)")
+        if self.mtp_layers not in (0, 1) or (self.mtp_layers
+                                             and self.mla is None):
+            raise ValueError("mtp_layers is 0, or 1 for family 'mla_moe'")
+        if self.family != "gpt2":
             if len(self.layers) != self.num_layers:
                 raise ValueError(
-                    f"family 'rope_moe' takes one LayerSpec a layer: "
-                    f"{len(self.layers)} given for {self.num_layers}")
+                    f"family {self.family!r} takes one LayerSpec a "
+                    f"layer: {len(self.layers)} given for "
+                    f"{self.num_layers}")
             if self.tp_axis is not None or self.ep_axis is not None:
                 raise ValueError(
-                    "family 'rope_moe' has no tensor- or expert-"
-                    "parallel forward yet")
+                    f"family {self.family!r} has no tensor- or expert-"
+                    f"parallel forward yet")
             for spec in self.layers:
                 if spec.num_heads % self.num_kv_heads:
                     raise ValueError(
@@ -411,12 +436,21 @@ def _layer_tail(x, lw, attn_out, cfg, live=None):
     capacity MoE FFN instead (duck-typed on ``router``).  ``rope_moe``
     (duck-typed on ``norm2``): RMSNorm and the dense or dropless-MoE
     SwiGLU, whose routing counts over the ``live`` rows come back for
-    the decode tick's telemetry."""
+    the decode tick's telemetry.  A layer with sandwich norms
+    (``mla_moe``, duck-typed on ``norm1_post``) norms each branch once
+    more before it joins the residual stream."""
+    sandwich = hasattr(lw, "norm1_post")
+    if sandwich:
+        attn_out = rope_moe.rms_norm(attn_out, lw.norm1_post,
+                                     cfg.layernorm_eps)
     x = x + attn_out.astype(x.dtype)
     if hasattr(lw, "norm2"):
         branch, counters = rope_moe.mlp(
             rope_moe.rms_norm(x, lw.norm2, cfg.layernorm_eps), lw, cfg,
             live)
+        if sandwich:
+            branch = rope_moe.rms_norm(branch, lw.norm2_post,
+                                       cfg.layernorm_eps)
         return x + branch, counters
     m_in = layer_norm(x, lw.ln2_w, lw.ln2_b,
                       cfg.layernorm_eps).astype(cfg.dtype)
@@ -481,6 +515,26 @@ def prefill_logits(weights, cfg, cache_cfg, cache, tokens, length,
                    blocks):
     """:func:`gpt_prefill_step` up to the logits of the last real
     position: ``(cache, (V,) logits)``."""
+    cache, x = _prefill_hidden(weights, cfg, cache_cfg, cache, tokens,
+                               blocks)
+    return cache, _last_logits(x, weights, cfg, length)
+
+
+def _last_logits(x, weights, cfg, length):
+    """The logits of a prefill's last real position, from its final
+    residual stream ``x`` (1, s_pad, H)."""
+    if cfg.family != "gpt2":
+        # the head is vocabulary-wide: run it on the one row that is read
+        return _lm_head(jax.lax.dynamic_index_in_dim(
+            x[0], length - 1, axis=0, keepdims=False), weights, cfg)
+    logits = _lm_head(x, weights, cfg)[0]          # (s_pad, V)
+    return jax.lax.dynamic_index_in_dim(logits, length - 1, axis=0,
+                                        keepdims=False)
+
+
+def _prefill_hidden(weights, cfg, cache_cfg, cache, tokens, blocks):
+    """The prefill's layers: ``(cache, final residual stream (1, s_pad,
+    H))``, before the final norm."""
     from ..ops.flash_attention import flash_attention, mha_reference
 
     s_pad = tokens.shape[0]
@@ -494,26 +548,24 @@ def prefill_logits(weights, cfg, cache_cfg, cache, tokens, length,
     x = _embed(weights, tokens, positions, cfg)
     for i, lw in enumerate(weights.layers):
         spec = _spec(cfg, i)
-        a_in, q, k, v = _attn_inputs(x, lw, cfg, spec, positions, h, d)
-        cache = write_prefill_kv(cache, cache_cfg, i, k[0], v[0],
-                                 blocks)
-        qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        attn = flash_attention if cfg.prefill_flash else mha_reference
-        with _attn_scope(spec):
-            ctx = attn(qt, kt, vt, scale=scale, causal=True,
-                       window=spec.window if spec else None)
-        attn_out = _attn_branch(ctx.transpose(0, 2, 1, 3), a_in, lw, cfg,
-                                spec)
+        if cfg.mla is not None:
+            attn_out, latent = mla_moe.expanded(x, lw, cfg, spec,
+                                                positions)
+            cache = write_prefill_kv(cache, cache_cfg, i,
+                                     latent[0, :, None], None, blocks)
+        else:
+            a_in, q, k, v = _attn_inputs(x, lw, cfg, spec, positions, h, d)
+            cache = write_prefill_kv(cache, cache_cfg, i, k[0], v[0],
+                                     blocks)
+            qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+            attn = flash_attention if cfg.prefill_flash else mha_reference
+            with _attn_scope(spec):
+                ctx = attn(qt, kt, vt, scale=scale, causal=True,
+                           window=spec.window if spec else None)
+            attn_out = _attn_branch(ctx.transpose(0, 2, 1, 3), a_in, lw,
+                                    cfg, spec)
         x, _ = _layer_tail(x, lw, attn_out, cfg)
-    if cfg.family == "rope_moe":
-        # the head is vocabulary-wide: run it on the one row that is read
-        last = _lm_head(jax.lax.dynamic_index_in_dim(
-            x[0], length - 1, axis=0, keepdims=False), weights, cfg)
-    else:
-        logits = _lm_head(x, weights, cfg)[0]          # (s_pad, V)
-        last = jax.lax.dynamic_index_in_dim(logits, length - 1, axis=0,
-                                            keepdims=False)
-    return cache, last
+    return cache, x
 
 
 def gpt_decode_step(weights, cfg: ServingModelConfig,
@@ -567,16 +619,21 @@ def decode_logits(weights, cfg, cache_cfg, cache, tokens, positions,
                             cache_cfg.block_size)
     for i, lw in enumerate(weights.layers):
         spec = _spec(cfg, i)
-        a_in, q, k, v = _attn_inputs(x, lw, cfg, spec, positions, h, d)
-        cache = write_token_kv(cache, cache_cfg, i, k, v, write)
-        kc, vc, ks, vs = cache.layer(i)
-        attn = flash_decode if cfg.decode_attention == "kernel" \
-            else paged_attention_reference
-        with _attn_scope(spec):
-            ctx = attn(q, kc, vc, block_tables, seq_lens, scale=scale,
-                       k_scale=ks, v_scale=vs,
-                       window=spec.window if spec else None)
-        attn_out = _attn_branch(ctx, a_in, lw, cfg, spec)
+        if cfg.mla is not None:
+            cache, attn_out = mla_moe.absorbed(
+                x, lw, cfg, spec, cache_cfg, cache, i, positions, write,
+                block_tables, seq_lens)
+        else:
+            a_in, q, k, v = _attn_inputs(x, lw, cfg, spec, positions, h, d)
+            cache = write_token_kv(cache, cache_cfg, i, k, v, write)
+            kc, vc, ks, vs = cache.layer(i)
+            attn = flash_decode if cfg.decode_attention == "kernel" \
+                else paged_attention_reference
+            with _attn_scope(spec):
+                ctx = attn(q, kc, vc, block_tables, seq_lens, scale=scale,
+                           k_scale=ks, v_scale=vs,
+                           window=spec.window if spec else None)
+            attn_out = _attn_branch(ctx, a_in, lw, cfg, spec)
         x, c = _layer_tail(x, lw, attn_out, cfg, live)
         if c is not None:
             counters = c if counters is None else counters + c
@@ -625,6 +682,16 @@ def extend_logits(weights, cfg, cache_cfg, cache, tokens, block_tables,
                   seq_lens, write_blocks, write_offsets):
     """:func:`gpt_extend_step` up to its logits: ``(cache, (b, t, V)
     logits)``."""
+    cache, x, _ = _extend_hidden(weights, cfg, cache_cfg, cache, tokens,
+                                 block_tables, seq_lens, write_blocks,
+                                 write_offsets)
+    return cache, _lm_head(x, weights, cfg)        # (b, t, V)
+
+
+def _extend_hidden(weights, cfg, cache_cfg, cache, tokens, block_tables,
+                   seq_lens, write_blocks, write_offsets):
+    """The extend step's layers: ``(cache, final residual stream (b, t,
+    H), (positions (b, t), the step's page write))``."""
     h, d = cache_cfg.num_heads, cache_cfg.head_dim   # per-shard heads
     b, t = tokens.shape
     scale = d ** -0.5
@@ -639,18 +706,89 @@ def extend_logits(weights, cfg, cache_cfg, cache, tokens, block_tables,
                             cache_cfg.block_size)
     for i, lw in enumerate(weights.layers):
         spec = _spec(cfg, i)
-        a_in, q, k, v = _attn_inputs(x, lw, cfg, spec, pos, h, d)
-        cache = write_token_kv(cache, cache_cfg, i, k, v, write)
-        kc, vc, ks, vs = cache.layer(i)
-        attn = flash_decode_multi if cfg.decode_attention == "kernel" \
-            else paged_attention_multi_reference
-        with _attn_scope(spec):
-            ctx = attn(q, kc, vc, block_tables, seq_lens, scale=scale,
-                       k_scale=ks, v_scale=vs,
-                       window=spec.window if spec else None)
-        attn_out = _attn_branch(ctx, a_in, lw, cfg, spec)
+        if cfg.mla is not None:
+            cache, attn_out = mla_moe.absorbed(
+                x, lw, cfg, spec, cache_cfg, cache, i, pos, write,
+                block_tables, seq_lens)
+        else:
+            a_in, q, k, v = _attn_inputs(x, lw, cfg, spec, pos, h, d)
+            cache = write_token_kv(cache, cache_cfg, i, k, v, write)
+            kc, vc, ks, vs = cache.layer(i)
+            attn = flash_decode_multi \
+                if cfg.decode_attention == "kernel" \
+                else paged_attention_multi_reference
+            with _attn_scope(spec):
+                ctx = attn(q, kc, vc, block_tables, seq_lens, scale=scale,
+                           k_scale=ks, v_scale=vs,
+                           window=spec.window if spec else None)
+            attn_out = _attn_branch(ctx, a_in, lw, cfg, spec)
         x, _ = _layer_tail(x, lw, attn_out, cfg)
-    return cache, _lm_head(x, weights, cfg)        # (b, t, V)
+    return cache, x, (pos, write)
+
+
+# --- the model's own draft: its multi-token-prediction module ---------------
+
+def _argmax(logits):
+    return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+
+
+def mtp_prefill_step(weights, cfg, cache_cfg, cache, tokens, length,
+                     blocks):
+    """:func:`gpt_prefill_step` and, in the same program, the MTP module
+    over the same positions: ``(cache, [first token, first draft])``.
+
+    The module's input at position ``j`` is the model's final hidden
+    state there and the token AFTER it: the prompt's next token, and at
+    the last real position the token the prefill just chose.  Its latent
+    layer is the cache's last, written through the prompt's own pages;
+    its logits at the last real position are for the token two past it:
+    the draft of the first decode tick."""
+    cache, x = _prefill_hidden(weights, cfg, cache_cfg, cache, tokens,
+                               blocks)
+    first = _argmax(_last_logits(x, weights, cfg, length))
+    eps, spec = cfg.layernorm_eps, cfg.layers[-1]
+    at = jnp.arange(tokens.shape[0], dtype=jnp.int32)
+    nxt = jnp.where(at == length - 1, first, jnp.roll(tokens, -1))
+    with jax.named_scope("apex.mtp"):
+        g = mla_moe.mtp_input(x, nxt[None], weights, eps)
+        lw = weights.mtp.layer
+        attn_out, latent = mla_moe.expanded(g, lw, cfg, spec, at[None])
+        cache = write_prefill_kv(cache, cache_cfg, cfg.num_layers,
+                                 latent[0, :, None], None, blocks)
+        g, _ = _layer_tail(g, lw, attn_out, cfg)
+        draft = _argmax(mla_moe.mtp_logits(jax.lax.dynamic_index_in_dim(
+            g[0], length - 1, axis=0, keepdims=False), weights, eps))
+    return cache, jnp.stack([first, draft])
+
+
+def mtp_extend_step(weights, cfg, cache_cfg, cache, tokens, block_tables,
+                    seq_lens, write_blocks, write_offsets):
+    """:func:`gpt_extend_step` as speculation's verify step, and in the
+    same program the MTP module on the hidden states it ends in:
+    ``(cache, (b, 2t))``, the target's greedy token after each of the
+    ``t`` slots, then the module's draft of the token after THAT.
+
+    Slot ``j``'s hidden state is the model's at its position given the
+    tokens fed up to it, so slot ``j``'s draft stands if the fed tokens
+    up to ``j`` were accepted: the engine takes the draft of the last
+    slot it kept.  The module's latents go to the cache's last layer
+    through the same write slots, and a rejected slot's is overwritten
+    by the next tick's before anything attends to it, as the model's
+    own is."""
+    cache, x, (pos, write) = _extend_hidden(
+        weights, cfg, cache_cfg, cache, tokens, block_tables, seq_lens,
+        write_blocks, write_offsets)
+    chosen = _argmax(_lm_head(x, weights, cfg))          # (b, t)
+    eps = cfg.layernorm_eps
+    with jax.named_scope("apex.mtp"):
+        g = mla_moe.mtp_input(x, chosen, weights, eps)
+        lw = weights.mtp.layer
+        cache, attn_out = mla_moe.absorbed(
+            g, lw, cfg, cfg.layers[-1], cache_cfg, cache, cfg.num_layers,
+            pos, write, block_tables, seq_lens)
+        g, _ = _layer_tail(g, lw, attn_out, cfg)
+        drafts = _argmax(mla_moe.mtp_logits(g, weights, eps))
+    return cache, jnp.concatenate([chosen, drafts], axis=1)
 
 
 def gpt_sequence_logits(weights, cfg: ServingModelConfig,
@@ -672,13 +810,16 @@ def gpt_sequence_logits(weights, cfg: ServingModelConfig,
     x = _embed(weights, tokens, pos, cfg)
     for i, lw in enumerate(weights.layers):
         spec = _spec(cfg, i)
-        a_in, q, k, v = _attn_inputs(x, lw, cfg, spec, pos, h, d)
-        qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        attn = flash_attention if cfg.prefill_flash else mha_reference
-        ctx = attn(qt, kt, vt, scale=scale, causal=True,
-                   window=spec.window if spec else None)
-        attn_out = _attn_branch(ctx.transpose(0, 2, 1, 3), a_in, lw, cfg,
-                                spec)
+        if cfg.mla is not None:
+            attn_out, _ = mla_moe.expanded(x, lw, cfg, spec, pos)
+        else:
+            a_in, q, k, v = _attn_inputs(x, lw, cfg, spec, pos, h, d)
+            qt, kt, vt = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
+            attn = flash_attention if cfg.prefill_flash else mha_reference
+            ctx = attn(qt, kt, vt, scale=scale, causal=True,
+                       window=spec.window if spec else None)
+            attn_out = _attn_branch(ctx.transpose(0, 2, 1, 3), a_in, lw,
+                                    cfg, spec)
         x, _ = _layer_tail(x, lw, attn_out, cfg)
     return _lm_head(x, weights, cfg)
 

@@ -43,8 +43,11 @@ __all__ = ["RopeSpec", "LayerSpec", "RopeMoELayerWeights",
 
 # What a decode step of this family appends to its ``next_tokens``
 # (int32, summed over the MoE layers, live rows only): the distinct
-# experts that received a row, and the most rows one expert received.
-MOE_TICK_COUNTERS = ("experts_hit", "expert_max_rows")
+# experts that received a row, the most rows one expert received and,
+# from a layer that holds a share of its experts alone, the routed
+# (row, expert) pairs that fell on the experts it holds.  Experts are
+# counted where they are held: an expert on another chip is not hit here.
+MOE_TICK_COUNTERS = ("experts_hit", "expert_max_rows", "pairs_held")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,9 +104,10 @@ class RopeMoEWeights(NamedTuple):
     """The whole model as a pytree of plain arrays."""
 
     embed: jnp.ndarray             # (V, H)
-    layers: Tuple[RopeMoELayerWeights, ...]
+    layers: Tuple[NamedTuple, ...]  # this family's layers or mla_moe's
     norm_f: jnp.ndarray            # (H,) fp32
     head: jnp.ndarray              # (H, V), not tied to ``embed``
+    mtp: Optional[NamedTuple] = None   # mla_moe.MtpWeights, where served
 
 
 def init_rope_moe_weights(key, cfg, *, dense_ffn: int, expert_ffn: int,
@@ -261,36 +265,99 @@ def route(m, router, k: int, scaling: float):
     return scaling * top / top.sum(-1, keepdims=True), ids
 
 
-def _experts_sorted(m, lw, weights, ids):
+def _experts_sorted(m, lw, weights, ids, first: int = 0):
     """(row, expert) pairs sorted by expert through grouped matmuls:
-    exactly ``k`` experts' work a row, whatever the routing."""
+    exactly ``k`` experts' work a row, whatever the routing.
+
+    The layer holds the experts ``first .. first + e1.shape[0]`` of the
+    ``num_experts`` that ``ids`` range over (all of them: Laguna; a
+    sixteenth: a chip of an expert-parallel deployment).  Pairs routed
+    to an expert held elsewhere are that chip's work: they sort behind
+    the held ones and are not computed.  The held pairs are taken a
+    static number of rows at a time (:func:`_held_rows`: once, unless
+    the routing is skewed towards this chip), so no pair is ever
+    dropped and an even routing pays for its own share alone."""
     t, k = ids.shape
-    e = lw.e1.shape[0]
+    held = lw.e1.shape[0]
     flat = ids.reshape(t * k)
-    order = jnp.argsort(flat)
-    sizes = jnp.zeros((e,), jnp.int32).at[flat].add(1)
-    xs = m.astype(lw.e1.dtype)[order // k]                 # (t*k, H)
-    gate = jax.lax.ragged_dot(xs, lw.e1, sizes,
-                              preferred_element_type=jnp.float32)
-    up = jax.lax.ragged_dot(xs, lw.e3, sizes,
-                            preferred_element_type=jnp.float32)
-    act = jax.nn.silu(gate) * up * weights.reshape(t * k)[order][:, None]
-    out = jax.lax.ragged_dot(act.astype(lw.e2.dtype), lw.e2, sizes,
-                             preferred_element_type=jnp.float32)
-    return jnp.zeros((t, m.shape[-1]), jnp.float32).at[order // k].add(out)
+    everything = (first, held) == (0, lw.router.shape[-1])
+    if not everything:
+        flat = flat - first
+        flat = jnp.where((flat >= 0) & (flat < held), flat, held)
+    order = jnp.argsort(flat)          # pairs held elsewhere sort last
+    sizes = jnp.zeros((held + (not everything),), jnp.int32) \
+        .at[flat].add(1)[:held]
+
+    def run(order, sizes, valid=None):
+        xs = m.astype(lw.e1.dtype)[order // k]             # (rows, H)
+        gate = jax.lax.ragged_dot(xs, lw.e1, sizes,
+                                  preferred_element_type=jnp.float32)
+        up = jax.lax.ragged_dot(xs, lw.e3, sizes,
+                                preferred_element_type=jnp.float32)
+        act = jax.nn.silu(gate) * up \
+            * weights.reshape(t * k)[order][:, None]
+        out = jax.lax.ragged_dot(act.astype(lw.e2.dtype), lw.e2, sizes,
+                                 preferred_element_type=jnp.float32)
+        if valid is not None:      # rows past the groups hold no result
+            out = jnp.where(valid[:, None], out, 0.0)
+        return jnp.zeros((t, m.shape[-1]), jnp.float32) \
+            .at[order // k].add(out)
+
+    if everything:
+        return run(order, sizes)
+    rows = _held_rows(t * k, held, lw.router.shape[-1])
+    ends = jnp.cumsum(sizes)
+    order = jnp.pad(order, (0, -(t * k) % rows))
+
+    def some(i, total):
+        lo = i * rows
+        at = lo + jnp.arange(rows, dtype=jnp.int32)
+        return total + run(
+            jax.lax.dynamic_slice_in_dim(order, lo, rows),
+            jnp.clip(ends, lo, lo + rows)
+            - jnp.clip(ends - sizes, lo, lo + rows), at < ends[-1])
+
+    return jax.lax.fori_loop(
+        0, (ends[-1] + rows - 1) // rows, some,
+        jnp.zeros((t, m.shape[-1]), jnp.float32))
 
 
-def moe_counters(ids, live, num_experts: int):
-    """:data:`MOE_TICK_COUNTERS` of one layer as an int32 pair, over
-    the rows where ``live`` (T,) holds."""
-    rows = jnp.zeros((num_experts,), jnp.int32).at[ids.reshape(-1)].add(
-        jnp.repeat(live.astype(jnp.int32), ids.shape[1]))
-    return jnp.stack([(rows > 0).sum().astype(jnp.int32), rows.max()])
+def _held_rows(pairs: int, held: int, num_experts: int) -> int:
+    """The sorted pairs a layer that holds ``held`` of ``num_experts``
+    pushes through its grouped matmuls at a time: twice its even share
+    of ``pairs``, in whole sublane tiles.  The grouped matmul's time
+    grows with the rows it is given, live or not (a 2,048-token prefill
+    on a chip that holds a sixteenth: 1,024 pairs expected, standard
+    deviation 31), so the bound is kept near the share and the rare
+    step that passes it takes a second go."""
+    return min(pairs, -(-2 * pairs * held // num_experts // 8) * 8)
 
 
-def mlp(m, lw: RopeMoELayerWeights, cfg, live=None):
+def moe_counters(ids, live, num_experts: int, first: int = 0,
+                 held: Optional[int] = None):
+    """:data:`MOE_TICK_COUNTERS` of one layer as int32s, over the rows
+    where ``live`` (T,) holds: two where the layer holds every expert,
+    the pairs that fell on its own as a third where it holds ``held``
+    from ``first``."""
+    if held is None or (first, held) == (0, num_experts):
+        rows = jnp.zeros((num_experts,), jnp.int32).at[
+            ids.reshape(-1)].add(
+                jnp.repeat(live.astype(jnp.int32), ids.shape[1]))
+        return jnp.stack([(rows > 0).sum().astype(jnp.int32), rows.max()])
+    local = ids.reshape(-1) - first
+    local = jnp.where((local >= 0) & (local < held), local, held)
+    rows = jnp.zeros((held + 1,), jnp.int32).at[local].add(
+        jnp.repeat(live.astype(jnp.int32), ids.shape[1]))[:held]
+    return jnp.stack([(rows > 0).sum().astype(jnp.int32), rows.max(),
+                      rows.sum()])
+
+
+def mlp(m, lw, cfg, live=None):
     """The block's MLP branch on normed input (..., H) float32:
-    ``(branch (..., H) float32, tick counters or None)``."""
+    ``(branch (..., H) float32, tick counters or None)``.  A MoE layer
+    routes over every expert the router knows and computes the part of
+    those it holds (``cfg.expert_first`` on, as many as ``lw.e1`` has);
+    the shared expert is whole on every chip."""
     if lw.router is None:
         return _swiglu(m, lw.w1, lw.w3, lw.w2), None
     lead, hidden = m.shape[:-1], m.shape[-1]
@@ -299,9 +366,10 @@ def mlp(m, lw: RopeMoELayerWeights, cfg, live=None):
         weights, ids = route(m2, lw.router, cfg.experts_per_token,
                              cfg.routed_scaling)
         counters = None if live is None else moe_counters(
-            ids, live.reshape(-1), lw.router.shape[-1])
+            ids, live.reshape(-1), lw.router.shape[-1], cfg.expert_first,
+            lw.e1.shape[0])
     with jax.named_scope("apex.moe.experts"):
-        routed = _experts_sorted(m2, lw, weights, ids)
+        routed = _experts_sorted(m2, lw, weights, ids, cfg.expert_first)
     with jax.named_scope("apex.moe.shared"):
         shared = _swiglu(m2, lw.s1, lw.s3, lw.s2)
     return (routed + shared).reshape(*lead, hidden), counters

@@ -154,7 +154,10 @@ class TPContext:
         if model_cfg.family != "gpt2":
             raise ValueError(
                 f"family {model_cfg.family!r} has no tensor-parallel "
-                f"serving forward yet; serve it on one chip")
+                f"serving forward yet; serve it on one chip"
+                + (" (a latent cache has no head axis to split: the "
+                   "absorbed projections would be)"
+                   if model_cfg.mla is not None else ""))
         if tp < 2:
             raise ValueError(f"tp {tp} must be >= 2 (tp=1 is the "
                              f"single-chip engine, no context needed)")

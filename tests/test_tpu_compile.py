@@ -21,7 +21,8 @@ from jax.sharding import SingleDeviceSharding
 
 from apex_tpu import serving
 from apex_tpu.ops import (flash_attention as fa, flash_decode as fd,
-                          fused_pipeline, layer_norm as ln, moe_routing,
+                          fused_pipeline, latent_decode as ld,
+                          layer_norm as ln, moe_routing,
                           quant_matmul as qm, scaled_softmax)
 from apex_tpu.serving import model as serving_model
 
@@ -66,7 +67,7 @@ def mosaic(monkeypatch):
     """Steer every kernel module off interpret mode.  flash_decode,
     quant_matmul and moe_routing import ``_interpret`` by value, so each
     module's own name is patched."""
-    for mod in (fa, fd, ln, scaled_softmax, qm, moe_routing,
+    for mod in (fa, fd, ld, ln, scaled_softmax, qm, moe_routing,
                 fused_pipeline.fused_optim):
         monkeypatch.setattr(mod, "_interpret", lambda: False)
 
@@ -206,6 +207,29 @@ def _grouped_prefill_args(heads, s):
             ((1, GQ_KV, s, GQ_D), BF16))
 
 
+# the mla_moe cell (openpangu-ultra-moe): a latent cache of 576-wide rows
+# (stored at 640, whole lane tiles) in a pool of 24,577 blocks of 16, read by 128 absorbed query heads;
+# batch rung 64 on the 288-page rung, the 2-token verify step, and the
+# expanded prefill at QK width 192, V width 128, rungs to 4,096
+LAT_BLOCKS, LAT_H, LAT_D, LAT_V, LAT_B = 24577, 128, 640, 512, 64
+_LAT_CACHE = ((LAT_BLOCKS, 1, KV_BLOCK, LAT_D), BF16)
+
+
+def _latent_decode(q, cache, bt, sl):
+    attn = ld.latent_decode if q.ndim == 3 else ld.latent_decode_multi
+    return attn(q, cache, bt, sl, value_dim=LAT_V, scale=192 ** -0.5)
+
+
+def _latent_args(pages, t=None):
+    q = (LAT_B, LAT_H, LAT_D) if t is None else (LAT_B, t, LAT_H, LAT_D)
+    return ((q, BF16), _LAT_CACHE, ((LAT_B, pages), I32), ((LAT_B,), I32))
+
+
+def _mla_prefill_args(s):
+    return (((1, LAT_H, s, 192), BF16), ((1, LAT_H, s, 192), BF16),
+            ((1, LAT_H, s, 128), BF16))
+
+
 def _qmm_args(m, n):
     return (((m, HID), BF16), ((HID, n), I8), ((n,), F32))
 
@@ -280,6 +304,15 @@ CASES = {
         _grouped_prefill(GQ_WINDOW), _grouped_prefill_args(64, 8704)),
     "flash_fwd_grouped_window_s1024": (
         _grouped_prefill(GQ_WINDOW), _grouped_prefill_args(64, 1024)),
+    # latent attention (serving's third family): the paged latent
+    # kernel, eight pages a step, and the flash forward with a value
+    # width of its own
+    "latent_decode_b64_p288": (_latent_decode, _latent_args(288)),
+    "latent_decode_b64_p64": (_latent_decode, _latent_args(64)),
+    "latent_decode_multi_t2_b64_p288": (_latent_decode,
+                                        _latent_args(288, t=2)),
+    "flash_fwd_qk192_v128_s4096": (_flash, _mla_prefill_args(4096)),
+    "flash_fwd_qk192_v128_s1024": (_flash, _mla_prefill_args(1024)),
     # off the 345M path (MoE routing; optimizer sweeps, off by default)
     "moe_route_dispatch": (_moe_route, (((S, HID), BF16),   # one prompt
                                         ((S, 8), F32))),    # 8 experts
@@ -361,9 +394,13 @@ _OVER_OPERAND_0 = '"aliasing_operands":{"lists":[{"indices":["0",'
 
 def _holds_leaf(result_type: str, leaf_shape) -> bool:
     """Whether an HLO result type has an array of a cache leaf's shape,
-    alone or stacked (``[nb,hk,bs,dk]``, ``[1,nb,hk,bs,dk]``, ...)."""
-    tail = ",".join(map(str, leaf_shape))
-    return any(dims == tail or dims.endswith("," + tail)
+    alone or stacked (``[nb,hk,bs,dk]``, ``[1,nb,hk,bs,dk]``, ...; a
+    latent leaf ``[nb,1,bs,d]`` also as ``[nb,bs,d]``)."""
+    def solid(dims):      # the compiler drops and adds axes of 1 freely
+        return [d for d in map(int, filter(None, dims)) if d != 1]
+
+    tail = solid(map(str, leaf_shape))
+    return any(solid(dims.split(","))[-len(tail):] == tail
                for dims in re.findall(r"\w+\[([\d,]*)\]", result_type))
 
 
@@ -434,6 +471,26 @@ def _laguna_two_layers():
     return cfg, weights
 
 
+def _openpangu_two_layers():
+    """The ``mla_moe`` family at openPangu-Ultra-MoE's widths, the dense
+    layer and a layer that holds 16 of 256 experts, an eighth of the
+    vocabulary."""
+    rope = serving.RopeSpec(theta=25.6e6, rotary_dim=64)
+    cfg = serving.ServingModelConfig(
+        vocab_size=19200, hidden_size=7680, num_heads=LAT_H, num_layers=2,
+        max_seq=4608, dtype=BF16, num_experts=256, family="mla_moe",
+        layers=tuple(serving.LayerSpec(num_heads=LAT_H, window=None,
+                                       rope=rope, moe=moe)
+                     for moe in (False, True)),
+        experts_per_token=8, routed_scaling=2.5,
+        mla=serving.MlaSpec(q_rank=1536, kv_rank=LAT_V, nope_dim=128,
+                            rope_dim=64, v_dim=128))
+    weights = jax.eval_shape(lambda: serving.init_mla_moe_weights(
+        jax.random.PRNGKey(0), cfg, dense_ffn=18432, expert_ffn=2048,
+        shared_ffn=2048, experts_held=16))
+    return cfg, weights
+
+
 # name -> (model, pool blocks, step, batch rung, page rung, chunk)
 PROGRAMS = {
     "gpt2_decode_b16_p64": (_gpt2_345m, CELL_BLOCKS, "decode", 16, 64, 0),
@@ -445,6 +502,10 @@ PROGRAMS = {
                                32, 544, 0),
     "laguna_extend_b8_t8_p544": (_laguna_two_layers, GQ_BLOCKS, "extend",
                                  8, 544, 8),
+    "openpangu_decode_b64_p288": (_openpangu_two_layers, LAT_BLOCKS,
+                                  "decode", LAT_B, 288, 0),
+    "openpangu_extend_b64_t2_p288": (_openpangu_two_layers, LAT_BLOCKS,
+                                     "extend", LAT_B, 288, 2),
 }
 
 
@@ -480,11 +541,12 @@ def test_serving_step_keeps_cache_layout(name, one_chip, mosaic,
                                          no_persistent_cache):
     compiled, ccfg, n_weights = compile_serving_step(name, one_chip)
     text = compiled.as_text()
-    leaves = 2 * ccfg.num_layers
+    leaves = (1 if ccfg.latent else 2) * ccfg.num_layers
     ops = cache_sized_ops(text, ccfg.kv_shape)
     moved = [op for op in ops if not op[2]]
     assert not moved, f"cache-sized ops that are not in place: {moved}"
-    # a layer's k and v are each written once, where they lie
+    # a layer's k and v (or its latents) are each written once, where
+    # they lie
     assert len(ops) == leaves, ops
     header = text[:text.index("\n")]
     aliased = {int(p) for p in re.findall(
