@@ -55,7 +55,8 @@ SCHEMA_VERSION = 1
 #:               ``request_rejected`` / ``request_admitted`` /
 #:               ``request_first_token`` / ``request_done``,
 #:               ``decode_step``, ``serve_compile``, ``serve_preempt``,
-#:               ``serve_done``, ``engine_snapshot``; resilience:
+#:               ``serve_done``, ``engine_snapshot``, ``weights_held``
+#:               / ``weights_swapped``; resilience:
 #:               ``deadline_exceeded``, ``request_shed``,
 #:               ``request_replayed``, ``journal_replay``,
 #:               ``crash_reset``, ``alloc_rejected``,

@@ -76,7 +76,8 @@ from .model import (MOE_TICK_COUNTERS, GPTServingWeights, LayerSpec,
                     extract_serving_weights, gather_cache_blocks,
                     gpt_decode_step, gpt_extend_step,
                     gpt_prefill_step, gpt_sequence_logits,
-                    quantize_weights, scatter_cache_blocks)
+                    quantize_weights, scatter_cache_blocks,
+                    weights_in_compute_dtype)
 from .resilience import (RequestJournal, ServeRunResult, ShedPolicy,
                          SpeculationGovernor, recover_engine,
                          run_serving)
@@ -103,7 +104,7 @@ __all__ = [
     "copy_cache_block", "extract_serving_weights",
     "gather_cache_blocks", "gpt_decode_step", "gpt_extend_step",
     "gpt_prefill_step", "gpt_sequence_logits", "quantize_weights",
-    "scatter_cache_blocks",
+    "scatter_cache_blocks", "weights_in_compute_dtype",
     "EngineGauges", "ReplicaMonitor", "RequestTrace", "ServeMetrics",
     "SLObjective", "SLOTracker", "SnapshotTrigger",
     "RequestJournal", "ServeRunResult", "ShedPolicy",

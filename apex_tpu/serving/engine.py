@@ -59,7 +59,8 @@ from .model import (MOE_TICK_COUNTERS, GPTServingWeights,
                     ServingModelConfig,
                     copy_cache_block, gpt_decode_step,
                     gpt_extend_step, gpt_prefill_step,
-                    mtp_extend_step, mtp_prefill_step)
+                    mtp_extend_step, mtp_prefill_step,
+                    weights_in_compute_dtype)
 from .resilience import RequestJournal, ShedPolicy, SpeculationGovernor
 
 logger = get_logger(__name__)
@@ -292,7 +293,15 @@ class ServingEngine:
     """Continuous-batching driver over one model + one paged cache.
 
     ``weights``/``model_cfg`` come from :mod:`.model`;
-    ``cache_cfg`` sizes the pool.  ``monitor`` is an optional
+    ``cache_cfg`` sizes the pool.  The engine holds the weights in the
+    dtype its programs read them in
+    (:func:`~.model.weights_in_compute_dtype`, applied once to every
+    tree it takes: here, in :meth:`swap_weights`, and to the draft's):
+    a float32 GPT tree under a bf16 policy is cast leaf by leaf, a tree
+    already in that dtype is held as the arrays given.  The caller's
+    tree is not touched and may be dropped; the ``weights_held`` event
+    and ``router_snapshot()["weight_bytes"]`` say what is held.
+    ``monitor`` is an optional
     :class:`apex_tpu.monitor.StepMonitor` (or anything with its
     ``event`` method) receiving ``serving`` events; ``autoresume`` an
     installed :class:`apex_tpu.resilience.AutoResume` polled between
@@ -355,6 +364,11 @@ class ServingEngine:
 
             if not isinstance(monitor, ReplicaMonitor):
                 monitor = ReplicaMonitor(monitor, self.replica_id)
+        # the engine OWNS its weights in the dtype the steps read them
+        # in (model.weights_in_compute_dtype: cast once here, not by
+        # every program); what that did, by tree, for ``weights_held``
+        self._held: Dict[str, Dict[str, int]] = {}
+        weights, self._held["target"] = _held_weights(weights, model_cfg)
         if tp is not None:
             if speculate_k or draft_weights is not None:
                 raise ValueError(
@@ -477,13 +491,14 @@ class ServingEngine:
                 "narrower GPT — or serve a model that brings its own "
                 "(family 'mla_moe' with mtp_layers=1: its "
                 "multi-token-prediction module)")
-        self.draft_weights = draft_weights
         self.draft_cfg = draft_cfg
         self.draft_cache_cfg: Optional[KVCacheConfig] = None
         self.draft_cache = None
         if draft_weights is not None:
             if draft_cfg is None:
                 raise ValueError("draft_weights without draft_cfg")
+            draft_weights, self._held["draft"] = _held_weights(
+                draft_weights, draft_cfg)
             # the draft rides the SAME block pool geometry as the
             # target (same block ids, same tables, one manager), so
             # a (block, offset) slot means the same page in both
@@ -498,10 +513,10 @@ class ServingEngine:
                 model_dtype=draft_cfg.dtype)
             self.draft_cache = init_cache(self.draft_cache_cfg)
             if device is not None:
-                self.draft_weights = jax.device_put(draft_weights,
-                                                    device)
+                draft_weights = jax.device_put(draft_weights, device)
                 self.draft_cache = jax.device_put(self.draft_cache,
                                                   device)
+        self.draft_weights = draft_weights
         # degraded mode for the fast path: sustained verify mismatch
         # auto-disables speculation (alarm + gauge, never a crash)
         if spec_governor == "auto":
@@ -577,6 +592,7 @@ class ServingEngine:
         windows = [s.window for s in model_cfg.layers]
         self._layer_windows = {w: windows.count(w) for w in set(windows)} \
             if any(windows) else {}
+        self._emit_weights_held()
 
     # --- events -------------------------------------------------------
 
@@ -584,6 +600,15 @@ class ServingEngine:
         if self.monitor is not None:
             self.monitor.event("serving", name, value=value,
                                step=self.steps, **attrs)
+
+    # --- the weights the programs read --------------------------------
+
+    def _emit_weights_held(self) -> None:
+        """One ``weights_held`` event a tree the engine holds: static
+        per taking (construction, each swap), so a fact and not a
+        rate."""
+        for tree, stats in self._held.items():
+            self._event("weights_held", tree=tree, **stats)
 
     # --- compiled-program cache ---------------------------------------
 
@@ -1939,6 +1964,10 @@ class ServingEngine:
             "tokens_generated": self._done_tokens
             + sum(len(q.out_tokens) for q in self.active.values()),
             "compiles": sum(self._compiles.values()),
+            # the trees the replica holds (target + draft), as held:
+            # GPT weights in the compute dtype whatever was handed over
+            "weight_bytes": sum(s["bytes_held"]
+                                for s in self._held.values()),
         }
         return snap
 
@@ -1951,10 +1980,14 @@ class ServingEngine:
         first.  Weights are ARGUMENTS of the compiled programs, not
         closures, so every AOT-compiled ladder bucket survives the
         swap untouched — zero recompiles, which the sanitized CI swap
-        leg asserts.  The KV pool and the shared-prefix index reset
-        (every cached k/v row was computed under the OLD weights;
-        serving it would silently mix models), so the first
-        post-swap admissions run cold by design.
+        leg asserts.  The incoming tree is held like the first
+        (:func:`~.model.weights_in_compute_dtype`) BEFORE its leaves
+        are compared with the serving arrays, so a freshly trained
+        float32 tree swaps into an engine that holds bf16.  The KV
+        pool and the shared-prefix index reset (every cached k/v row
+        was computed under the OLD weights; serving it would silently
+        mix models), so the first post-swap admissions run cold by
+        design.
 
         A **requantization swap** (bf16 ``GPTServingWeights`` ↔ int8
         :class:`~apex_tpu.ops.quant_matmul.QuantGPTServingWeights`)
@@ -1970,6 +2003,7 @@ class ServingEngine:
                 f"active, {len(self.prefilling)} prefilling, "
                 f"{len(self.queue)} queued) — drain first (the "
                 f"router's admit-stop → drain → swap sequence)")
+        weights, held = _held_weights(weights, self.model_cfg)
         requantized = (jax.tree_util.tree_structure(self.weights)
                        != jax.tree_util.tree_structure(weights))
         if not requantized:
@@ -2007,10 +2041,13 @@ class ServingEngine:
         elif self.device is not None:
             weights = jax.device_put(weights, self.device)
         self.weights = weights
+        self._held["target"] = held
         if draft_weights is not None:
             if self.draft_weights is None:
                 raise ValueError("draft_weights swap on an engine "
                                  "built without a draft")
+            draft_weights, self._held["draft"] = _held_weights(
+                draft_weights, self.draft_cfg)
             if self.device is not None:
                 draft_weights = jax.device_put(draft_weights,
                                                self.device)
@@ -2029,6 +2066,7 @@ class ServingEngine:
             self.warmup()
         self._event("weights_swapped", requantized=requantized,
                     compiles=sum(self._compiles.values()))
+        self._emit_weights_held()
 
     def snapshot_state(self) -> Dict[str, Any]:
         """Live engine state as one JSON-able dict — what the
@@ -2196,6 +2234,19 @@ class ServingEngine:
                             if self.slo is not None else 0),
             slo_burning=(list(self.slo.burning)
                          if self.slo is not None else []))
+
+
+def _held_weights(weights, cfg: ServingModelConfig):
+    """``weights`` as an engine holds them, before sharding or
+    placement (:func:`~.model.weights_in_compute_dtype`), and what that
+    did: the attributes of the ``weights_held`` event."""
+    held = weights_in_compute_dtype(weights, cfg)
+    pairs = list(zip(jax.tree.leaves(weights), jax.tree.leaves(held)))
+    return held, dict(
+        leaves=len(pairs),
+        leaves_cast=sum(h is not g for g, h in pairs),
+        bytes_given=sum(g.size * g.dtype.itemsize for g, _ in pairs),
+        bytes_held=sum(h.size * h.dtype.itemsize for _, h in pairs))
 
 
 def _check_swap_leaf(old, new) -> None:

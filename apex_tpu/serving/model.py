@@ -52,6 +52,7 @@ __all__ = ["GPTServingWeights", "LayerWeights", "MoELayerWeights",
            "mtp_extend_step",
            "QuantGPTServingWeights", "QuantLayerWeights",
            "quantize_weights", "extract_serving_weights",
+           "weights_in_compute_dtype",
            "gpt_prefill_step", "gpt_decode_step", "gpt_extend_step",
            "prefill_logits", "decode_logits", "extend_logits",
            "gpt_sequence_logits", "copy_cache_block",
@@ -255,8 +256,13 @@ def extract_serving_weights(params,
                             num_layers: int) -> GPTServingWeights:
     """Flatten a ``GPTModel`` param tree (as returned by ``init`` /
     held by the train loop) into :class:`GPTServingWeights`.  Arrays
-    are referenced, not copied — a freshly trained tree serves
-    without a round-trip through a checkpoint."""
+    are referenced, not copied, in the dtype the tree has them — a
+    freshly trained tree serves without a round-trip through a
+    checkpoint.  :class:`~.engine.ServingEngine` makes its own tree of
+    these (:func:`weights_in_compute_dtype`: float32 masters under a
+    bf16 policy are cast once, when it takes them), so the caller may
+    drop this one; a step function handed it directly casts each leaf
+    where it reads it."""
     p = _unbox(params)
     emb = p["embedding"]
     tr = p["transformer"]
@@ -284,6 +290,55 @@ def extract_serving_weights(params,
         layers=tuple(layers),
         lnf_w=tr["final_layernorm"]["weight"],
         lnf_b=tr["final_layernorm"]["bias"])
+
+
+# The leaves that EVERY use below reads in ``cfg.dtype``: the operands of
+# ``_matmul`` (float kernels), the biases ``_linear`` / ``_row_linear``
+# add, the expert stacks of ``_moe_mlp`` and the two tables of ``_embed``
+# (``wte`` is the tied head of ``_lm_head`` too).  The rest is read as it
+# is given: LayerNorm's scale and shift (``layer_norm`` takes its
+# statistics and its affine in float32), ``router`` (float32 by design),
+# and Q8's int8 kernels beside their float32 scale rows.
+_LAYER_COMPUTE_LEAVES = ("qkv_k", "qkv_b", "dense_k", "dense_b")
+_COMPUTE_LEAVES = {
+    GPTServingWeights: ("wte", "wpe"),
+    QuantGPTServingWeights: ("wte", "wpe"),
+    LayerWeights: _LAYER_COMPUTE_LEAVES + ("fc1_k", "fc1_b", "fc2_k",
+                                           "fc2_b"),
+    MoELayerWeights: _LAYER_COMPUTE_LEAVES + ("wi", "wo"),
+    QuantLayerWeights: ("qkv_b", "dense_b", "fc1_b", "fc2_b"),
+}
+
+
+def weights_in_compute_dtype(weights, cfg: ServingModelConfig):
+    """``weights`` as the steps read them: each leaf of
+    ``_COMPUTE_LEAVES`` cast to ``cfg.dtype`` ONCE, so a program that
+    takes the tree as arguments finds its matmul operands made (an
+    argument cannot be constant-folded: a float32 tree under a bf16
+    policy is read whole, and cast, by every decode tick and every
+    prefill).  The in-step casts stay and lower to nothing on such a
+    tree; the values are the ones they would have made.
+
+    A leaf that has the dtype already is returned ITSELF, and a tree of
+    a type not listed (``RopeMoEWeights``: those families are made in
+    the compute dtype, float32 where a leaf is read in float32) comes
+    back as the object it is, as does a GPT tree with nothing to cast
+    (a float32 policy, a tree held before): nothing is copied, which
+    matters at 8-10 GB of weights on a 16 GB chip.  The caller's tree
+    is not touched."""
+    dtype = jnp.dtype(cfg.dtype)
+
+    def cast(node):
+        changed = {n: getattr(node, n).astype(dtype)
+                   for n in _COMPUTE_LEAVES.get(type(node), ())
+                   if getattr(node, n).dtype != dtype}
+        return node._replace(**changed) if changed else node
+
+    held = cast(weights)
+    layers = tuple(cast(lw) for lw in weights.layers)
+    if any(h is not lw for h, lw in zip(layers, weights.layers)):
+        held = held._replace(layers=layers)
+    return held
 
 
 def _matmul(x, kernel, dtype, scale):

@@ -430,25 +430,35 @@ def _on(tree, sharding):
                                        sharding=sharding), tree)
 
 
-def _gpt2_345m():
-    """GPT-2 345M as the serving cells run it, its weights in the
-    compute dtype: float32 masters add a cast of the embedding table
-    (103 MB of temporaries a step) that is not the cache's."""
-    cfg = serving.ServingModelConfig(
-        vocab_size=VOCAB, hidden_size=HID, num_heads=H, num_layers=24,
-        max_seq=S, dtype=BF16)
+GPT2_CFG = serving.ServingModelConfig(
+    vocab_size=VOCAB, hidden_size=HID, num_heads=H, num_layers=24,
+    max_seq=S, dtype=BF16)
 
+
+def _gpt2_345m_float32():
+    """GPT-2 345M's serving weights as ``extract_serving_weights`` hands
+    them over: the trained tree's float32 arrays."""
     def w(*shape):
-        return jax.ShapeDtypeStruct(shape, BF16)
+        return jax.ShapeDtypeStruct(shape, F32)
 
     layer = serving_model.LayerWeights(
         ln1_w=w(HID), ln1_b=w(HID), qkv_k=w(HID, 3 * HID),
         qkv_b=w(3 * HID), dense_k=w(HID, HID), dense_b=w(HID),
         ln2_w=w(HID), ln2_b=w(HID), fc1_k=w(HID, 4 * HID),
         fc1_b=w(4 * HID), fc2_k=w(4 * HID, HID), fc2_b=w(HID))
-    return cfg, serving.GPTServingWeights(
+    return GPT2_CFG, serving.GPTServingWeights(
         wte=w(VOCAB, HID), wpe=w(S, HID), layers=(layer,) * 24,
         lnf_w=w(HID), lnf_b=w(HID))
+
+
+def _gpt2_345m():
+    """GPT-2 345M as the serving cells run it: the float32 tree as the
+    engine holds it (``weights_in_compute_dtype``, what ``ServingEngine``
+    makes of the tree it is given): matrices, biases and the two tables
+    in bf16, LayerNorm's vectors in float32."""
+    cfg, given = _gpt2_345m_float32()
+    return cfg, jax.eval_shape(
+        lambda w: serving_model.weights_in_compute_dtype(w, cfg), given)
 
 
 def _laguna_two_layers():
@@ -491,8 +501,11 @@ def _openpangu_two_layers():
     return cfg, weights
 
 
-# name -> (model, pool blocks, step, batch rung, page rung, chunk)
+# name -> (model, pool blocks, step, batch rung, page rung, chunk; a
+# prefill's chunk is its prompt rung)
 PROGRAMS = {
+    "gpt2_decode_b4_p64": (_gpt2_345m, CELL_BLOCKS, "decode", 4, 64, 0),
+    "gpt2_prefill_256": (_gpt2_345m, CELL_BLOCKS, "prefill", 1, 16, 256),
     "gpt2_decode_b16_p64": (_gpt2_345m, CELL_BLOCKS, "decode", 16, 64, 0),
     "gpt2_decode_b32_p64": (_gpt2_345m, CELL_BLOCKS, "decode", 32, 64, 0),
     "gpt2_extend_b8_t8_p64": (_gpt2_345m, CELL_BLOCKS, "extend", 8, 64, 8),
@@ -509,12 +522,13 @@ PROGRAMS = {
 }
 
 
-def compile_serving_step(name, sharding):
+def compile_serving_step(name, sharding, make=None):
     """``PROGRAMS[name]`` compiled for the chip ``sharding`` describes,
     the cache donated: ``(compiled, cache config, number of weight
-    leaves)``; the cache's leaves are the parameters after those."""
-    make, blocks, step, bb, pb, t = PROGRAMS[name]
-    cfg, weights = make()
+    leaves)``; the cache's leaves are the parameters after those.
+    ``make`` stands another model in for the program's own."""
+    own, blocks, step, bb, pb, t = PROGRAMS[name]
+    cfg, weights = (make or own)()
     ccfg = serving.default_cache_config(cfg, num_blocks=blocks,
                                         block_size=KV_BLOCK,
                                         kv_dtype="bf16")
@@ -526,6 +540,9 @@ def compile_serving_step(name, sharding):
     if step == "decode":
         fn, data = serving_model.gpt_decode_step, (
             ints(bb), ints(bb), ints(bb, pb), ints(bb), ints(bb), ints(bb))
+    elif step == "prefill":
+        fn, data = serving_model.gpt_prefill_step, (
+            ints(t), ints(), ints(t // KV_BLOCK))
     else:
         fn, data = serving_model.gpt_extend_step, (
             ints(bb, t), ints(bb, pb), ints(bb), ints(bb, t), ints(bb, t))
@@ -554,3 +571,69 @@ def test_serving_step_keeps_cache_layout(name, one_chip, mosaic,
     assert aliased == set(range(n_weights, n_weights + leaves)), header
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < TEMP_LIMIT, f"{temp / 2**20:.0f} MiB of temporaries"
+
+
+_DEFINITION = re.compile(r"^\s*(?:ROOT )?(%[\w.-]+) = (\w+)\[([\d,]*)\]")
+_CONVERT = re.compile(r" convert\((%[\w.-]+)\)")
+
+
+def _dims(text: str):
+    return tuple(int(d) for d in text.split(",") if d)
+
+
+def float32_weight_reads(hlo_text: str, weights):
+    """What a program that is handed float32 matrices shows: ``(the
+    float32 parameters of its entry, the ``convert`` ops whose operand
+    is float32 of a weight matrix's shape)``, each as a list of shapes.
+    ``weights`` gives the matrices' shapes; an activation has a batch or
+    prompt rung in front and none of them."""
+    matrices = {tuple(leaf.shape) for leaf in jax.tree.leaves(weights)
+                if len(leaf.shape) > 1}
+    lines = hlo_text.splitlines()
+    header = next(line for line in lines if line.startswith("ENTRY "))
+    params = [_dims(d) for d in re.findall(r": f32\[([\d,]*)\]",
+                                           header.split(") -> ")[0])]
+    types = {m[1]: (m[2], _dims(m[3]))
+             for m in map(_DEFINITION.match, lines) if m}
+    converts = []
+    for m in filter(None, map(_CONVERT.search, lines)):
+        dtype, dims = types.get(m[1], (None, ()))
+        if dtype == "f32" and (dims in matrices or dims[::-1] in matrices):
+            converts.append(dims)
+    return params, converts
+
+
+GPT2_CELL_PROGRAMS = ["gpt2_decode_b4_p64", "gpt2_prefill_256"]
+
+
+@pytest.mark.parametrize("name", GPT2_CELL_PROGRAMS)
+def test_held_gpt2_weights_are_read_as_they_lie(name, one_chip, mosaic,
+                                                no_persistent_cache):
+    """The tick and the 256-token prefill of `gpt2-345m.serve-chat`, on
+    what the engine makes of a float32 tree: no matrix comes in as
+    float32, none is cast in the program."""
+    compiled, _, n_weights = compile_serving_step(name, one_chip)
+    assert n_weights == 292
+    params, converts = float32_weight_reads(compiled.as_text(),
+                                            _gpt2_345m()[1])
+    # LayerNorm's scale and shift, twice a layer and once at the end
+    assert params == [(HID,)] * (2 + 24 * 4), params
+    assert not converts, f"float32 matrices cast in the step: {converts}"
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < TEMP_LIMIT, f"{temp / 2**20:.0f} MiB of temporaries"
+
+
+@pytest.mark.parametrize("name", GPT2_CELL_PROGRAMS)
+def test_float32_gpt2_weights_given_to_the_step_show(name, one_chip, mosaic,
+                                                     no_persistent_cache):
+    """The control of the test above, and what every tick did before the
+    engine held its weights: the float32 tree as an argument of the step
+    is read whole (292 float32 parameters, 1.42 GB) and every matrix
+    cast, the embedding table's 103 MB among the temporaries."""
+    compiled, _, _ = compile_serving_step(name, one_chip,
+                                          make=_gpt2_345m_float32)
+    params, converts = float32_weight_reads(compiled.as_text(),
+                                            _gpt2_345m()[1])
+    assert len(params) == 292, params
+    assert len(converts) == 2 + 24 * 4, converts
+    assert compiled.memory_analysis().temp_size_in_bytes > TEMP_LIMIT
