@@ -314,7 +314,8 @@ def rand_keep_global(shape, seed, rate, batch_offset=0, head_offset=0,
 # --- forward ---------------------------------------------------------------
 
 def _fwd_single_kernel(scale, a, causal, has_kvm, has_off, kpad, sq, sk,
-                       *refs, drop=0.0, h=1, pack=False, window=None):
+                       *refs, drop=0.0, h=1, pack=False, window=None,
+                       prefix=0):
     """Whole-(padded)-sequence-in-one-block forward: plain softmax, no
     online-correction carries (the default 1024 blocks put GPT s=1024
     and BERT s=512 here).  ``has_off``: a leading SMEM ref carries
@@ -329,12 +330,14 @@ def _fwd_single_kernel(scale, a, causal, has_kvm, has_off, kpad, sq, sk,
     ``h`` counts head PAIRS; per-head scores come from the sigma
     rotation (see the module head-packing note) and softmax/masking/
     dropout/lse run per head; lse_ref carries 16 sublanes (head 2j on
-    rows 0-7, 2j+1 on 8-15)."""
+    rows 0-7, 2j+1 on 8-15).  ``prefix`` (static): the first ``prefix``
+    keys stand before the causal square and every query sees them --
+    query row ``i`` sits at position ``prefix + i``."""
     if has_off:
         off_ref, *refs = refs
         qoff, koff = off_ref[0], off_ref[1]
     else:
-        qoff = koff = 0
+        qoff, koff = prefix, 0
     if drop > 0.0:
         dsalt_ref, *refs = refs
     q_ref, k_ref, v_ref, *rest = refs
@@ -426,12 +429,12 @@ def _fwd_single_kernel(scale, a, causal, has_kvm, has_off, kpad, sq, sk,
 
 
 def _fwd_kernel(scale, a, causal, has_kvm, has_off, kpad, sq, sk, bq, bk,
-                *refs, drop=0.0, h=1, pack=False, window=None):
+                *refs, drop=0.0, h=1, pack=False, window=None, prefix=0):
     if has_off:
         off_ref, *refs = refs
         qoff, koff = off_ref[0], off_ref[1]
     else:
-        qoff = koff = 0
+        qoff, koff = prefix, 0      # see _fwd_single_kernel
     if drop > 0.0:
         dsalt_ref, *refs = refs
     q_ref, k_ref, v_ref, *rest = refs
@@ -578,7 +581,7 @@ def _kvm8(kv_mask, b, psk, bk):
 
 
 def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_mask=None,
-               offsets=None, drop=0.0, dsalt=None, window=None):
+               offsets=None, drop=0.0, dsalt=None, window=None, prefix=0):
     b, h, sq, d = q.shape
     sk = k.shape[2]
     # a value width of its own (latent attention's expanded heads score
@@ -622,6 +625,9 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_mask=None,
 
     has_kvm = kv_mask is not None
     has_off = offsets is not None and causal
+    # the forward with a pooled prefix before its causal square (EVA's
+    # prefill) carries a name of its own into the HLO and the trace
+    name = "eva_flash_attention_fwd" if prefix else "flash_attention_fwd"
     if nq == 1 and nk == 1:
         qb_spec = pl.BlockSpec((1, psq, d), lambda b_: (b_, 0, 0),
                                memory_space=pltpu.VMEM)
@@ -654,7 +660,8 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_mask=None,
         o, lse8 = pl.pallas_call(
             functools.partial(_fwd_single_kernel, scale, a, causal,
                               has_kvm, has_off, kpad, sq, sk,
-                              drop=drop, h=h, pack=pack, window=window),
+                              drop=drop, h=h, pack=pack, window=window,
+                              prefix=prefix),
             grid=(bh,),
             in_specs=in_specs,
             out_specs=[ob_spec, lse_spec],
@@ -662,7 +669,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_mask=None,
                 jax.ShapeDtypeStruct((bh, psq, dv), q.dtype),
                 jax.ShapeDtypeStruct((bh, 1, 8 * g, bq), jnp.float32),
             ],
-            name="flash_attention_fwd",
+            name=name,
             interpret=_interpret(),
         )(*operands)
         return _unpack(o, lse8)
@@ -706,7 +713,8 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_mask=None,
     o, lse8 = pl.pallas_call(
         functools.partial(_fwd_kernel, scale, a, causal, has_kvm,
                           has_off, kpad, sq, sk, bq, bk,
-                          drop=drop, h=h, pack=pack, window=window),
+                          drop=drop, h=h, pack=pack, window=window,
+                          prefix=prefix),
         grid=(bh, nq, nk),
         in_specs=in_specs,
         out_specs=[o_spec, lse_spec],
@@ -719,7 +727,7 @@ def _flash_fwd(q, k, v, scale, causal, block_q, block_k, kv_mask=None,
             pltpu.VMEM((bq, 128), jnp.float32),
             pltpu.VMEM((bq, 128), jnp.float32),
         ],
-        name="flash_attention_fwd",
+        name=name,
         interpret=_interpret(),
     )(*operands)
     return _unpack(o, lse8)
@@ -1419,7 +1427,8 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
                     block_q: int = DEFAULT_BLOCK_Q,
                     block_k: int = DEFAULT_BLOCK_K,
                     kv_mask: Optional[jnp.ndarray] = None,
-                    window: Optional[int] = None) -> jnp.ndarray:
+                    window: Optional[int] = None,
+                    prefix: int = 0) -> jnp.ndarray:
     """Fused attention: softmax(q k^T * scale [masked]) v.
 
     Shapes: q (b, h, sq, d); k, v (b, h, sk, d).  ``scale`` defaults to
@@ -1441,8 +1450,12 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     query the ``window`` keys ending at its own position, whole key
     blocks outside that band neither fetched nor computed, and ``v``
     may have a last dimension of its own (latent attention's expanded
-    heads: q, k of 192, v and the result of 128).  None of the three
-    has a backward pass yet.
+    heads: q, k of 192, v and the result of 128).  ``prefix`` (static,
+    with ``causal``): ``k``/``v`` hold ``prefix`` rows before the causal
+    square, seen by every query (query ``i`` sees keys ``<= prefix +
+    i``): EVA's pooled rows of the earlier windows, ahead of the
+    window's own keys; ``kv_mask`` then says which of them are there.
+    None of the four has a backward pass yet.
     """
     from ._context import in_manual_axis_context
     from .._autocast_ctx import autocast_compute_dtype
@@ -1456,7 +1469,17 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
         q, k, v = (x.astype(act) for x in (q, k, v))
     if in_manual_axis_context(q, k, v):
         return mha_reference(q, k, v, scale=scale, causal=causal,
-                             kv_mask=kv_mask, window=window)
+                             kv_mask=kv_mask, window=window, prefix=prefix)
+    if prefix:
+        if not causal or window is not None:
+            raise ValueError("a prefix stands before a causal square, "
+                             "and not beside a window")
+        if scale is None:
+            scale = q.shape[-1] ** -0.5
+        return _flash_fwd(
+            q, k, v, scale, True, block_q, block_k,
+            kv_mask=None if kv_mask is None
+            else kv_mask.astype(jnp.float32), prefix=prefix)[0]
     if window is not None or k.shape[1] != q.shape[1] \
             or v.shape[-1] != q.shape[-1]:
         if kv_mask is not None or (window is not None and not causal):
@@ -2937,12 +2960,13 @@ def _fallback_dropout_attention(q, k, v, scale, causal, kv_mask, rate,
 
 
 def mha_reference(q, k, v, scale=None, causal=False, kv_mask=None,
-                  window=None):
+                  window=None, prefix=0):
     """Unfused reference (the [b,h,sq,sk]-materializing baseline the
     reference's standalone GPT uses) — for parity tests and benchmarks.
     ``kv_mask`` (b, sk): True/nonzero = attend.  ``k``/``v`` of fewer
     heads are repeated for the query heads that read them; ``window``
-    (with ``causal``) keeps the ``window`` keys ending at the query."""
+    (with ``causal``) keeps the ``window`` keys ending at the query;
+    ``prefix`` keys stand before the causal square, seen by all."""
     if scale is None:
         scale = q.shape[-1] ** -0.5
     if k.shape[1] != q.shape[1]:
@@ -2952,7 +2976,7 @@ def mha_reference(q, k, v, scale=None, causal=False, kv_mask=None,
                    k.astype(jnp.float32)) * scale
     sq, sk = s.shape[-2:]
     if causal:
-        mask = jnp.tril(jnp.ones((sq, sk), bool))
+        mask = jnp.tril(jnp.ones((sq, sk), bool), prefix)
         if window is not None:
             mask = mask & ~jnp.tril(jnp.ones((sq, sk), bool), -window)
         s = jnp.where(mask, s, _NEG)

@@ -116,9 +116,9 @@ from .flash_attention import (_LOG2E, _NEG, _dot, _interpret,
                               _packed_out, _packed_scores,
                               _pack_lane_cols, _use_head_packing)
 
-__all__ = ["flash_decode", "flash_decode_multi",
+__all__ = ["flash_decode", "flash_decode_multi", "eva_flash_decode",
            "paged_attention_reference",
-           "paged_attention_multi_reference",
+           "paged_attention_multi_reference", "eva_attention_reference",
            "use_decode_head_packing", "pack_decode_heads",
            "unpack_decode_heads", "dequantize_kv"]
 
@@ -423,13 +423,14 @@ def _paged_program(q4, k_cache, v_cache, block_tables, seq_lens, scale,
 # Pallas -> Mosaic lowering is Python time on every start, compile
 # cache hit or not (PERF.md, PR 28: 24 layers x 8 decode buckets).
 # ``interpret`` is an argument so that it keys the trace.
-_jit_driver = functools.partial(
-    jax.jit, static_argnames=("scale", "pack", "window", "interpret"))
+_DRIVER_STATICS = ("scale", "pack", "window", "interpret")
+_jit_driver = functools.partial(jax.jit, static_argnames=_DRIVER_STATICS)
 
 
-@_jit_driver
+@functools.partial(jax.jit, static_argnames=_DRIVER_STATICS + ("name",))
 def _decode_paged(q3, k_cache, v_cache, block_tables, seq_lens, scale,
-                  k_scale, v_scale, pack, window, interpret):
+                  k_scale, v_scale, pack, window, interpret,
+                  name="paged_flash_decode"):
     """The single-token pallas_call driver: (b, h, dk) queries are the
     ``t == 1`` chunk.  The call's output stays ``(b, h, 1, dk)``, ``h``
     the query heads, and its first operand the block table as the
@@ -437,7 +438,7 @@ def _decode_paged(q3, k_cache, v_cache, block_tables, seq_lens, scale,
     kernel, keywords, operands = _paged_program(
         q3[:, :, None, :], k_cache, v_cache, block_tables, seq_lens,
         scale, k_scale, v_scale, pack, window, interpret)
-    return pl.pallas_call(kernel, name="paged_flash_decode",
+    return pl.pallas_call(kernel, name=name,
                           **keywords)(*operands)[:, :, 0, :]
 
 
@@ -503,6 +504,42 @@ def flash_decode(q: jnp.ndarray, k_cache: jnp.ndarray,
                         block_tables.astype(jnp.int32),
                         seq_lens.astype(jnp.int32), scale,
                         k_scale, v_scale, pack, window, _interpret())
+    return unpack_decode_heads(out) if pack else out
+
+
+def eva_flash_decode(q: jnp.ndarray, k_cache: jnp.ndarray,
+                     v_cache: jnp.ndarray, block_tables: jnp.ndarray,
+                     summary_lens: jnp.ndarray, window_lens: jnp.ndarray,
+                     *, scale: Optional[float] = None) -> jnp.ndarray:
+    """EVA's decode attention: ONE softmax of each row's query over two
+    paged segments, each with its own length -- ``summary_lens[b]``
+    pooled (key, value) rows, one for every chunk of the windows before
+    the query's, and the ``window_lens[b]`` exact rows of its own window
+    up to itself.
+
+    Both segments live in the same cache arrays (a pooled row has a
+    cached row's shape) and ``block_tables[b]`` names them in one run:
+    the summary pages first, then the window's.  A closed window's
+    pooled rows fill whole pages (:class:`~apex_tpu.serving.kv_cache.
+    KVCacheConfig` holds ``window`` to a multiple of ``block_size^2``),
+    so **``summary_lens`` is a multiple of the page size**, the window's
+    first row follows the last summary row with no gap, and the union is
+    the leading ``summary_lens + window_lens`` rows of the table: the
+    paged program of :func:`flash_decode` reads exactly those, a page a
+    grid step, and pays a bare grid step for the rest of the page rung.
+    Rows whose lengths are both 0 are inactive and emit exactly 0.
+    Shapes and layouts as :func:`flash_decode` (float caches; the call's
+    output ``(b, h, 1, d)`` with the block table its first operand), its
+    name in the HLO ``eva_flash_decode``.  Inference-only."""
+    if scale is None:
+        scale = q.shape[-1] ** -0.5
+    pack = _cache_is_packed(q.shape, k_cache, v_cache, None, None)
+    q3 = pack_decode_heads(q) if pack else q
+    rows = summary_lens.astype(jnp.int32) + window_lens.astype(jnp.int32)
+    out = _decode_paged(q3, k_cache, v_cache,
+                        block_tables.astype(jnp.int32), rows, scale,
+                        None, None, pack, None, _interpret(),
+                        name="eva_flash_decode")
     return unpack_decode_heads(out) if pack else out
 
 
@@ -627,6 +664,18 @@ def paged_attention_reference(q, k_cache, v_cache, block_tables,
     o = jnp.einsum("bhk,bhkd->bhd", p / safe, v.astype(jnp.float32))
     o = jnp.where(l == 0.0, 0.0, o)
     return o.astype(q.dtype)
+
+
+def eva_attention_reference(q, k_cache, v_cache, block_tables,
+                            summary_lens, window_lens, scale=None):
+    """Dense jnp twin of :func:`eva_flash_decode`: the table's pages
+    gathered whole, one float32 softmax over its leading ``summary_lens
+    + window_lens`` rows (the summary pages are whole, so the window's
+    first row follows the last pooled row)."""
+    return paged_attention_reference(
+        q, k_cache, v_cache, block_tables,
+        summary_lens.astype(jnp.int32) + window_lens.astype(jnp.int32),
+        scale=scale)
 
 
 def paged_attention_multi_reference(q, k_cache, v_cache, block_tables,

@@ -40,7 +40,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import time
-from collections import deque
+from collections import Counter, deque
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -71,6 +71,11 @@ __all__ = ["Request", "BucketLadder", "ServingEngine", "ServeSummary",
 # per-token latency samples kept for the p50/p99 window (a lifetime
 # list would grow without bound on a long-running serve)
 _LATENCY_WINDOW = 100_000
+
+# the vectors a decode tick hands its step a row of the batch, in the
+# order the pooled cache's step unpacks them: tokens, positions,
+# seq_lens, write_blocks, write_offsets, pool_blocks, pool_offsets
+_POOLED_TICK_ROWS = 7
 
 # the gauges of ``_tick_tail`` that ride on the ``apex.serve.step`` span
 # as its metadata (with ``admitted``), set only while a trace records
@@ -416,6 +421,16 @@ class ServingEngine:
                 f"value_dim={cache_cfg.value_dim}: latent attention "
                 f"(family 'mla_moe'), and it alone, serves from the "
                 f"latent cache (default_cache_config makes it)")
+        eva = {(s.window, s.chunk) for s in model_cfg.layers
+               if s.chunk is not None}
+        if eva != ({(cache_cfg.window, cache_cfg.block_size)}
+                   if cache_cfg.pooled else set()):
+            raise ValueError(
+                f"EVA layers (window, chunk) {sorted(eva)} and a cache of "
+                f"window {cache_cfg.window}, block_size "
+                f"{cache_cfg.block_size}: they, and they alone, serve "
+                f"from the pooled cache of their window whose pages are a "
+                f"chunk long (default_cache_config makes it)")
         self.weights = weights
         self.model_cfg = model_cfg
         self.cache_cfg = cache_cfg
@@ -439,6 +454,22 @@ class ServingEngine:
                 self.ladder, chunks=(self.prefill_chunk,))
         self.prefix_share = prefix_share if prefix_share is not None \
             else flag_bool("APEX_TPU_SERVE_PREFIX_SHARE")
+        if cache_cfg.pooled:
+            # a chunk of a prompt is a window of its own (its keys are
+            # its activations), and a pooled row cannot be rolled back
+            rungs = self.ladder.chunk_rungs(cache_cfg.block_size)
+            if self.prefill_chunk != cache_cfg.window or any(
+                    r % cache_cfg.block_size ** 2 for r in rungs) \
+                    or rungs[-1] != cache_cfg.window:
+                raise ValueError(
+                    f"the pooled cache is prefilled a window a chunk: "
+                    f"prefill_chunk {self.prefill_chunk} has to be its "
+                    f"window {cache_cfg.window}, and the chunk rungs "
+                    f"{rungs} whole summary pages up to it")
+            if self.speculate_k or draft_weights is not None:
+                raise ValueError(
+                    "speculative decoding does not serve the pooled "
+                    "cache yet: a pooled row cannot be taken back")
         # --- ISSUE-13 serving resilience ----------------------------
         # default request deadline (0/None = none), hysteresis shed
         # policy, crash-safe request journal, watchdog escalation
@@ -567,6 +598,14 @@ class ServingEngine:
         self._latencies: deque = deque(maxlen=_LATENCY_WINDOW)
         self._tick_levels: Optional[Dict[str, Any]] = None
         self._tick_mtp: Dict[str, int] = {}    # a tick's MTP drafts
+        # the pooled cache: what a tick's prefill chunk and window
+        # closings count (zeroed at each step), and the summary pages
+        # of the windows before a prompt's last (the chunk program's
+        # prefix capacity)
+        self._tick_eva: Counter = Counter()
+        self._summary_capacity = (model_cfg.max_seq - 1) \
+            // cache_cfg.window * cache_cfg.window_summary_pages \
+            if cache_cfg.pooled else 0
         self._done_count = 0
         self._preempted_count = 0
         self._done_tokens = 0
@@ -589,7 +628,9 @@ class ServingEngine:
         self._experts_slots = sum(
             lw.e1.shape[0] for lw in weights.layers
             if getattr(lw, "e1", None) is not None)
-        windows = [s.window for s in model_cfg.layers]
+        # (an EVA layer's window is aligned and its cache pooled: the
+        # pooled cache's own counters are in _tick_counts)
+        windows = [s.window for s in model_cfg.layers if s.chunk is None]
         self._layer_windows = {w: windows.count(w) for w in set(windows)} \
             if any(windows) else {}
         self._emit_weights_held()
@@ -638,6 +679,23 @@ class ServingEngine:
         cfg = self.draft_cfg if draft else self.model_cfg
         ccfg = self.draft_cache_cfg if draft else self.cache_cfg
 
+        if ccfg.pooled:
+            # the pooled cache's tick takes its seven vectors a row as
+            # the rows of ONE array (:data:`_POOLED_TICK_ROWS`): every
+            # numpy argument is an upload of its own, ~0.12 ms of the
+            # host's time whatever its size and exposed in every tick
+            # (the device waits for it), and this kind would make eight
+            @functools.partial(jax.jit, donate_argnums=(1,))
+            def step(weights, cache, rows, block_tables):
+                (tokens, positions, seq_lens, write_blocks, write_offsets,
+                 pool_blocks, pool_offsets) = rows
+                return gpt_decode_step(weights, cfg, ccfg, cache, tokens,
+                                       positions, block_tables, seq_lens,
+                                       write_blocks, write_offsets,
+                                       pool_blocks, pool_offsets)
+
+            return step
+
         @functools.partial(jax.jit, donate_argnums=(1,))
         def step(weights, cache, tokens, positions, block_tables,
                  seq_lens, write_blocks, write_offsets):
@@ -656,9 +714,9 @@ class ServingEngine:
         prefill = mtp_prefill_step if self._mtp else gpt_prefill_step
 
         @functools.partial(jax.jit, donate_argnums=(1,))
-        def step(weights, cache, tokens, length, blocks):
+        def step(weights, cache, tokens, length, blocks, *chunk):
             return prefill(weights, cfg, ccfg, cache, tokens, length,
-                           blocks)
+                           blocks, *chunk)
 
         return step
 
@@ -689,13 +747,22 @@ class ServingEngine:
     def _decode_args(self, bb: int, pb: int, draft: bool = False):
         z = jnp.zeros((bb,), jnp.int32)
         w, c = self._wc(draft)
-        return (w, c, z, z, jnp.zeros((bb, pb), jnp.int32), z, z, z)
+        bt = jnp.zeros((bb, pb), jnp.int32)
+        if self.cache_cfg.pooled:
+            return (w, c, jnp.zeros((_POOLED_TICK_ROWS, bb), jnp.int32), bt)
+        return (w, c, z, z, bt, z, z, z)
 
     def _prefill_args(self, s_pad: int, draft: bool = False):
         w, c = self._wc(draft)
+        bs = self.cache_cfg.block_size
+        # the pooled cache: one window-aligned chunk (start, the summary
+        # pages before it, those its own pages pool to)
+        chunk = (jnp.int32(0),
+                 jnp.zeros((self._summary_capacity,), jnp.int32),
+                 jnp.zeros((s_pad // bs ** 2,), jnp.int32)) \
+            if self.cache_cfg.pooled else ()
         return (w, c, jnp.zeros((s_pad,), jnp.int32), jnp.int32(1),
-                jnp.zeros((s_pad // self.cache_cfg.block_size,),
-                          jnp.int32))
+                jnp.zeros((s_pad // bs,), jnp.int32), *chunk)
 
     def _extend_args(self, bb: int, t: int, pb: int,
                      draft: bool = False):
@@ -792,12 +859,17 @@ class ServingEngine:
         bs = self.cache_cfg.block_size
         spec = self.speculate_k > 0
         draft = spec and not self._mtp    # a second model's mirror programs
-        if not self._chunking:
+        if self.cache_cfg.pooled:
+            # a chunk of a prompt is a prefill of its own window
+            for ct in self.ladder.chunk_rungs(bs):
+                self._prefill_fn(ct)
+        elif not self._chunking:
             for pb in self.ladder.pages:
                 self._prefill_fn(pb * bs)
                 if draft:
                     self._draft_prefill_fn(pb * bs)
-        if self._chunking or self.prefix_share:
+        if (self._chunking or self.prefix_share) \
+                and not self.cache_cfg.pooled:
             for ct in self.ladder.chunk_rungs(bs):
                 for pb in self.ladder.pages:
                     self._extend_fn(1, ct, pb)
@@ -839,13 +911,14 @@ class ServingEngine:
                 request, "max_new_tokens",
                 f"request {request.rid!r}: max_new_tokens "
                 f"{request.max_new_tokens} < 1")
-        limit = self.ladder.max_pages * self.cache_cfg.block_size
         worst = len(request.prompt) + request.max_new_tokens
-        if worst > limit:
+        columns = self.cache_cfg.table_pages(worst)
+        if columns > self.ladder.max_pages:
             self._reject(
                 request, "ladder_span",
                 f"request {request.rid!r}: prompt + max_new_tokens = "
-                f"{worst} exceeds the ladder's {limit}-token span")
+                f"{worst} takes {columns} block-table columns, the "
+                f"ladder's span is {self.ladder.max_pages}")
         if worst > self.model_cfg.max_seq:
             self._reject(
                 request, "max_seq",
@@ -934,7 +1007,7 @@ class ServingEngine:
         for rid, req in in_flight:
             worst = self.cache_cfg.blocks_for(
                 len(req.prompt) + req.max_new_tokens)
-            total += max(0, worst - self.manager.num_pages(rid))
+            total += max(0, worst - self.manager.held_blocks(rid))
             if self.prefix_share:
                 total += self.manager.pending_cow_blocks(rid)
         return total
@@ -956,8 +1029,10 @@ class ServingEngine:
         t0 = self._clock()
         if prefix is None:          # step() passes its admission match
             prefix = self.manager.match_prefix(req.prompt)
-        self.manager.alloc(req.rid, p_len,
-                           shared_blocks=prefix.blocks)
+        # (the pooled cache claims a window at a time: _prefill_step)
+        self.manager.alloc(
+            req.rid, min(p_len, self.cache_cfg.window or p_len),
+            shared_blocks=prefix.blocks)
         if prefix.warm:
             self._warm_admissions += 1
             self._prefix_hit_tokens += prefix.tokens
@@ -1039,6 +1114,8 @@ class ServingEngine:
         rem = p_len - job.written
         ct = self.ladder.pick_chunk(rem, bs)
         n = min(rem, ct)
+        if self.cache_cfg.pooled:
+            return self._prefill_window(job, ct, n)
         pb = self.ladder.pick_pages(self.manager.num_pages(req.rid))
         bt = self.manager.block_table(req.rid, pb)
         table = self.manager.blocks(req.rid)
@@ -1070,7 +1147,53 @@ class ServingEngine:
             done = job.written >= p_len
             first = int(np.asarray(out)[0, -1]) if done else None
             # ^ the only host sync: non-final chunks stay async
-        dt = self._clock() - t0
+        return self._chunk_done(job, n, first, self._clock() - t0)
+
+    def _prefill_window(self, job: _PrefillJob, ct: int, n: int) -> bool:
+        """:meth:`_prefill_step` on the pooled cache: the next ``n``
+        positions are (the head of) a window of their own, prefilled as
+        one right-padded prompt on the ``ct`` rung.  The window's pages
+        are claimed now and, where the chunk fills it, given back once
+        the program that pools them has been handed to the device."""
+        cfg, mgr, rid = self.cache_cfg, self.manager, job.req.rid
+        bs, start = cfg.block_size, job.written
+        mgr.grow_to(rid, start + n)
+        toks = np.zeros(ct, np.int32)
+        toks[:n] = job.tokens[start:start + n]
+        blocks = np.full(ct // bs, DUMP_BLOCK, np.int32)
+        own = mgr.blocks(rid)
+        blocks[:len(own)] = own
+        summaries = mgr.summary_blocks(rid)
+        closed = start // cfg.window * cfg.window_summary_pages
+        table = np.full(self._summary_capacity, DUMP_BLOCK, np.int32)
+        table[:closed] = summaries[:closed]
+        pool = np.full(ct // bs ** 2, DUMP_BLOCK, np.int32)
+        pool[:len(summaries) - closed] = summaries[closed:]
+        t0 = self._clock()
+        with span("apex.serve.prefill"):
+            self.cache, out = self._prefill_fn(ct)(
+                self.weights, self.cache, toks, np.int32(n), blocks,
+                np.int32(start), table, pool)
+            freed = len(mgr.close_window(rid))
+            job.written += n
+            self.prefill_chunks += 1
+            done = job.written >= len(job.tokens)
+            first = int(out) if done else None   # the only host sync
+        rows = start // bs                  # pooled rows before the chunk
+        self._tick_eva.update(
+            eva_chunks=1, eva_chunk_tokens=n, eva_chunk_summary_rows=rows,
+            eva_chunk_pairs=n * (n + 1) // 2 + n * rows,
+            eva_pages_freed=freed, eva_windows_closed=int(freed > 0))
+        return self._chunk_done(job, n, first, self._clock() - t0)
+
+    def _chunk_done(self, job: _PrefillJob, n: int, first: Optional[int],
+                    dt: float) -> bool:
+        """What follows a prefill chunk of ``n`` tokens that took
+        ``dt``: its event and, where it completed the prompt (``first``
+        is the first generated token), the request's move into the
+        decode set.  True when the prefill finished."""
+        req, p_len = job.req, len(job.tokens)
+        done = first is not None
         self._event("prefill_chunk", value=round(dt * 1e3, 3),
                     rid=str(req.rid), tokens=int(n),
                     written=int(job.written), prompt_len=p_len)
@@ -1316,11 +1439,18 @@ class ServingEngine:
         with span("apex.serve.step") as tick:
             self._tick_levels = None
             self._tick_mtp = {}
+            self._tick_eva = Counter()
             gained, admitted = self._tick()
+            if self._tick_eva and recording():
+                # what the tick's prefill chunk and window closings
+                # counted (the pooled cache), decode tick or none
+                for key, value in self._tick_eva.items():
+                    self.tick_sums[key] = self.tick_sums.get(key, 0) + value
             if self._tick_levels is not None and recording():
                 # the tick's counters ride on its span: an operator
                 # reads them in xprof on the step they belong to
                 tick.set(admitted=admitted, **self._tick_mtp,
+                         **self._tick_eva,
                          **{k: self._tick_levels[k]
                             for k in _STEP_SPAN_COUNTERS})
             return gained
@@ -1411,11 +1541,13 @@ class ServingEngine:
             slots = [self._append_slot(q) for q in reqs]
             pb = self.ladder.pick_pages(
                 max(self.manager.num_pages(q.rid) for q in reqs))
-            tokens = np.zeros(bb, np.int32)
-            positions = np.zeros(bb, np.int32)
-            seq_lens = np.zeros(bb, np.int32)
-            wb = np.full(bb, DUMP_BLOCK, np.int32)
-            wo = np.zeros(bb, np.int32)
+            pooled = self.cache_cfg.pooled
+            # the vectors a row are views of one array: the pooled
+            # cache's tick uploads it whole (``_jit_decode``), with where
+            # a row's completed page pools to as its last two rows
+            rows = np.zeros((_POOLED_TICK_ROWS, bb), np.int32)
+            tokens, positions, seq_lens, wb, wo, pool_b, pool_o = rows
+            wb[:] = pool_b[:] = DUMP_BLOCK
             bt = np.full((bb, pb), DUMP_BLOCK, np.int32)
             for i, (q, (blk, off)) in enumerate(zip(reqs, slots)):
                 new_len = self.manager.seq_len(q.rid)   # post-append
@@ -1424,14 +1556,27 @@ class ServingEngine:
                 seq_lens[i] = new_len
                 wb[i], wo[i] = blk, off
                 bt[i] = self.manager.block_table(q.rid, pb)
+                if pooled:
+                    pool_b[i], pool_o[i] = self.manager.pool_slot(q.rid)
             fn = self._decode_fn(bb, pb)
         t0 = self._clock()
         with span("apex.serve.decode.dispatch"):
             # the executable uploads its numpy inputs itself, in one
             # batch: six jnp.asarray calls before it cost 1 ms a tick
             self.cache, next_tokens = fn(
-                self.weights, self.cache, tokens, positions, bt,
-                seq_lens, wb, wo)
+                self.weights, self.cache,
+                *((rows, bt) if pooled
+                  else (tokens, positions, bt, seq_lens, wb, wo)))
+            if pooled:
+                # a window whose last position this step wrote (and
+                # pooled) is closed: its pages go back now that the step
+                # is on its way, and not before -- another row of this
+                # step must not be handed a page this one still reads
+                for q in reqs:
+                    freed = len(self.manager.close_window(q.rid))
+                    if freed:
+                        self._tick_eva.update(eva_pages_freed=freed,
+                                              eva_windows_closed=1)
         with span("apex.serve.decode.fetch"):
             out = np.asarray(next_tokens)    # the tick's ONE device fetch
         dt = self._clock() - t0
@@ -1443,7 +1588,7 @@ class ServingEngine:
             self.decode_wall_s += dt
             self.decode_tokens += n
             self.steps += 1
-            counts = self._tick_counts(n, seq_lens, out[bb:])
+            counts = self._tick_counts(n, seq_lens, out[bb:], bb * pb)
             self._event("decode_step", value=round(dt * 1e3, 3),
                         batch=n, batch_bucket=bb, pages_bucket=pb,
                         **counts)
@@ -1451,7 +1596,7 @@ class ServingEngine:
         return n
 
     def _tick_counts(self, n: int, seq_lens: np.ndarray,
-                     extra: np.ndarray) -> Dict[str, int]:
+                     extra: np.ndarray, slots: int = 0) -> Dict[str, int]:
         """What a decode tick of a family with routed experts or
         windowed layers counts, for the ``decode_step`` event and,
         while :func:`~..monitor.tracing.recording`, added into
@@ -1500,10 +1645,36 @@ class ServingEngine:
                     (-(-lens // self.cache_cfg.block_size)).sum()),
                 latent_tokens=layers * int(lens.sum()),
                 experts_slots=self._experts_slots)
+        eva = {}
+        if self.cache_cfg.pooled:
+            # EVA layers read, a live row and layer, the exact rows of
+            # the row's own window up to its position
+            # (``eva_window_rows``) and one pooled row a page of every
+            # closed window (``eva_summary_rows``), both summed over the
+            # layers, ``eva_rows`` the two together; ``eva_pages_live``
+            # the pages of both lists that hold them, of the
+            # ``eva_pages_slots`` (batch rung x page rung) the kernel's
+            # grid was launched over.  They join what the tick's prefill
+            # chunk and window closings counted (``_tick_eva``), which
+            # ``step`` sums and puts on its span: a tick that only
+            # prefills counts too.
+            cfg, layers = self.cache_cfg, self.model_cfg.num_layers
+            at = seq_lens[:n].astype(np.int64) - 1
+            closed, rows = at // cfg.window, at % cfg.window + 1
+            window_rows = layers * int(rows.sum())
+            summary_rows = layers * cfg.window_pages * int(closed.sum())
+            counts.update(ticks=1, rows=n)
+            eva = dict(
+                eva_window_rows=window_rows, eva_summary_rows=summary_rows,
+                eva_rows=window_rows + summary_rows,
+                eva_pages_live=int((closed * cfg.window_summary_pages
+                                    - (-rows // cfg.block_size)).sum()),
+                eva_pages_slots=slots)
+            self._tick_eva.update(eva)
         if record:
             for key, value in counts.items():
                 self.tick_sums[key] = self.tick_sums.get(key, 0) + value
-        return counts
+        return {**counts, **eva}
 
     def _spec_tick(self, reqs: List[Request]) -> int:
         """One speculative tick: the draft proposes K tokens row by
@@ -2273,6 +2444,12 @@ def default_cache_config(model_cfg: ServingModelConfig,
     (``APEX_TPU_SERVE_KV_BLOCK`` / ``APEX_TPU_SERVE_KV_DTYPE`` /
     ``APEX_TPU_SERVE_BLOCKS``); explicit arguments override."""
     mla = model_cfg.mla
+    eva = next((s for s in model_cfg.layers if s.chunk is not None), None)
+    if eva is not None:
+        if block_size not in (None, eva.chunk):
+            raise ValueError(f"EVA layers of chunk {eva.chunk} are served "
+                             f"from pages a chunk long, not {block_size}")
+        block_size = eva.chunk
     return KVCacheConfig(
         # latent attention: one latent row a token and layer, and one
         # more layer for an MTP module that is served
@@ -2286,4 +2463,6 @@ def default_cache_config(model_cfg: ServingModelConfig,
                     else flag_int("APEX_TPU_SERVE_KV_BLOCK")),
         kv_dtype=(kv_dtype if kv_dtype is not None
                   else flag_str("APEX_TPU_SERVE_KV_DTYPE")),
-        model_dtype=model_cfg.dtype)
+        model_dtype=model_cfg.dtype,
+        # EVA layers: the pooled kind, window pages beside summary pages
+        window=eva.window if eva is not None else None)

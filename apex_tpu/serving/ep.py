@@ -163,6 +163,10 @@ class EPContext:
                 + (" (it computes the share of the experts that "
                    "expert_first and its stacks name, and no exchange)"
                    if model_cfg.mla is not None else ""))
+        if cache_cfg.pooled:
+            raise ValueError(
+                "the pooled cache (KVCacheConfig.window) has no "
+                "expert-parallel serving forward yet; serve it on one chip")
         if ep < 2:
             raise ValueError(f"ep {ep} must be >= 2 (ep=1 is the "
                              f"single-chip engine, no context needed)")

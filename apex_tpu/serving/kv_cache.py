@@ -18,7 +18,9 @@ Two cleanly separated halves:
   **latent** kind (``KVCacheConfig.value_dim``, latent attention) one
   ``(nb, 1, bs, d_latent)`` array a layer and no v array, a token's row
   ``[c_kv ; k_rope]`` being its key and, in its first ``value_dim``
-  values, its value.  A pytree, threaded through the jitted prefill/decode steps and
+  values, its value; the **pooled** kind (``KVCacheConfig.window``, EVA
+  attention) the same k and v arrays, whose pages hold either a window's
+  rows or one pooled row a closed page.  A pytree, threaded through the jitted prefill/decode steps and
   **donated** every step, every leaf its own buffer (the same carry
   discipline as the scan driver's amp state — the cache is the
   largest buffer in the serving process, double-buffering it halves
@@ -101,6 +103,13 @@ class KVCacheConfig:
     # whose first ``value_dim`` are also its value: a page is read once
     # for both.  None: the k and v arrays of the module docstring.
     value_dim: Optional[int] = None
+    # the POOLED kind (EVA attention): a layer keeps the k and v rows of
+    # the current ``window``-aligned window alone and, for every closed
+    # page before it, ONE pooled (k, v) row -- a page of ``block_size``
+    # rows pools to a row of the same shape, so window pages and summary
+    # pages are the same arrays and one pool, and a request holds two
+    # lists (:class:`KVCacheManager`).  None: every position keeps its row.
+    window: Optional[int] = None
 
     def __post_init__(self):
         if self.kv_dtype not in _KV_DTYPES:
@@ -123,10 +132,39 @@ class KVCacheConfig:
                     "the latent cache has no int8 storage yet: a latent "
                     "row is every head's key and value at once, and one "
                     "scale a row is unproven there")
+        if self.pooled:
+            if self.latent or self.quantized:
+                raise ValueError(
+                    "the pooled cache (window pages beside one pooled row "
+                    "a closed page) has no latent and no int8 storage yet")
+            if self.packed:
+                raise ValueError(
+                    "the pooled cache pools a page a head: it has no "
+                    "head-pair packed layout (d=64 pairs) yet")
+            if self.window < 1 or self.window % self.block_size ** 2:
+                raise ValueError(
+                    f"a pooled cache's window ({self.window}) is whole "
+                    f"summary pages: a multiple of block_size^2 "
+                    f"({self.block_size ** 2}), a page of rows pooling to "
+                    f"one row of a page")
 
     @property
     def latent(self) -> bool:
         return self.value_dim is not None
+
+    @property
+    def pooled(self) -> bool:
+        return self.window is not None
+
+    @property
+    def window_pages(self) -> int:
+        """Pages of a full window (the pooled kind)."""
+        return self.window // self.block_size
+
+    @property
+    def window_summary_pages(self) -> int:
+        """Summary pages a closed window's pooled rows fill."""
+        return self.window // self.block_size ** 2
 
     @property
     def packed(self) -> bool:
@@ -162,7 +200,26 @@ class KVCacheConfig:
         return self.num_blocks - 1
 
     def blocks_for(self, length: int) -> int:
-        return -(-max(int(length), 1) // self.block_size)
+        """The most blocks a request holds on its way to ``length``
+        positions: its reservation.  Every position's page; the pooled
+        kind ``min(ceil(T / bs), window pages) + ceil(T / bs^2)``, the
+        pages of one window and a summary row a page."""
+        length = max(int(length), 1)
+        pages = -(-length // self.block_size)
+        if not self.pooled:
+            return pages
+        return min(pages, self.window_pages) + -(-pages // self.block_size)
+
+    def table_pages(self, length: int) -> int:
+        """The columns a block table needs for a row of up to ``length``
+        positions: its pages, or for the pooled kind the summary pages
+        of the closed windows and then the window's own."""
+        length = max(int(length), 1)
+        pages = -(-length // self.block_size)
+        if not self.pooled:
+            return pages
+        return min(pages, self.window_pages) \
+            + (length - 1) // self.window * self.window_summary_pages
 
     def cache_nbytes(self) -> int:
         per = np.dtype(self.storage_dtype).itemsize
@@ -471,16 +528,39 @@ class KVCacheManager:
     a later identical prompt still hits warm — idle blocks are
     reclaimed (unregistered) only when an allocation finds the free
     list empty.  ``can_admit`` counts idle blocks as available and a
-    warm request's need as only its unshared tail."""
+    warm request's need as only its unshared tail.
+
+    **The pooled kind** (``config.window``): a request's pages stop
+    growing with its length.  It holds TWO lists: the pages of its
+    current window (``blocks``: position ``t`` lies on page ``(t mod
+    window) // bs`` of them) and its summary pages (``summary_blocks``:
+    the pooled row of page ``c`` of the sequence is row ``c mod bs`` of
+    summary page ``c // bs``, taken when position ``c * bs + bs - 1``
+    is appended).  When the length reaches a multiple of ``window`` the
+    window has closed: once the step that wrote its last position has
+    been handed to the device the caller gives its pages back
+    (:meth:`close_window`; not before, or another row of the same step
+    could be handed a page this one still reads).  The block table of a
+    step is the summary pages of the CLOSED windows, all of them whole,
+    and then the window's own pages, so its live rows are contiguous
+    (:meth:`block_table`, :meth:`num_pages`).  Prefix sharing refuses
+    this kind: a shared prefix would be summary rows and window pages
+    of another request's windows."""
 
     def __init__(self, config: KVCacheConfig, *,
                  prefix_sharing: bool = False):
+        if config.pooled and prefix_sharing:
+            raise ValueError(
+                "prefix sharing and copy-on-write do not serve the pooled "
+                "cache (KVCacheConfig.window) yet: a request's pages are "
+                "its own window's and its own summaries")
         self.config = config
         # stack: pop() from the end; ids descend so the FIRST blocks
         # handed out are 1, 2, 3, ... (stable, test-friendly)
         self._free: List[int] = list(range(config.num_blocks - 1, 0,
                                            -1))
         self._tables: Dict[object, List[int]] = {}
+        self._summaries: Dict[object, List[int]] = {}   # the pooled kind
         self._lens: Dict[object, int] = {}
         self.prefix_sharing = bool(prefix_sharing)
         self._index: Dict[bytes, int] = {}       # chain key -> block
@@ -493,6 +573,7 @@ class KVCacheManager:
         self.prefix_hits = 0
         self.cow_copies = 0
         self.shared_blocks_hw = 0
+        self.used_blocks_hw = 0          # the pool's high-water mark
 
     # --- capacity -----------------------------------------------------
 
@@ -736,7 +817,9 @@ class KVCacheManager:
         """One block off the free list, reclaiming the LRU idle shared
         block (unregistering its prefix entry) when the list is dry."""
         if self._free:
-            return self._free.pop()
+            blk = self._free.pop()
+            self.used_blocks_hw = max(self.used_blocks_hw, self.used_blocks)
+            return blk
         if self._idle:
             blk, _ = self._idle.popitem(last=False)
             key = self._block_key.pop(blk)
@@ -762,6 +845,20 @@ class KVCacheManager:
             raise ValueError(f"request {rid!r} already has blocks")
         if length < 1:
             raise ValueError("length must be >= 1")
+        if self.config.pooled:
+            if shared_blocks or length > self.config.window:
+                raise ValueError(
+                    f"request {rid!r}: a pooled cache allocates one "
+                    f"window ({self.config.window} positions) at most and "
+                    f"maps no shared page; grow_to() takes the next")
+            self._tables[rid], self._summaries[rid] = [], []
+            self._lens[rid] = 0
+            try:
+                self.grow_to(rid, length)
+            except CachePoolExhausted:
+                self.free(rid)
+                raise
+            return list(self._tables[rid])
         need = self.config.blocks_for(length) - len(shared_blocks)
         if need < 0:
             raise ValueError(
@@ -838,6 +935,9 @@ class KVCacheManager:
         block on device)."""
         blocks = self._tables[rid]
         pos = self._lens[rid]
+        if self.config.pooled:
+            self.grow_to(rid, pos + 1)
+            return blocks[-1], pos % self.config.block_size
         page, off = divmod(pos, self.config.block_size)
         if page == len(blocks):
             blocks.append(self._take_block(
@@ -852,6 +952,66 @@ class KVCacheManager:
         self._lens[rid] = pos + 1
         return blocks[page], off
 
+    def grow_to(self, rid, length: int) -> None:
+        """Raise ``rid``'s length to ``length`` positions, claiming the
+        pages they land on: what a prefill chunk does before it is
+        written (a request whose :meth:`alloc` covered its whole prompt
+        has nothing to claim).  The pooled kind grows inside ONE window
+        -- a closed window is given back first (:meth:`close_window`) --
+        and claims a summary page with the first page that pools to
+        it."""
+        cfg, pos = self.config, self._lens[rid]
+        if length <= pos:
+            return
+        if not cfg.pooled:
+            while self._lens[rid] < length:
+                self.append(rid)
+            return
+        blocks, summaries = self._tables[rid], self._summaries[rid]
+        if pos // cfg.window != (length - 1) // cfg.window \
+                or (pos % cfg.window == 0 and blocks):
+            raise RuntimeError(
+                f"request {rid!r}: positions {pos}..{length - 1} do not "
+                f"lie in one open window of {cfg.window} (close_window() "
+                f"gives a closed one back first)")
+        bs = cfg.block_size
+        want = -(-((length - 1) % cfg.window + 1) // bs) - len(blocks)
+        want_sum = -(-(length // bs) // bs) - len(summaries)
+        if want + want_sum > self.available_blocks:
+            raise CachePoolExhausted(
+                f"request {rid!r} needs {want + want_sum} block(s) to "
+                f"reach length {length}, pool has {self.available_blocks} "
+                f"— admission control must keep headroom (can_admit)")
+        why = f"request {rid!r}: pool drained growing to {length}"
+        blocks.extend(self._take_block(why) for _ in range(want))
+        summaries.extend(self._take_block(why) for _ in range(want_sum))
+        self._lens[rid] = int(length)
+
+    def pool_slot(self, rid) -> Tuple[int, int]:
+        """Where the pooled row of the page that ``rid``'s newest
+        position completed goes, as ``(summary block, row)``; the dump
+        page when that position completed none."""
+        bs = self.config.block_size
+        chunk, rest = divmod(self._lens[rid], bs)
+        if rest or not chunk:
+            return DUMP_BLOCK, 0
+        return self._summaries[rid][(chunk - 1) // bs], (chunk - 1) % bs
+
+    def close_window(self, rid) -> List[int]:
+        """Give back the pages of ``rid``'s window if its last position
+        has been appended (the length is a multiple of ``window``):
+        what is kept of a closed window is its pooled rows.  Call once
+        the step that wrote that position has been handed to the
+        device.  Returns the freed block ids."""
+        blocks = self._tables[rid]
+        if not self.config.pooled or not blocks \
+                or self._lens[rid] % self.config.window:
+            return []
+        freed = list(blocks)
+        self._free.extend(reversed(blocks))
+        blocks.clear()
+        return freed
+
     def truncate(self, rid, new_len: int) -> List[int]:
         """Roll ``rid``'s write cursor back to ``new_len`` tokens
         (speculative-decode rejection), returning pages past the new
@@ -862,6 +1022,10 @@ class KVCacheManager:
             raise ValueError(
                 f"request {rid!r}: truncate to {new_len} outside "
                 f"[1, {self._lens[rid]}]")
+        if self.config.pooled:
+            raise ValueError(
+                "the pooled cache has no rollback: a pooled row cannot be "
+                "taken back (speculative decoding does not serve it yet)")
         blocks = self._tables[rid]
         keep = self.config.blocks_for(new_len)
         freed: List[int] = []
@@ -882,7 +1046,7 @@ class KVCacheManager:
         mappings are unref'd instead — a block another table still
         maps stays live, and one reaching zero refs parks in the idle
         LRU (still indexed, warm for the next identical prompt)."""
-        blocks = self._tables.pop(rid)
+        blocks = self._tables.pop(rid) + self._summaries.pop(rid, [])
         del self._lens[rid]
         shared = self._shared_of.pop(rid, set())
         for blk in reversed(blocks):
@@ -901,11 +1065,38 @@ class KVCacheManager:
         return self._lens[rid]
 
     def blocks(self, rid) -> List[int]:
+        """``rid``'s pages in position order (the pooled kind: those of
+        its current window)."""
         return list(self._tables[rid])
+
+    def summary_blocks(self, rid) -> List[int]:
+        """The pooled kind: ``rid``'s summary pages, in order."""
+        return list(self._summaries[rid])
+
+    def held_blocks(self, rid) -> int:
+        """Blocks ``rid`` holds now, of every list."""
+        return len(self._tables[rid]) + len(self._summaries.get(rid, ()))
+
+    def _closed_pages(self, rid) -> int:
+        """The pooled kind: the summary pages of ``rid``'s closed
+        windows, each whole; they lead its block table."""
+        if not self.config.pooled:
+            return 0
+        return max(self._lens[rid] - 1, 0) // self.config.window \
+            * self.config.window_summary_pages
+
+    def _table(self, rid) -> List[int]:
+        """The pages a step reads for ``rid``, in the order its block
+        table lists them: every page; the pooled kind the summary pages
+        of the closed windows and then the window's own."""
+        if not self.config.pooled:
+            return self._tables[rid]
+        return self._summaries[rid][:self._closed_pages(rid)] \
+            + self._tables[rid]
 
     def block_table(self, rid, max_pages: int) -> np.ndarray:
         """(max_pages,) int32, padded with the dump block."""
-        blocks = self._tables[rid]
+        blocks = self._table(rid)
         if len(blocks) > max_pages:
             raise ValueError(
                 f"request {rid!r} owns {len(blocks)} pages > bucket "
@@ -915,4 +1106,5 @@ class KVCacheManager:
         return bt
 
     def num_pages(self, rid) -> int:
-        return len(self._tables[rid])
+        """The columns of ``rid``'s block table that name a page."""
+        return self._closed_pages(rid) + len(self._tables[rid])

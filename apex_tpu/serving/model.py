@@ -31,7 +31,8 @@ from typing import Any, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ..ops.flash_decode import (flash_decode, flash_decode_multi,
+from ..ops.flash_decode import (eva_attention_reference, eva_flash_decode,
+                                flash_decode, flash_decode_multi,
                                 paged_attention_multi_reference,
                                 paged_attention_reference)
 from ..ops.layer_norm import layer_norm
@@ -180,6 +181,12 @@ class ServingModelConfig:
     # MTP modules served as the engine's draft (0 or 1): the cache
     # holds a latent layer for each after the model's own
     mtp_layers: int = 0
+    # RMSNorm multiplies by ``1 + w``, its weight held about zero
+    norm_unit_offset: bool = False
+    # the head is this many vocabularies wide, prediction head ``p`` (the
+    # logits ``[p * V, (p + 1) * V)``) for the token ``1 + p`` ahead;
+    # head 0 is the next token and the one that is sampled
+    pred_heads: int = 1
 
     def __post_init__(self):
         if self.mla is not None:
@@ -218,6 +225,17 @@ class ServingModelConfig:
                     raise ValueError(
                         f"{spec.num_heads} query heads do not divide "
                         f"over {self.num_kv_heads} cache heads")
+            pooled = {(s.window, s.chunk) for s in self.layers
+                      if s.chunk is not None}
+            if pooled and (self.family != "rope_moe" or len(pooled) > 1
+                           or len(self.layers) != sum(
+                               s.chunk is not None for s in self.layers)):
+                raise ValueError(
+                    "EVA layers (LayerSpec.chunk) are rope_moe layers of "
+                    "one window and chunk, and a model of them holds no "
+                    "other kind yet: the pooled cache is one pool")
+        if self.pred_heads < 1:
+            raise ValueError(f"pred_heads {self.pred_heads} must be >= 1")
         if self.decode_attention not in ("kernel", "reference"):
             raise ValueError(
                 f"decode_attention {self.decode_attention!r} not in "
@@ -454,7 +472,8 @@ def _attn_inputs(x, lw, cfg, spec, positions, h, d):
     ``spec.num_heads`` query and ``h`` cache heads, rotated by
     ``positions``."""
     if spec is not None:
-        a_in = rope_moe.rms_norm(x, lw.norm1, cfg.layernorm_eps)
+        a_in = rope_moe.rms_norm(x, lw.norm1, cfg.layernorm_eps,
+                                 cfg.norm_unit_offset)
         return (a_in,) + rope_moe.qkv(a_in, lw, spec, cfg, positions)
     a_in = layer_norm(x, lw.ln1_w, lw.ln1_b,
                       cfg.layernorm_eps).astype(cfg.dtype)
@@ -483,6 +502,109 @@ def _attn_branch(ctx, a_in, lw, cfg, spec):
                        cfg.tp_axis, getattr(lw, "dense_s", None))
 
 
+def _eva_prefill_attention(x, lw, cfg, spec, cache_cfg, cache, layer,
+                           positions, blocks, start, summary_table,
+                           pool_blocks):
+    """An EVA layer's attention branch over ONE window-aligned chunk
+    (``x`` (1, s_pad, H), its first position ``start`` a multiple of the
+    window): ``(cache, branch (1, s_pad, H))``.
+
+    The chunk is its own window, so its keys and values are its own
+    activations: they are written to the window's pages (``blocks``) for
+    the decode steps that follow, every page of the chunk is pooled
+    (``pool_chunks``) into the summary pages it fills (``pool_blocks``,
+    ``s_pad / bs^2`` of them; a page the chunk only starts pools
+    padding, to a row that is written again when the page completes and
+    not seen before), and only the pooled rows of the EARLIER windows
+    come from pages: ``summary_table`` (their capacity, dump-padded),
+    of which the first ``start / chunk`` rows are there, gathered in
+    front of the causal square."""
+    from ..ops.flash_attention import flash_attention, mha_reference
+
+    h, d = cache_cfg.num_heads, cache_cfg.head_dim
+    bs, scale = cache_cfg.block_size, d ** -0.5
+    if spec.chunk != bs or spec.window != cache_cfg.window:
+        raise ValueError(
+            f"an EVA layer of window {spec.window} and chunk {spec.chunk} "
+            f"is served from a pooled cache of that window whose pages "
+            f"are a chunk long (window {cache_cfg.window}, block_size "
+            f"{bs}): default_cache_config makes it")
+    with jax.named_scope("apex.attn.eva"):
+        a_in, q, k, v = _attn_inputs(x, lw, cfg, spec, positions, h, d)
+        cache = write_prefill_kv(cache, cache_cfg, layer, k[0], v[0],
+                                 blocks)
+        with jax.named_scope("apex.attn.eva.pool"):
+            kp, vp = rope_moe.pool_chunks(
+                k[0].reshape(-1, bs, h, d), v[0].reshape(-1, bs, h, d),
+                lw, scale)
+            cache = write_prefill_kv(
+                cache, cache_cfg, layer, kp.astype(cfg.dtype),
+                vp.astype(cfg.dtype), pool_blocks)
+        kc, vc, _, _ = cache.layer(layer)
+        prefix = summary_table.shape[0] * bs
+
+        def with_prefix(arr, own):
+            # (P, h, bs, d) summary pages -> (1, h, P * bs, d) rows
+            rows = arr[summary_table].transpose(1, 0, 2, 3) \
+                .reshape(1, h, prefix, d)
+            return jnp.concatenate(
+                [rows, own.transpose(0, 2, 1, 3).astype(rows.dtype)], 2)
+
+        at = jnp.arange(prefix + x.shape[1], dtype=jnp.int32)
+        there = (at < start // spec.chunk) | (at >= prefix)
+        attn = flash_attention if cfg.prefill_flash else mha_reference
+        ctx = attn(q.transpose(0, 2, 1, 3), with_prefix(kc, k),
+                   with_prefix(vc, v), scale=scale, causal=True,
+                   prefix=prefix, kv_mask=there[None])
+        return cache, _attn_branch(ctx.transpose(0, 2, 1, 3), a_in, lw,
+                                   cfg, spec)
+
+
+def _eva_decode_attention(x, lw, cfg, spec, cache_cfg, cache, layer,
+                          positions, write, pool_write, write_blocks,
+                          block_tables, seq_lens):
+    """An EVA layer's attention branch for one new position a row
+    (``x`` (b, H)): ``(cache, branch (b, H))``.  The position's key and
+    value go to its window page; the query reads, under one softmax, the
+    pooled rows of every earlier window and its own window's rows up to
+    itself (``block_tables``: those summary pages, then the window's;
+    the two lengths follow from the position); and where the position
+    completed its page (``pool_write`` names a summary row, else the
+    dump page) the page just written is pooled to that row."""
+    h, d = cache_cfg.num_heads, cache_cfg.head_dim
+    scale = d ** -0.5
+    with jax.named_scope("apex.attn.eva"):
+        a_in, q, k, v = _attn_inputs(x, lw, cfg, spec, positions, h, d)
+        cache = write_token_kv(cache, cache_cfg, layer, k, v, write)
+        kc, vc, _, _ = cache.layer(layer)
+        live = seq_lens > 0
+        summary_lens = jnp.where(
+            live, positions // spec.window * (spec.window // spec.chunk), 0)
+        window_lens = jnp.where(live, positions % spec.window + 1, 0)
+        attn = eva_flash_decode if cfg.decode_attention == "kernel" \
+            else eva_attention_reference
+        ctx = attn(q, kc, vc, block_tables, summary_lens, window_lens,
+                   scale=scale)
+        with jax.named_scope("apex.attn.eva.pool"):
+            kp, vp = rope_moe.pool_chunks(
+                kc[write_blocks].transpose(0, 2, 1, 3),
+                vc[write_blocks].transpose(0, 2, 1, 3), lw, scale)
+            cache = write_token_kv(cache, cache_cfg, layer,
+                                   kp.astype(cfg.dtype),
+                                   vp.astype(cfg.dtype), pool_write)
+        return cache, _attn_branch(ctx, a_in, lw, cfg, spec)
+
+
+def _eva(spec) -> bool:
+    return spec is not None and spec.chunk is not None
+
+
+def _sampled(logits, cfg):
+    """The logits a token is sampled from: the next token's, prediction
+    head 0 of a head that is several vocabularies wide."""
+    return logits[..., :cfg.vocab_size] if cfg.pred_heads > 1 else logits
+
+
 def _layer_tail(x, lw, attn_out, cfg, live=None):
     """residual + norm + MLP + residual -- shared by prefill, decode
     and extend; returns ``(x, tick counters or None)``.  GPT-2: fc1 is
@@ -501,8 +623,8 @@ def _layer_tail(x, lw, attn_out, cfg, live=None):
     x = x + attn_out.astype(x.dtype)
     if hasattr(lw, "norm2"):
         branch, counters = rope_moe.mlp(
-            rope_moe.rms_norm(x, lw.norm2, cfg.layernorm_eps), lw, cfg,
-            live)
+            rope_moe.rms_norm(x, lw.norm2, cfg.layernorm_eps,
+                              cfg.norm_unit_offset), lw, cfg, live)
         if sandwich:
             branch = rope_moe.rms_norm(branch, lw.norm2_post,
                                        cfg.layernorm_eps)
@@ -523,7 +645,8 @@ def _lm_head(x, weights, cfg):
     embedding (GPTHead + attend), or ``rope_moe``'s RMSNorm and untied
     head with float32 logits."""
     if isinstance(weights, RopeMoEWeights):
-        return rope_moe.head_logits(x, weights, cfg.layernorm_eps)
+        return rope_moe.head_logits(x, weights, cfg.layernorm_eps,
+                                    cfg.norm_unit_offset)
     hf = layer_norm(x, weights.lnf_w, weights.lnf_b,
                     cfg.layernorm_eps).astype(cfg.dtype)
     return hf.astype(cfg.dtype) @ weights.wte.astype(cfg.dtype).T
@@ -542,7 +665,7 @@ def _embed(weights, tokens, positions, cfg):
 def gpt_prefill_step(weights, cfg: ServingModelConfig,
                      cache_cfg: KVCacheConfig, cache: PagedKVCache,
                      tokens: jnp.ndarray, length: jnp.ndarray,
-                     blocks: jnp.ndarray):
+                     blocks: jnp.ndarray, *chunk):
     """Run one prompt through the model, writing every layer's k/v
     into the request's pages; returns ``(cache, next_token)``.
     (:func:`prefill_logits` is this step before its argmax.)
@@ -560,18 +683,27 @@ def gpt_prefill_step(weights, cfg: ServingModelConfig,
     flash_attention`) — prefill is exactly a training forward at
     batch 1 (with grouped heads and, on windowed layers, a causal
     window for the ``rope_moe`` family, whose head runs on the last
-    real position's row alone)."""
+    real position's row alone).
+
+    A model of EVA layers (the pooled cache) is prefilled one
+    window-aligned chunk a call, and ``chunk`` is the three arguments
+    that place it: ``start`` (the chunk's first position, a multiple of
+    the window; ``length`` counts from it), ``summary_table`` (the
+    summary pages of the windows before it, dump-padded to their
+    capacity) and ``pool_blocks`` (the ``s_pad / bs^2`` summary pages
+    the chunk's own pages pool to, the dump page for those it does not
+    reach): :func:`_eva_prefill_attention`."""
     cache, last = prefill_logits(weights, cfg, cache_cfg, cache, tokens,
-                                 length, blocks)
-    return cache, jnp.argmax(last, axis=-1).astype(jnp.int32)
+                                 length, blocks, *chunk)
+    return cache, jnp.argmax(_sampled(last, cfg), axis=-1).astype(jnp.int32)
 
 
 def prefill_logits(weights, cfg, cache_cfg, cache, tokens, length,
-                   blocks):
+                   blocks, *chunk):
     """:func:`gpt_prefill_step` up to the logits of the last real
-    position: ``(cache, (V,) logits)``."""
+    position: ``(cache, (V,) logits)``, every prediction head's."""
     cache, x = _prefill_hidden(weights, cfg, cache_cfg, cache, tokens,
-                               blocks)
+                               blocks, *chunk)
     return cache, _last_logits(x, weights, cfg, length)
 
 
@@ -587,9 +719,11 @@ def _last_logits(x, weights, cfg, length):
                                         keepdims=False)
 
 
-def _prefill_hidden(weights, cfg, cache_cfg, cache, tokens, blocks):
+def _prefill_hidden(weights, cfg, cache_cfg, cache, tokens, blocks,
+                    start=0, *pooled):
     """The prefill's layers: ``(cache, final residual stream (1, s_pad,
-    H))``, before the final norm."""
+    H))``, before the final norm; ``start`` and ``pooled`` place one
+    chunk of a model of EVA layers (:func:`gpt_prefill_step`)."""
     from ..ops.flash_attention import flash_attention, mha_reference
 
     s_pad = tokens.shape[0]
@@ -600,6 +734,8 @@ def _prefill_hidden(weights, cfg, cache_cfg, cache, tokens, blocks):
     scale = d ** -0.5
     tokens = tokens[None, :]
     positions = jnp.arange(s_pad, dtype=jnp.int32)[None, :]
+    if pooled:
+        positions = positions + start
     x = _embed(weights, tokens, positions, cfg)
     for i, lw in enumerate(weights.layers):
         spec = _spec(cfg, i)
@@ -608,6 +744,10 @@ def _prefill_hidden(weights, cfg, cache_cfg, cache, tokens, blocks):
                                                 positions)
             cache = write_prefill_kv(cache, cache_cfg, i,
                                      latent[0, :, None], None, blocks)
+        elif _eva(spec):
+            cache, attn_out = _eva_prefill_attention(
+                x, lw, cfg, spec, cache_cfg, cache, i, positions, blocks,
+                start, *pooled)
         else:
             a_in, q, k, v = _attn_inputs(x, lw, cfg, spec, positions, h, d)
             cache = write_prefill_kv(cache, cache_cfg, i, k[0], v[0],
@@ -628,7 +768,7 @@ def gpt_decode_step(weights, cfg: ServingModelConfig,
                     tokens: jnp.ndarray, positions: jnp.ndarray,
                     block_tables: jnp.ndarray, seq_lens: jnp.ndarray,
                     write_blocks: jnp.ndarray,
-                    write_offsets: jnp.ndarray):
+                    write_offsets: jnp.ndarray, *pool_slots):
     """Advance every batch row one token against the paged cache;
     returns ``(cache, next_tokens)``.
 
@@ -651,18 +791,25 @@ def gpt_decode_step(weights, cfg: ServingModelConfig,
     interleave — the continuous-batching determinism the serving
     tests prove.  (:func:`decode_logits` is this step before its
     argmax.)
+
+    A model of EVA layers (the pooled cache) takes two more arrays,
+    ``pool_slots`` = ``(pool_blocks, pool_offsets)`` (b,): the summary
+    row a row's completed page pools to, the dump page where the new
+    position completed none (:func:`_eva_decode_attention`).
     """
     cache, logits, counters = decode_logits(
         weights, cfg, cache_cfg, cache, tokens, positions, block_tables,
-        seq_lens, write_blocks, write_offsets)
-    next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        seq_lens, write_blocks, write_offsets, *pool_slots)
+    next_tokens = jnp.argmax(_sampled(logits, cfg),
+                             axis=-1).astype(jnp.int32)
     if counters is not None:
         next_tokens = jnp.concatenate([next_tokens, counters])
     return cache, next_tokens
 
 
 def decode_logits(weights, cfg, cache_cfg, cache, tokens, positions,
-                  block_tables, seq_lens, write_blocks, write_offsets):
+                  block_tables, seq_lens, write_blocks, write_offsets,
+                  *pool_slots):
     """:func:`gpt_decode_step` up to its logits: ``(cache, (b, V)
     logits, the MoE tick counters or None)``."""
     h, d = cache_cfg.num_heads, cache_cfg.head_dim   # per-shard heads
@@ -672,12 +819,18 @@ def decode_logits(weights, cfg, cache_cfg, cache, tokens, positions,
     counters = None
     write = plan_page_write(write_blocks, write_offsets,
                             cache_cfg.block_size)
+    pool_write = plan_page_write(*pool_slots, cache_cfg.block_size) \
+        if pool_slots else None
     for i, lw in enumerate(weights.layers):
         spec = _spec(cfg, i)
         if cfg.mla is not None:
             cache, attn_out = mla_moe.absorbed(
                 x, lw, cfg, spec, cache_cfg, cache, i, positions, write,
                 block_tables, seq_lens)
+        elif _eva(spec):
+            cache, attn_out = _eva_decode_attention(
+                x, lw, cfg, spec, cache_cfg, cache, i, positions, write,
+                pool_write, write_blocks, block_tables, seq_lens)
         else:
             a_in, q, k, v = _attn_inputs(x, lw, cfg, spec, positions, h, d)
             cache = write_token_kv(cache, cache_cfg, i, k, v, write)
@@ -759,6 +912,11 @@ def _extend_hidden(weights, cfg, cache_cfg, cache, tokens, block_tables,
     # one page, and the page write puts them in together
     write = plan_page_write(write_blocks, write_offsets,
                             cache_cfg.block_size)
+    if any(map(_eva, cfg.layers)):
+        raise NotImplementedError(
+            "EVA layers have no multi-token extend step: a chunk of a "
+            "prompt is a window of its own (gpt_prefill_step), and "
+            "speculative verification does not serve the pooled cache")
     for i, lw in enumerate(weights.layers):
         spec = _spec(cfg, i)
         if cfg.mla is not None:
@@ -860,6 +1018,10 @@ def gpt_sequence_logits(weights, cfg: ServingModelConfig,
     b, s = tokens.shape
     h, d = cfg.num_kv_heads, cfg.head_dim
     scale = d ** -0.5
+    if any(map(_eva, cfg.layers)):
+        raise NotImplementedError(
+            "no cache-free whole-sequence forward of EVA layers here: "
+            "benchmarks/reference_evabyte.py is that oracle")
     pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None, :],
                            (b, s))
     x = _embed(weights, tokens, pos, cfg)

@@ -39,7 +39,7 @@ import jax.numpy as jnp
 
 __all__ = ["RopeSpec", "LayerSpec", "RopeMoELayerWeights",
            "RopeMoEWeights", "init_rope_moe_weights",
-           "MOE_TICK_COUNTERS", "rope_inv_freq"]
+           "MOE_TICK_COUNTERS", "rope_inv_freq", "pool_chunks"]
 
 # What a decode step of this family appends to its ``next_tokens``
 # (int32, summed over the MoE layers, live rows only): the distinct
@@ -75,6 +75,13 @@ class LayerSpec:
     window: Optional[int]          # None: full causal attention
     rope: RopeSpec
     moe: bool                      # False: a dense SwiGLU MLP
+    # an EVA layer (``chunk`` set): ``window`` is ALIGNED, not sliding --
+    # position t sees the positions <= t of its own window
+    # floor(t / window) exactly and, of every earlier window, one pooled
+    # (key, value) a ``chunk`` of positions (:func:`pool_chunks`); no
+    # output gate.  Served from the pooled cache (``KVCacheConfig.window``),
+    # whose pages are a chunk long.
+    chunk: Optional[int] = None
 
 
 class RopeMoELayerWeights(NamedTuple):
@@ -98,6 +105,9 @@ class RopeMoELayerWeights(NamedTuple):
     s1: Optional[jnp.ndarray]      # (H, Fs) the shared expert
     s3: Optional[jnp.ndarray]
     s2: Optional[jnp.ndarray]      # (Fs, H)
+    # an EVA layer's two learned vectors a head (and no ``wg``)
+    phi: Optional[jnp.ndarray] = None   # (kv_heads, d) fp32: pooling query
+    mu: Optional[jnp.ndarray] = None    # (kv_heads, d) fp32: pooled-key bias
 
 
 class RopeMoEWeights(NamedTuple):
@@ -110,15 +120,19 @@ class RopeMoEWeights(NamedTuple):
     mtp: Optional[NamedTuple] = None   # mla_moe.MtpWeights, where served
 
 
-def init_rope_moe_weights(key, cfg, *, dense_ffn: int, expert_ffn: int,
-                          shared_ffn: int,
+def init_rope_moe_weights(key, cfg, *, dense_ffn: int, expert_ffn: int = 0,
+                          shared_ffn: int = 0,
                           std: float = 0.02) -> RopeMoEWeights:
     """Seeded random weights for ``cfg`` (a ``rope_moe``
     ``ServingModelConfig``), every leaf made on the device in ONE
     jitted call: matrices normal(0, ``std``) in ``cfg.dtype``, the
     router normal(0, ``std``) in float32, norm weights 1 + normal(0,
     0.1) in float32 (not all-ones, so that a norm weight left out
-    shows)."""
+    shows; normal(0, 0.1) about the offset where the norm adds the 1
+    itself, ``cfg.norm_unit_offset``).  An EVA layer's ``phi`` and
+    ``mu`` are normal(0, 1) in float32, so that its pooling weights are
+    far from uniform and a wrong pooling moves the logits; the head is
+    ``cfg.pred_heads`` vocabularies wide."""
     if cfg.family != "rope_moe":
         raise ValueError(f"init_rope_moe_weights: family {cfg.family!r}")
     hidden, d = cfg.hidden_size, cfg.head_dim
@@ -133,16 +147,21 @@ def init_rope_moe_weights(key, cfg, *, dense_ffn: int, expert_ffn: int,
                                             jnp.float32)).astype(dtype)
 
         def norm():
-            return 1.0 + 0.1 * jax.random.normal(next(keys), (hidden,),
-                                                 jnp.float32)
+            return (0.0 if cfg.norm_unit_offset else 1.0) \
+                + 0.1 * jax.random.normal(next(keys), (hidden,), jnp.float32)
 
         layers = []
         for spec in cfg.layers:
             h = spec.num_heads
+            eva = spec.chunk is not None
             attn = dict(norm1=norm(), wq=mat(hidden, h * d),
                         wk=mat(hidden, kv * d), wv=mat(hidden, kv * d),
-                        wg=mat(hidden, h), wo=mat(h * d, hidden),
-                        norm2=norm())
+                        wg=None if eva else mat(hidden, h),
+                        wo=mat(h * d, hidden), norm2=norm())
+            if eva:
+                attn.update(
+                    phi=jax.random.normal(next(keys), (kv, d), jnp.float32),
+                    mu=jax.random.normal(next(keys), (kv, d), jnp.float32))
             none = dict.fromkeys(RopeMoELayerWeights._fields)
             if spec.moe:
                 mlp = dict(router=mat(hidden, e, dtype=jnp.float32),
@@ -159,7 +178,8 @@ def init_rope_moe_weights(key, cfg, *, dense_ffn: int, expert_ffn: int,
             layers.append(RopeMoELayerWeights(**{**none, **attn, **mlp}))
         return RopeMoEWeights(embed=mat(cfg.vocab_size, hidden),
                               layers=tuple(layers), norm_f=norm(),
-                              head=mat(hidden, cfg.vocab_size))
+                              head=mat(hidden,
+                                       cfg.pred_heads * cfg.vocab_size))
 
     return jax.jit(make)(key)
 
@@ -172,9 +192,12 @@ def _mm(x, w):
                    preferred_element_type=jnp.float32)
 
 
-def rms_norm(x, w, eps):
-    """``x / sqrt(mean(x^2) + eps) * w`` in float32."""
+def rms_norm(x, w, eps, unit_offset: bool = False):
+    """``x / sqrt(mean(x^2) + eps) * w`` in float32; ``unit_offset``:
+    times ``1 + w``, the weight held about zero."""
     x = x.astype(jnp.float32)
+    if unit_offset:
+        w = 1.0 + w
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * w
 
 
@@ -238,16 +261,29 @@ def qkv(a_in, lw: RopeMoELayerWeights, spec: LayerSpec, cfg, positions):
 def attn_out(ctx, a_in, lw: RopeMoELayerWeights, spec: LayerSpec, cfg):
     """Attention context (..., heads, d) -> the block's attention
     branch (..., H): each head scaled by its sigmoid gate (from the
-    normed input), then the output projection."""
-    gate = jax.nn.sigmoid(_mm(a_in, lw.wg))                # (..., heads)
-    ctx = ctx.astype(jnp.float32) * gate[..., None]
+    normed input; an EVA layer has none), then the output projection."""
+    if lw.wg is not None:
+        gate = jax.nn.sigmoid(_mm(a_in, lw.wg))            # (..., heads)
+        ctx = ctx.astype(jnp.float32) * gate[..., None]
     lead = ctx.shape[:-2]
     return _mm(ctx.reshape(*lead, spec.num_heads * cfg.head_dim), lw.wo)
 
 
-def head_logits(x, weights: RopeMoEWeights, eps):
+def head_logits(x, weights: RopeMoEWeights, eps, unit_offset: bool = False):
     """Final RMSNorm and the untied head: float32 logits."""
-    return _mm(rms_norm(x, weights.norm_f, eps), weights.head)
+    return _mm(rms_norm(x, weights.norm_f, eps, unit_offset), weights.head)
+
+
+def pool_chunks(k, v, lw: RopeMoELayerWeights, scale: float):
+    """EVA's chunk pooling: ``k``, ``v`` (..., chunk, heads, d), the
+    rotated keys and the values of whole chunks, to one pooled key and
+    value (..., heads, d) a chunk, float32.  With the head's learned
+    ``phi`` and ``mu``: ``a_j = softmax_j(scale * k_j . phi)`` over the
+    chunk's positions, ``k~ = sum_j a_j k_j + mu``, ``v~ = sum_j a_j
+    v_j``."""
+    k, v = k.astype(jnp.float32), v.astype(jnp.float32)
+    a = jax.nn.softmax(scale * jnp.sum(k * lw.phi, -1), axis=-2)[..., None]
+    return jnp.sum(a * k, -3) + lw.mu, jnp.sum(a * v, -3)
 
 
 def _swiglu(m, w1, w3, w2):

@@ -158,6 +158,10 @@ class TPContext:
                 + (" (a latent cache has no head axis to split: the "
                    "absorbed projections would be)"
                    if model_cfg.mla is not None else ""))
+        if cache_cfg.pooled:
+            raise ValueError(
+                "the pooled cache (KVCacheConfig.window) has no "
+                "tensor-parallel serving forward yet; serve it on one chip")
         if tp < 2:
             raise ValueError(f"tp {tp} must be >= 2 (tp=1 is the "
                              f"single-chip engine, no context needed)")

@@ -501,6 +501,26 @@ def _openpangu_two_layers():
     return cfg, weights
 
 
+EVA_BLOCKS, EVA_WINDOW, EVA_SEQ = 4097, 2048, 32768
+
+
+def _evabyte_two_layers():
+    """The ``rope_moe`` family's EVA layer at EvaByte's widths: 32
+    heads of 128, SwiGLU of 11,008, window 2,048 beside a pooled row a
+    16-byte chunk, 320 bytes, eight prediction heads."""
+    spec = serving.LayerSpec(
+        num_heads=32, window=EVA_WINDOW, moe=False, chunk=KV_BLOCK,
+        rope=serving.RopeSpec(theta=1e5, rotary_dim=128))
+    cfg = serving.ServingModelConfig(
+        vocab_size=320, hidden_size=4096, num_heads=32, num_layers=2,
+        max_seq=EVA_SEQ, dtype=BF16, layernorm_eps=1e-5, head_dim=128,
+        num_kv_heads=32, family="rope_moe", layers=(spec, spec),
+        norm_unit_offset=True, pred_heads=8)
+    weights = jax.eval_shape(lambda: serving.init_rope_moe_weights(
+        jax.random.PRNGKey(0), cfg, dense_ffn=11008, std=0.01275))
+    return cfg, weights
+
+
 # name -> (model, pool blocks, step, batch rung, page rung, chunk; a
 # prefill's chunk is its prompt rung)
 PROGRAMS = {
@@ -519,6 +539,12 @@ PROGRAMS = {
                                   "decode", LAT_B, 288, 0),
     "openpangu_extend_b64_t2_p288": (_openpangu_two_layers, LAT_BLOCKS,
                                      "extend", LAT_B, 288, 2),
+    "evabyte_decode_b16_p256": (_evabyte_two_layers, EVA_BLOCKS, "decode",
+                                16, 256, 0),
+    "evabyte_chunk_2048": (_evabyte_two_layers, EVA_BLOCKS, "prefill",
+                           1, 128, EVA_WINDOW),
+    "evabyte_chunk_512": (_evabyte_two_layers, EVA_BLOCKS, "prefill",
+                          1, 32, 512),
 }
 
 
@@ -537,12 +563,21 @@ def compile_serving_step(name, sharding, make=None):
     def ints(*shape):
         return jax.ShapeDtypeStruct(shape, I32, sharding=sharding)
 
+    # the pooled cache: a decode step is told where a completed page
+    # pools to, a prefill is one window-aligned chunk (its start, the
+    # summary pages before it, those its own pages pool to)
     if step == "decode":
         fn, data = serving_model.gpt_decode_step, (
             ints(bb), ints(bb), ints(bb, pb), ints(bb), ints(bb), ints(bb))
+        if ccfg.pooled:
+            data += (ints(bb), ints(bb))
     elif step == "prefill":
         fn, data = serving_model.gpt_prefill_step, (
             ints(t), ints(), ints(t // KV_BLOCK))
+        if ccfg.pooled:
+            data += (ints(), ints((cfg.max_seq - 1) // ccfg.window
+                                  * ccfg.window_summary_pages),
+                     ints(t // KV_BLOCK ** 2))
     else:
         fn, data = serving_model.gpt_extend_step, (
             ints(bb, t), ints(bb, pb), ints(bb), ints(bb, t), ints(bb, t))
@@ -563,14 +598,19 @@ def test_serving_step_keeps_cache_layout(name, one_chip, mosaic,
     moved = [op for op in ops if not op[2]]
     assert not moved, f"cache-sized ops that are not in place: {moved}"
     # a layer's k and v (or its latents) are each written once, where
-    # they lie
-    assert len(ops) == leaves, ops
+    # they lie; the pooled cache twice, the new rows to the window's
+    # pages and the pooled rows to the summary pages
+    assert len(ops) == leaves * (2 if ccfg.pooled else 1), ops
     header = text[:text.index("\n")]
     aliased = {int(p) for p in re.findall(
         r"\{\d+\}: \((\d+), \{\}, may-alias\)", header)}
     assert aliased == set(range(n_weights, n_weights + leaves)), header
     temp = compiled.memory_analysis().temp_size_in_bytes
-    assert temp < TEMP_LIMIT, f"{temp / 2**20:.0f} MiB of temporaries"
+    # a 2,048-byte chunk's float32 activations at these widths are
+    # 32-86 MiB each (277 MiB of temporaries in all): whole activations,
+    # far from a cache leaf's 2 GiB
+    limit = 5 * TEMP_LIMIT if name == "evabyte_chunk_2048" else TEMP_LIMIT
+    assert temp < limit, f"{temp / 2**20:.0f} MiB of temporaries"
 
 
 _DEFINITION = re.compile(r"^\s*(?:ROOT )?(%[\w.-]+) = (\w+)\[([\d,]*)\]")
