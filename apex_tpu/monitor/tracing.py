@@ -14,7 +14,9 @@ in four pieces:
   as the device plane, so a host phase and the device's ops share one
   clock and an idle gap of the device can be put down to what the host
   was doing.  Names are ``apex.<layer>.<phase>``
-  (docs/api/observability.md has the table).
+  (docs/api/observability.md has the table).  :func:`watch_collector`
+  adds one span the program does not open itself: ``apex.host.gc``,
+  round each of Python's garbage collections.
 * :class:`SpanTracer` — what :func:`span` also records into when one
   is installed (:func:`set_tracer`, :class:`TraceSession`):
   thread-and-process-aware monotonic timing on the host's own clock.
@@ -58,6 +60,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import gc
 import json
 import os
 import threading
@@ -74,7 +77,7 @@ logger = get_logger(__name__)
 
 __all__ = [
     "Span", "SpanTracer", "get_tracer", "set_tracer", "span",
-    "recording",
+    "recording", "watch_collector",
     "StepWaterfall", "WATERFALL_PARTS",
     "DeviceMetricsBuffer", "MetricsBufferState", "DeferredTelemetry",
     "CaptureTrigger", "TraceSession",
@@ -298,9 +301,13 @@ def get_tracer() -> Optional[SpanTracer]:
 
 
 def set_tracer(tracer: Optional[SpanTracer]) -> None:
-    """Publish (or clear, with None) the process-wide tracer."""
+    """Publish (or clear, with None) the process-wide tracer.  It also
+    takes the collector's hook out (:func:`watch_collector`): a new
+    recorder gets ``apex.host.gc`` spans once a serving step re-arms it,
+    and none from a hook that an earlier profiler session left."""
     global _GLOBAL_TRACER
     _GLOBAL_TRACER = tracer
+    watch_collector(False)
 
 
 class _ProfilerSpan(TraceAnnotation):
@@ -363,6 +370,35 @@ def recording() -> bool:
     """Whether a :func:`span` opened now lands anywhere: a profiler
     session is on, or a tracer is installed."""
     return _GLOBAL_TRACER is not None or TraceAnnotation.is_enabled()
+
+
+_OPEN_COLLECTION: List[Any] = []     # the span of the collection running
+
+
+def _collection_span(phase: str, info: Dict[str, Any]) -> None:
+    """The ``gc.callbacks`` hook: ``apex.host.gc`` (stat ``generation``)
+    from a collection's start to its stop.  CPython runs one collection
+    at a time and calls the hook on the thread that collects, so the
+    span lands on that thread's line."""
+    if phase == "start":
+        s = span("apex.host.gc", generation=info["generation"])
+        s.__enter__()
+        _OPEN_COLLECTION.append(s)
+    elif _OPEN_COLLECTION:
+        _OPEN_COLLECTION.pop().__exit__(None, None, None)
+
+
+def watch_collector(on: bool) -> None:
+    """Put the collector's hook into ``gc.callbacks`` (``on``) or take
+    it out; idempotent and process-wide.  ``ServingEngine.step()`` calls
+    it with :func:`recording` at its start, so the hook is there while a
+    profiler or a tracer records, and with tracing off ``gc.callbacks``
+    holds nothing of ours (jax keeps a callback of its own there)."""
+    hooked = _collection_span in gc.callbacks
+    if on and not hooked:
+        gc.callbacks.append(_collection_span)
+    elif hooked and not on:
+        gc.callbacks.remove(_collection_span)
 
 
 def _chrome_json(events: List[dict], *, pid: int,

@@ -49,7 +49,7 @@ import numpy as np
 
 from ..analysis.flags import flag_bool, flag_float, flag_int, flag_str
 from ..monitor.export import MetricsRegistry
-from ..monitor.tracing import recording, span
+from ..monitor.tracing import recording, span, watch_collector
 from ..utils.log_util import get_logger
 from .kv_cache import (DUMP_BLOCK, KVCacheConfig, KVCacheManager,
                        PrefixMatch, init_cache)
@@ -1066,21 +1066,24 @@ class ServingEngine:
             tokens[:p_len] = req.prompt
             with span("apex.serve.prefill"):
                 fn = self._prefill_fn(s_pad)
-                self.cache, next_token = fn(
-                    self.weights, self.cache, jnp.asarray(tokens),
-                    jnp.int32(p_len), jnp.asarray(bt))
-                if self.draft_cache is not None:
-                    dfn = self._draft_prefill_fn(s_pad)
-                    self.draft_cache, _ = dfn(
-                        self.draft_weights, self.draft_cache,
-                        jnp.asarray(tokens), jnp.int32(p_len),
-                        jnp.asarray(bt))
-                if self._mtp:                # [first token, its draft]
-                    first, req.draft = (int(t) for t in
-                                        np.asarray(next_token))
-                else:
-                    first = int(next_token)  # explicit host sync: the
-                # admission boundary needs the token to seed the decode
+                with span("apex.serve.prefill.dispatch"):
+                    self.cache, next_token = fn(
+                        self.weights, self.cache, jnp.asarray(tokens),
+                        jnp.int32(p_len), jnp.asarray(bt))
+                    if self.draft_cache is not None:
+                        dfn = self._draft_prefill_fn(s_pad)
+                        self.draft_cache, _ = dfn(
+                            self.draft_weights, self.draft_cache,
+                            jnp.asarray(tokens), jnp.int32(p_len),
+                            jnp.asarray(bt))
+                with span("apex.serve.prefill.fetch"):
+                    # explicit host sync: the admission boundary needs
+                    # the token to seed the decode
+                    if self._mtp:            # [first token, its draft]
+                        first, req.draft = (int(t) for t in
+                                            np.asarray(next_token))
+                    else:
+                        first = int(next_token)
             dt = self._clock() - t0
             req.out_tokens.append(first)
             req.token_latency_s.append(dt)
@@ -1140,22 +1143,25 @@ class ServingEngine:
         t0 = self._clock()
         with span("apex.serve.prefill"):
             fn = self._extend_fn(1, ct, pb)
-            self.cache, out = fn(
-                self.weights, self.cache, jnp.asarray(toks[None]),
-                jnp.asarray(bt[None]), jnp.asarray(sl),
-                jnp.asarray(wb[None]), jnp.asarray(wo[None]))
-            if self.draft_cache is not None:
-                dfn = self._draft_extend_fn(1, ct, pb)
-                self.draft_cache, _ = dfn(
-                    self.draft_weights, self.draft_cache,
-                    jnp.asarray(toks[None]), jnp.asarray(bt[None]),
-                    jnp.asarray(sl), jnp.asarray(wb[None]),
-                    jnp.asarray(wo[None]))
+            with span("apex.serve.prefill.dispatch"):
+                self.cache, out = fn(
+                    self.weights, self.cache, jnp.asarray(toks[None]),
+                    jnp.asarray(bt[None]), jnp.asarray(sl),
+                    jnp.asarray(wb[None]), jnp.asarray(wo[None]))
+                if self.draft_cache is not None:
+                    dfn = self._draft_extend_fn(1, ct, pb)
+                    self.draft_cache, _ = dfn(
+                        self.draft_weights, self.draft_cache,
+                        jnp.asarray(toks[None]), jnp.asarray(bt[None]),
+                        jnp.asarray(sl), jnp.asarray(wb[None]),
+                        jnp.asarray(wo[None]))
             job.written += n
             self.prefill_chunks += 1
-            done = job.written >= p_len
-            first = int(np.asarray(out)[0, -1]) if done else None
-            # ^ the only host sync: non-final chunks stay async
+            first = None
+            if job.written >= p_len:
+                # the only host sync: non-final chunks stay async
+                with span("apex.serve.prefill.fetch"):
+                    first = int(np.asarray(out)[0, -1])
         return self._chunk_done(job, n, first, self._clock() - t0)
 
     def _prefill_window(self, job: _PrefillJob, ct: int, n: int) -> bool:
@@ -1180,14 +1186,18 @@ class ServingEngine:
         pool[:len(summaries) - closed] = summaries[closed:]
         t0 = self._clock()
         with span("apex.serve.prefill"):
-            self.cache, out = self._prefill_fn(ct)(
-                self.weights, self.cache, toks, np.int32(n), blocks,
-                np.int32(start), table, pool)
+            fn = self._prefill_fn(ct)
+            with span("apex.serve.prefill.dispatch"):
+                self.cache, out = fn(
+                    self.weights, self.cache, toks, np.int32(n), blocks,
+                    np.int32(start), table, pool)
             freed = len(mgr.close_window(rid))
             job.written += n
             self.prefill_chunks += 1
-            done = job.written >= len(job.tokens)
-            first = int(out) if done else None   # the only host sync
+            first = None
+            if job.written >= len(job.tokens):
+                with span("apex.serve.prefill.fetch"):
+                    first = int(out)             # the only host sync
         rows = start // bs                  # pooled rows before the chunk
         self._tick_eva.update(
             eva_chunks=1, eva_chunk_tokens=n, eva_chunk_summary_rows=rows,
@@ -1445,6 +1455,8 @@ class ServingEngine:
         bucketed decode step — speculative when ``speculate_k > 0`` —
         over every active request.  Returns the number of tokens
         generated this tick."""
+        # Python's collections get a span while something records
+        watch_collector(recording())
         with span("apex.serve.step") as tick:
             self._tick_levels = None
             self._tick_mtp = {}
