@@ -62,7 +62,7 @@ class StepInfo(NamedTuple):
     # gradients, so grads_finite==True means "unchecked", not "healthy".
     grads_checked: bool = True
     # Unscaled global gradient L2 norm, measured by the fused
-    # pipeline's norm sweep (None on the per-stage path, which never
+    # pipeline's norm sweep (None on the per-leaf path, which never
     # computes one).  Telemetry consumers (StepMonitor) read it from
     # here instead of re-sweeping the gradient tree host-side; under
     # shard_map it is the LOCAL shard's norm.
@@ -87,17 +87,26 @@ class AmpOptimizer:
         self.policy = policy
         self.num_losses = int(num_losses)
         self.use_masters = bool(policy.master_weights)
-        # Persistent packed pipeline (ops/fused_pipeline.py): masters +
-        # optimizer state live in packed flat fp32 buffers across
-        # steps and the whole post-backward step is two fused sweeps.
-        # None resolves via APEX_TPU_FUSED_PIPELINE (default ON; "0"
-        # is the escape hatch back to the per-stage path), read at
-        # construction.  Requires master weights and an optimizer with
-        # a pipeline form (fused_adam / fused_sgd / fused_lamb); under
-        # the auto default anything else keeps the per-stage path, but
-        # an EXPLICIT pipeline=True with missing prerequisites raises —
-        # a silent staged fallback would corrupt pipeline-vs-staged
-        # comparisons (bench) and user expectations.
+        # Where the masters and the optimizer state lie.  By default
+        # (pipeline=None) every leaf keeps its own master and state in
+        # its own shape: the fused optimizers' fused_step calls one
+        # jitted leaf program a matrix leaf and one for all the vectors
+        # (optimizers/fused_adam.py LeafProgram, _step_calls), so the
+        # train step's tracing grows with the distinct leaf signatures
+        # and no matrix is packed.  Packing lost wherever it was
+        # measured on v5e: per-step packs at every size tried
+        # (ops/multi_tensor.py's log), the persistent pack at 0.73x on
+        # small trees, and at 345M the per-leaf step ran GPT-2's train
+        # step at 142.1 ms against the packed 148.7 and BERT-large's at
+        # 140.7 against 150.1 (the packing alone is 5.6 ms of GPT-2's
+        # 15.7 ms optimizer: gradients laid into flat buffers, bf16
+        # weights cut back out).
+        # An EXPLICIT pipeline=True still builds the persistent packed
+        # pipeline (ops/fused_pipeline.py: packed masters and state,
+        # two fused sweeps); it needs master weights and an optimizer
+        # with a pipeline form (fused_adam / fused_sgd / fused_lamb)
+        # and raises without them — a silent per-leaf fallback would
+        # corrupt packed-vs-per-leaf comparisons.
         capable = (self.use_masters
                    and getattr(tx, "pipeline_step", None) is not None)
         if pipeline and not capable:
@@ -109,16 +118,14 @@ class AmpOptimizer:
                 f"{bool(policy.master_weights)}, tx "
                 f"{type(tx).__name__} with pipeline_step="
                 f"{getattr(tx, 'pipeline_step', None)}")
-        self.use_pipeline = capable and _pipeline.pipeline_enabled(
-            pipeline)
-        # An explicit pipeline=True is a hard routing request (bench
-        # pipeline-vs-staged comparisons depend on it); the auto
-        # decision additionally applies the packed-size cutoff at
-        # init() time, when the tree is first seen (the 0.73x
-        # small-tree residue: below APEX_TPU_PIPELINE_PACK_MIN_BYTES
-        # of packed model bytes, direct per-leaf staged updates
-        # measured faster than the persistent pack).
         self._pipeline_explicit = pipeline is True
+        # What the last traced step did, static facts no metric reads
+        # (docs/api/observability.md): ``route`` ("per_leaf" or
+        # "packed", from the state's layout), ``leaves_stepped`` (the
+        # model tree's leaves); ``leaf_programs`` (a property) counts
+        # the leaf programs the optimizer has traced.
+        self.route: Optional[str] = None
+        self.leaves_stepped = 0
         # Model-parallel axes to reduce the found-inf flag over, so every
         # shard takes the same skip-vs-step branch (ref:
         # apex/transformer/amp/grad_scaler.py:25-36).  Only meaningful
@@ -141,7 +148,7 @@ class AmpOptimizer:
         from them (the reference likewise clones masters from the fp32
         model before it is cast, ref: apex/amp/_process_optimizer.py:28-44).
         """
-        if self._route_pipeline(params):
+        if self._pipeline_explicit:
             # Persistent packed mode: the master "tree" is a
             # PackedMasters (flat fp32 buffers + static layout), the
             # inner state packs into the same layout.  The layout is
@@ -167,29 +174,14 @@ class AmpOptimizer:
             ),
         )
 
-    def _route_pipeline(self, params: Any) -> bool:
-        """The init-time pipeline routing decision for this tree.
-        Explicit ``pipeline=True`` always packs; the auto decision
-        routes trees below ``APEX_TPU_PIPELINE_PACK_MIN_BYTES`` of
-        packed model bytes to the direct per-leaf staged path — the
-        regime where the persistent pack measured 0.73x vs direct
-        (ROADMAP item 4; the flag table in docs/api/ops.md has the
-        cutoff's provenance)."""
-        if not self.use_pipeline:
-            return False
-        if self._pipeline_explicit:
-            return True
-        from ..analysis.flags import flag_int
-
-        cutoff = flag_int("APEX_TPU_PIPELINE_PACK_MIN_BYTES")
-        if cutoff <= 0:
-            return True
-        # shapes/dtypes only: eval_shape keeps the probe off-device (a
-        # real cast here would allocate a full low-precision model
-        # copy just to read its byte total)
-        model_template = jax.eval_shape(
-            lambda p: _cast.cast_params(p, self.policy), params)
-        return _pipeline.packed_nbytes(model_template) >= cutoff
+    @property
+    def leaf_programs(self) -> int:
+        """Leaf programs the optimizer has traced: one per distinct leaf
+        signature and program kind (the per-leaf step, and the norm's
+        sum of squares where a clip is on); 0 for an optimizer without
+        them and on the packed route."""
+        return sum(len(p.signatures)
+                   for p in getattr(self.tx, "leaf_programs", ()))
 
     # -- per-iteration hooks ------------------------------------------------
 
@@ -216,53 +208,56 @@ class AmpOptimizer:
         to explicitly disable the reduction for this call (e.g. when
         stepping the same optimizer outside shard_map).
         """
-        # Dispatch on the STATE's layout, not the constructor flag:
-        # the auto pipeline decision is per-tree (init() applies the
-        # packed-size cutoff), and a checkpoint-restored state must
-        # step the way it was built.  Either way every op of the
-        # post-backward step carries the scope ``apex.optimizer`` in
-        # its name (metadata only: the compiled program is the same),
-        # which is how a device trace gives the optimizer's time.
+        # Dispatch on the STATE's layout, not the constructor flag: a
+        # checkpoint-restored state must step the way it was built.
+        # Either way every op of the post-backward step carries the
+        # scope ``apex.optimizer`` in its name (metadata only: the
+        # compiled program is the same; a leaf program's ops read
+        # ``apex.optimizer/jit(<program>)/...`` once the TPU compiler
+        # has inlined its calls), which is how a device trace gives
+        # the optimizer's time.
         packed = isinstance(state.master_params, _pipeline.PackedMasters)
         apply = (self._apply_gradients_pipeline if packed
                  else self._apply_gradients_per_leaf)
+        self.route = "packed" if packed else "per_leaf"
+        self.leaves_stepped = len(jax.tree_util.tree_leaves(params))
         with jax.named_scope("apex.optimizer"):
             return apply(scaled_grads, state, params, loss_id,
                          axis_names)
 
     def _apply_gradients_per_leaf(self, scaled_grads, state, params,
                                   loss_id, axis_names):
-        """The per-stage post-backward step: unscale, finite check,
-        conditional update, master -> model cast."""
+        """The per-leaf post-backward step: finite check, conditional
+        update, master -> model cast.  A fused optimizer takes the
+        scaled gradients and amp's inverse loss scale as its
+        ``grad_scale``: the unscale is one multiply inside each leaf
+        program, not a tree of its own (exact either way: the scale
+        schedule only holds powers of two).  Any other optimizer gets
+        an unscaled fp32 gradient tree."""
         scaler = state.scalers[loss_id]
-        fused_capable = getattr(self.tx, "fused_step", None) is not None
-        # Single-pass optimizers upcast per-leaf inside their update
-        # loop, so unscale in the gradient dtype (exact: power-of-two
-        # scales) instead of materializing an fp32 grad tree.
-        grads32 = _scaler.unscale(scaled_grads, scaler,
-                                  out_dtype=None if fused_capable
-                                  else jnp.float32)
+        inv = (1.0 / scaler.loss_scale).astype(jnp.float32)
+        # Single-pass optimizers (FusedTransformation.fused_step) apply
+        # the update AND emit the low-precision model copy inside the
+        # update's fusion scope — XLA does not multi-output-fuse a
+        # separate restore_dtypes pass (measured 2.1 ms/step of pure
+        # master->bf16 convert at GPT-345M).
+        fused = getattr(self.tx, "fused_step", None)
+        grads = (scaled_grads if fused is not None
+                 else _scaler.unscale(scaled_grads, scaler))
         if axis_names is None:
             axis_names = self.axis_names
 
         stepped = state.master_params if self.use_masters else params
-        # Single-pass optimizers (FusedTransformation.fused_step) apply
-        # the update AND emit the low-precision model copy inside the
-        # update kernel — XLA does not multi-output-fuse the separate
-        # restore_dtypes pass (measured 2.1 ms/step of pure master->
-        # bf16 convert at GPT-345M).
-        fused = getattr(self.tx, "fused_step", None)
 
         def do_step(operand):
-            grads32_, inner_, stepped_, model_ = operand
+            grads_, inner_, stepped_, model_ = operand
             if fused is not None:
-                # fused_step upcasts per leaf inside its own fused
-                # loop — no _grads_like tree materialization
                 new_stepped, new_inner, new_model = fused(
-                    grads32_, inner_, stepped_,
-                    model_params=model_ if self.use_masters else None)
+                    grads_, inner_, stepped_,
+                    model_params=model_ if self.use_masters else None,
+                    grad_scale=inv)
             else:
-                g = _grads_like(grads32_, stepped_)
+                g = _grads_like(grads_, stepped_)
                 updates, new_inner = self.tx.update(g, inner_, stepped_)
                 new_stepped = optax.apply_updates(stepped_, updates)
                 new_model = None
@@ -284,9 +279,14 @@ class AmpOptimizer:
             # ("unchecked") — see StepInfo.
             finite = jnp.bool_(True)
             new_stepped, new_inner, new_model = do_step(
-                (grads32, state.inner_state, stepped, params))
+                (grads, state.inner_state, stepped, params))
         else:
-            finite = _scaler.all_finite(grads32, axis_names=axis_names)
+            # the flag of the unscaled gradients, as the update sees
+            # them; the product fuses into the reduction
+            finite = _scaler.all_finite(
+                grads if fused is None else jax.tree_util.tree_map(
+                    lambda g: g.astype(jnp.float32) * inv, grads),
+                axis_names=axis_names)
 
             def skip_step(operand):
                 _, inner_, stepped_, model_ = operand
@@ -299,7 +299,7 @@ class AmpOptimizer:
 
             new_stepped, new_inner, new_model = jax.lax.cond(
                 finite, do_step, skip_step,
-                (grads32, state.inner_state, stepped, params))
+                (grads, state.inner_state, stepped, params))
 
         if self.use_masters:
             new_params = new_model
@@ -338,7 +338,7 @@ class AmpOptimizer:
     def _apply_gradients_pipeline(self, scaled_grads, state, params,
                                   loss_id, axis_names):
         """The persistent-packed post-backward step: TWO fused sweeps
-        instead of the per-stage unscale / finite-check / update /
+        instead of the per-leaf unscale / finite-check / update /
         master->model chain (see ops/fused_pipeline.py).
 
         Sweep 1 reads the packed grads once, producing the unscaled
@@ -347,11 +347,11 @@ class AmpOptimizer:
         writes masters+state+model-copy, with the unscale (and any
         optimizer clip) folded into its combined scale and the
         overflow skip as an in-sweep select.  Skip semantics match the
-        per-stage ``lax.cond`` bitwise: state unchanged, model re-cast
+        per-leaf ``lax.cond`` bitwise: state unchanged, model re-cast
         from the unchanged masters.
 
         Static scaling steps unconditionally (``_resolve_check``) AND
-        elides the norm/finite sweep entirely — the per-stage path
+        elides the norm/finite sweep entirely — the per-leaf path
         deliberately skips that grad-wide pass (measured 14 ms/step at
         GPT-345M) and the pipeline must not re-add it; StepInfo.
         grad_norm is then None (telemetry falls back) and any

@@ -179,23 +179,10 @@ def render_flag_table() -> str:
 # ---------------------------------------------------------------------------
 
 register_flag(
-    "APEX_TPU_FUSED_PIPELINE", "bool", True,
-    "Persistent packed optimizer pipeline under amp master weights "
-    "(docs/api/optimizers.md#persistent-packed-pipeline). `0` is the "
-    "escape hatch back to the per-stage unscale/check/step path.")
-register_flag(
     "APEX_TPU_PIPELINE_PALLAS", "bool", False,
     "Route both fused-pipeline sweeps through the Pallas kernels "
     "instead of the jnp twins (auto stays jnp per the measured "
     "880-vs-190 GB/s elementwise-stream gap).")
-register_flag(
-    "APEX_TPU_PIPELINE_PACK_MIN_BYTES", "int", 1 << 27,
-    "Packed-size cutoff (bytes of the model-dtype tree) below which "
-    "the auto pipeline decision (AmpOptimizer(pipeline=None)) routes "
-    "to direct per-leaf staged updates instead of the persistent "
-    "packed pipeline — the measured 0.73x small-tree packing residue "
-    "regime.  Explicit pipeline=True bypasses the cutoff; 0 packs "
-    "every tree.", lo=0)
 register_flag(
     "APEX_TPU_STEP_PALLAS_MIN", "int", 0,
     "Element-count floor above which single-pass STEP optimizer work "

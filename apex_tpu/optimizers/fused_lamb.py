@@ -29,9 +29,10 @@ import jax.numpy as jnp
 import optax
 
 from ..ops import fused_optim, fused_pipeline, multi_tensor
-from .fused_adam import (FusedTransformation, ScalarOrSchedule,
-                         _assemble_model, _grad_clip_factor,
-                         _lowp_dtype_for, _lr_at)
+from .fused_adam import (FusedTransformation, LeafProgram,
+                         ScalarOrSchedule, _assemble_model, _f32, _gather,
+                         _grad_clip_factor, _grad_scale, _lowp_dtype_for,
+                         _lr_at, _scatter, _step_calls, _sumsq_program)
 
 
 class FusedLAMBState(NamedTuple):
@@ -61,6 +62,14 @@ def fused_lamb(learning_rate: ScalarOrSchedule = 1e-3,
         raise ValueError("fused_lamb requires eps > 0 "
                          "(packed padding-gap invariant)")
     LANE = multi_tensor.LANE
+    beta3 = (1.0 - beta1) if grad_averaging else 1.0
+
+    def _bias_corrections(count):
+        cf = count.astype(jnp.float32)
+        if bias_correction:
+            return (1.0 - jnp.float32(beta1) ** cf,
+                    1.0 - jnp.float32(beta2) ** cf)
+        return jnp.float32(1.0), jnp.float32(1.0)
 
     def init(params):
         metas = multi_tensor.compute_metas(params, align=LANE,
@@ -76,13 +85,7 @@ def fused_lamb(learning_rate: ScalarOrSchedule = 1e-3,
         phase-1 math upcasts per group."""
         count = state.count + 1
         lr = _lr_at(learning_rate, count)
-        cf = count.astype(jnp.float32)
-        if bias_correction:
-            bc1 = 1.0 - jnp.float32(beta1) ** cf
-            bc2 = 1.0 - jnp.float32(beta2) ** cf
-        else:
-            bc1 = bc2 = jnp.float32(1.0)
-        beta3 = (1.0 - beta1) if grad_averaging else 1.0
+        bc1, bc2 = _bias_corrections(count)
 
         metas = multi_tensor.compute_metas(params, align=LANE,
                                            split_direct=True)
@@ -117,26 +120,70 @@ def fused_lamb(learning_rate: ScalarOrSchedule = 1e-3,
             deltas, metas, out_dtypes=[l.dtype for l in leaves])
         return updates, new_state
 
-    def fused_step(grads, state, params, model_params=None):
+    def lamb_leaf_step(g, p, m, v, scalars, *, meta, fused, lowp_dtype):
+        """Phase 1, the trust ratio (a direct leaf's two norms, or the
+        per-tensor norms of a packed group's ``meta``) and the apply."""
+        lr, bc1, bc2, gscale = (scalars[i] for i in range(4))
+        adapted_u, m2, v2 = _lamb_group_update(
+            meta, g, p, m, v, gscale=gscale, beta1=beta1, beta2=beta2,
+            beta3=beta3, eps=eps, weight_decay=weight_decay, bc1=bc1,
+            bc2=bc2, adam_w_mode=adam_w_mode, use_nvlamb=use_nvlamb,
+            fused=fused)
+        p2 = (p.astype(jnp.float32) + -lr * adapted_u).astype(p.dtype)
+        return p2, m2, v2, (None if lowp_dtype is None
+                            else p2.astype(lowp_dtype))
+
+    leaf_step = LeafProgram(lamb_leaf_step,
+                            static_argnames=("meta", "fused", "lowp_dtype"))
+    sumsq = _sumsq_program()
+
+    def fused_step(grads, state, params, model_params=None,
+                   grad_scale=None):
         """Single-pass step (+ optional model copy) — see
-        FusedTransformation; the apply and the amp master->model
-        writeback join the update's fusion scope."""
+        FusedTransformation: the global norm is a sum of the sumsq
+        program's outputs, then each group (every leaf, by default) is
+        one call of the leaf program, the vectors one call together
+        (``_step_calls``), whose update, apply and master->model
+        writeback share one fusion scope."""
         if params is None:
             raise ValueError("fused_lamb requires params")
-        metas, pbufs, deltas, new_state = _deltas(grads, state, params)
+        count = state.count + 1
+        lr = _lr_at(learning_rate, count)
+        bc1, bc2 = _bias_corrections(count)
+        metas = multi_tensor.compute_metas(params, align=LANE,
+                                           split_direct=True)
+        gbufs = multi_tensor.group_buffers(grads, metas)
+        pbufs = multi_tensor.group_buffers(params, metas)
         model_leaves = (jax.tree_util.tree_leaves(model_params)
                         if model_params is not None else None)
-        new_p, lowps = [], []
-        for i, meta in enumerate(metas):
-            p2 = (pbufs[i].astype(jnp.float32)
-                  + deltas[i]).astype(pbufs[i].dtype)
-            lowp_dt = _lowp_dtype_for(meta, pbufs[i], model_leaves)
-            new_p.append(p2)
-            lowps.append(p2.astype(lowp_dt) if lowp_dt is not None
-                         else None)
+        lowp_dts = [_lowp_dtype_for(meta, pbufs[i], model_leaves)
+                    for i, meta in enumerate(metas)]
+        # a bundle's vectors start on a lane row each, so its per-tensor
+        # trust-ratio norms are row-aligned slices
+        calls = _step_calls(metas, pbufs, lowp_dts, align=LANE)
+        gs = [_gather(c, gbufs) for c in calls]
+        gscale = _grad_scale(gs, grad_scale, max_grad_norm, sumsq)
+        scalars = _f32(lr, bc1, bc2, gscale)
+        new_p, new_m, new_v, lowps = ([None] * len(metas)
+                                      for _ in range(4))
+        for c, g in zip(calls, gs):
+            first = metas[c.groups[0]]
+            meta = first if c.meta is None else c.meta
+            p2, m2, v2, lp = leaf_step(
+                g, *(_gather(c, b) for b in (pbufs, state.m, state.v)),
+                scalars,
+                # a direct leaf's program must not key on its position
+                meta=None if multi_tensor.is_direct(meta) else meta,
+                # a bundle dispatches as its direct groups would
+                fused=fused_optim.group_use_pallas(use_pallas, first),
+                lowp_dtype=lowp_dts[c.groups[0]])
+            for into, out in ((new_p, p2), (new_m, m2), (new_v, v2),
+                              (lowps, lp)):
+                _scatter(c, out, into)
         leaves = jax.tree_util.tree_leaves(params)
         new_params = multi_tensor.assemble(
             new_p, metas, out_dtypes=[l.dtype for l in leaves])
+        new_state = FusedLAMBState(count, tuple(new_m), tuple(new_v))
         model_out = None
         if model_leaves is not None:
             model_out = _assemble_model(new_p, lowps, metas,
@@ -168,13 +215,7 @@ def fused_lamb(learning_rate: ScalarOrSchedule = 1e-3,
         count = state.count + finite.astype(jnp.int32)
         stepped = state.count + 1
         lr = _lr_at(learning_rate, stepped)
-        cf = stepped.astype(jnp.float32)
-        if bias_correction:
-            bc1 = 1.0 - jnp.float32(beta1) ** cf
-            bc2 = 1.0 - jnp.float32(beta2) ** cf
-        else:
-            bc1 = bc2 = jnp.float32(1.0)
-        beta3 = (1.0 - beta1) if grad_averaging else 1.0
+        bc1, bc2 = _bias_corrections(stepped)
         gscale = jnp.asarray(grad_scale, jnp.float32) \
             * _grad_clip_factor(grad_norm, max_grad_norm)
         fused = fused_pipeline.use_pallas_pipeline(use_pallas)
@@ -199,7 +240,8 @@ def fused_lamb(learning_rate: ScalarOrSchedule = 1e-3,
                 lowps)
 
     return FusedTransformation(init, update, fused_step,
-                               pipeline_init, pipeline_step)
+                               pipeline_init, pipeline_step,
+                               (leaf_step, sumsq))
 
 
 def _global_grad_clip(gbufs, max_norm):
@@ -259,9 +301,10 @@ def _trust_ratio_elem(meta, u, p32, use_nvlamb, weight_decay):
     packed-length index arrays explode program size at BERT-large scale
     (see multi_tensor.per_tensor_sumsq).
 
-    DIRECT groups (one native-shape leaf) reduce over the whole buffer
-    — one scalar ratio, no packing."""
-    if multi_tensor.is_direct(meta):
+    DIRECT groups (one native-shape leaf; ``meta`` None in a leaf
+    program) reduce over the whole buffer — one scalar ratio, no
+    packing."""
+    if meta is None or multi_tensor.is_direct(meta):
         if use_nvlamb or weight_decay != 0.0:
             p_n2 = jnp.sum(p32 * p32)
             u_n2 = jnp.sum(u.astype(jnp.float32) ** 2)

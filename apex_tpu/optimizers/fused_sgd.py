@@ -17,10 +17,11 @@ import jax.numpy as jnp
 import optax
 
 from ..ops import fused_optim, fused_pipeline, multi_tensor
-from .fused_adam import (FusedTransformation, ScalarOrSchedule,
-                         _assemble_model, _clip_enabled,
-                         _grad_clip_factor, _lowp_dtype_for, _lr_at,
-                         _staged_clip)
+from .fused_adam import (FusedTransformation, LeafProgram,
+                         ScalarOrSchedule, _assemble_model, _clip_enabled,
+                         _f32, _gather, _grad_clip_factor, _grad_scale,
+                         _lowp_dtype_for, _lr_at, _scatter, _staged_clip,
+                         _step_calls, _sumsq_program)
 
 
 class FusedSGDState(NamedTuple):
@@ -88,33 +89,53 @@ def fused_sgd(learning_rate: ScalarOrSchedule,
             deltas, metas, out_dtypes=[l.dtype for l in leaves])
         return updates, FusedSGDState(count, tuple(new_mom))
 
-    def fused_step(grads, state, params, model_params=None):
+    def sgd_leaf_step(g, p, mom, scalars, *, lowp_dtype):
+        lr, first, gscale = (scalars[i] for i in range(3))
+        g = g.astype(jnp.float32) * gscale
+        if momentum == 0.0:
+            p32 = p.astype(jnp.float32)
+            p2 = (p32 - lr * (g + weight_decay * p32)).astype(p.dtype)
+            mom2 = mom
+        else:
+            d, mom2 = _sgd_jnp(g, p, mom, lr, momentum, dampening,
+                               weight_decay, nesterov, wd_after_momentum,
+                               first)
+            p2 = p + d
+        return p2, mom2, (None if lowp_dtype is None
+                          else p2.astype(lowp_dtype))
+
+    leaf_step = LeafProgram(sgd_leaf_step, static_argnames=("lowp_dtype",))
+    sumsq = _sumsq_program()
+
+    def fused_step(grads, state, params, model_params=None,
+                   grad_scale=None):
         """Single-pass step (+ optional model copy) — the in-place
-        ``FusedSGD.step()`` analogue; see FusedTransformation."""
+        ``FusedSGD.step()`` analogue; see FusedTransformation.  Each
+        group (every leaf, by default) is one call of the leaf program,
+        the vectors one call together (``_step_calls``)."""
         count = state.count + 1
         lr = _lr_at(learning_rate, count)
         first = (state.count == 0).astype(jnp.float32) if momentum else \
             jnp.float32(0.0)
         metas = multi_tensor.compute_metas(params, split_direct=True)
-        gbufs = _staged_clip(multi_tensor.group_buffers(grads, metas),
-                             max_grad_norm)
+        gbufs = multi_tensor.group_buffers(grads, metas)
         pbufs = multi_tensor.group_buffers(params, metas)
         model_leaves = (jax.tree_util.tree_leaves(model_params)
                         if model_params is not None else None)
-        new_p, new_mom, lowps = [], [], []
-        for i, meta in enumerate(metas):
-            lowp_dt = _lowp_dtype_for(meta, pbufs[i], model_leaves)
-            lp = None
-            if momentum == 0.0:
-                g = gbufs[i].astype(jnp.float32)
-                p32 = pbufs[i].astype(jnp.float32)
-                p2 = (p32 - lr * (g + weight_decay * p32)).astype(
-                    meta.dtype)
-                mom2 = state.momentum[i]
-            elif fused_optim.step_use_pallas(use_pallas,
-                                             sum(meta.sizes)):
+        lowp_dts = [_lowp_dtype_for(meta, pbufs[i], model_leaves)
+                    for i, meta in enumerate(metas)]
+        calls = _step_calls(metas, pbufs, lowp_dts)
+        gs = [_gather(c, gbufs) for c in calls]
+        gscale = _grad_scale(gs, grad_scale, max_grad_norm, sumsq)
+        scalars = _f32(lr, first, gscale)
+        new_p, new_mom, lowps = ([None] * len(metas) for _ in range(3))
+        for c, g in zip(calls, gs):
+            p, mom = (_gather(c, b) for b in (pbufs, state.momentum))
+            lowp_dt = lowp_dts[c.groups[0]]
+            if momentum != 0.0 and fused_optim.step_use_pallas(
+                    use_pallas, sum(sum(metas[i].sizes) for i in c.groups)):
                 flats, restore = fused_optim.flatten_for_kernel(
-                    gbufs[i], pbufs[i], state.momentum[i])
+                    g.astype(jnp.float32) * gscale, p, mom)
                 outs = fused_optim.sgd_step(
                     *flats, lr=lr, momentum=momentum,
                     dampening=dampening, weight_decay=weight_decay,
@@ -122,19 +143,12 @@ def fused_sgd(learning_rate: ScalarOrSchedule,
                     wd_after_momentum=wd_after_momentum,
                     first_run=first, lowp_dtype=lowp_dt)
                 p2, mom2 = restore(outs[0]), restore(outs[1])
-                if lowp_dt is not None:
-                    lp = restore(outs[2])
+                lp = restore(outs[2]) if lowp_dt is not None else None
             else:
-                d, mom2 = _sgd_jnp(gbufs[i], pbufs[i],
-                                   state.momentum[i], lr, momentum,
-                                   dampening, weight_decay, nesterov,
-                                   wd_after_momentum, first)
-                p2 = pbufs[i] + d
-            if lp is None and lowp_dt is not None:
-                lp = p2.astype(lowp_dt)
-            new_p.append(p2)
-            new_mom.append(mom2)
-            lowps.append(lp)
+                p2, mom2, lp = leaf_step(g, p, mom, scalars,
+                                         lowp_dtype=lowp_dt)
+            for into, out in ((new_p, p2), (new_mom, mom2), (lowps, lp)):
+                _scatter(c, out, into)
         leaves = jax.tree_util.tree_leaves(params)
         new_params = multi_tensor.assemble(
             new_p, metas, out_dtypes=[l.dtype for l in leaves])
@@ -195,7 +209,8 @@ def fused_sgd(learning_rate: ScalarOrSchedule,
                 lowps)
 
     return FusedTransformation(init, update, fused_step,
-                               pipeline_init, pipeline_step)
+                               pipeline_init, pipeline_step,
+                               (leaf_step, sumsq))
 
 
 def _sgd_jnp(g, p, mom, lr, momentum, dampening, wd, nesterov,

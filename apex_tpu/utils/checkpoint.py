@@ -60,8 +60,8 @@ class CheckpointFormatMismatch(RuntimeError):
     Raised INSTEAD of letting Orbax fail on an opaque tree-structure
     mismatch (which the integrity fallback would then mistake for a
     torn payload and quarantine a perfectly good checkpoint).  Re-run
-    with the matching mode — ``APEX_TPU_FUSED_PIPELINE=0`` /
-    ``AmpOptimizer(pipeline=...)`` — or re-save under the new one."""
+    with the matching mode — ``AmpOptimizer(pipeline=True)`` packs, the
+    default steps per leaf — or re-save under the new one."""
 
 
 def _manager(directory: str, keep: int):
@@ -402,8 +402,8 @@ class CheckpointManager:
                     f"{'packed' if want_packed else 'per-leaf'} — the "
                     "persistent-pipeline mode changed between save and "
                     "restore.  Re-run with the matching mode "
-                    "(APEX_TPU_FUSED_PIPELINE / AmpOptimizer("
-                    "pipeline=...)) or re-save the checkpoint.")
+                    "(AmpOptimizer(pipeline=True) packs, the default "
+                    "steps per leaf) or re-save the checkpoint.")
             tree = {"params": amp_state.master_params,
                     "inner_state": amp_state.inner_state}
         else:

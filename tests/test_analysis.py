@@ -341,8 +341,8 @@ class TestBaseline:
 
 class TestFlagRegistry:
     def test_defaults_roundtrip(self, monkeypatch):
-        monkeypatch.delenv("APEX_TPU_FUSED_PIPELINE", raising=False)
-        assert flags_mod.flag_bool("APEX_TPU_FUSED_PIPELINE") is True
+        monkeypatch.delenv("APEX_TPU_PIPELINE_PALLAS", raising=False)
+        assert flags_mod.flag_bool("APEX_TPU_PIPELINE_PALLAS") is False
         monkeypatch.delenv("APEX_TPU_STEP_PALLAS_MIN", raising=False)
         assert flags_mod.flag_int("APEX_TPU_STEP_PALLAS_MIN") == 0
         monkeypatch.delenv("APEX_TPU_MONITOR_STALL_S", raising=False)
@@ -351,8 +351,8 @@ class TestFlagRegistry:
         assert flags_mod.flag_str("APEX_TPU_MONITOR_JSONL") is None
 
     def test_env_overrides(self, monkeypatch):
-        monkeypatch.setenv("APEX_TPU_FUSED_PIPELINE", "0")
-        assert flags_mod.flag_bool("APEX_TPU_FUSED_PIPELINE") is False
+        monkeypatch.setenv("APEX_TPU_PIPELINE_PALLAS", "1")
+        assert flags_mod.flag_bool("APEX_TPU_PIPELINE_PALLAS") is True
         monkeypatch.setenv("APEX_TPU_STEP_PALLAS_MIN", "4096")
         assert flags_mod.flag_int("APEX_TPU_STEP_PALLAS_MIN") == 4096
 
@@ -382,15 +382,15 @@ class TestFlagRegistry:
 
     def test_kind_mismatch_raises(self):
         with pytest.raises(TypeError, match="bool flag"):
-            flags_mod.flag_int("APEX_TPU_FUSED_PIPELINE")
+            flags_mod.flag_int("APEX_TPU_PIPELINE_PALLAS")
 
     def test_consumer_reads_per_call(self, monkeypatch):
         from apex_tpu.ops import fused_pipeline
 
-        monkeypatch.setenv("APEX_TPU_FUSED_PIPELINE", "0")
-        assert fused_pipeline.pipeline_enabled() is False
-        monkeypatch.setenv("APEX_TPU_FUSED_PIPELINE", "1")
-        assert fused_pipeline.pipeline_enabled() is True
+        monkeypatch.setenv("APEX_TPU_PIPELINE_PALLAS", "0")
+        assert fused_pipeline.use_pallas_pipeline() is False
+        monkeypatch.setenv("APEX_TPU_PIPELINE_PALLAS", "1")
+        assert fused_pipeline.use_pallas_pipeline() is True
 
     def test_table_lists_every_flag(self):
         table = flags_mod.render_flag_table()

@@ -526,50 +526,41 @@ class TestOverflowBranch:
 # ---------------------------------------------------------------------------
 
 class TestWiring:
-    def test_env_escape_hatch(self, monkeypatch):
+    def test_env_escape_hatch(self):
+        # no environment variable picks the route: the default and an
+        # explicit pipeline=False step per leaf, pipeline=True packs
         policy = amp.get_policy("O5")
-        monkeypatch.setenv("APEX_TPU_FUSED_PIPELINE", "0")
-        assert not amp.AmpOptimizer(fused_adam(1e-3),
-                                    policy).use_pipeline
-        monkeypatch.delenv("APEX_TPU_FUSED_PIPELINE")
-        assert amp.AmpOptimizer(fused_adam(1e-3), policy).use_pipeline
-        # explicit flag beats the env
-        monkeypatch.setenv("APEX_TPU_FUSED_PIPELINE", "0")
-        assert amp.AmpOptimizer(fused_adam(1e-3), policy,
-                                pipeline=True).use_pipeline
+        params = {"w": jnp.ones((8, 128), jnp.float32)}
+        for pipeline in (None, False):
+            opt = amp.AmpOptimizer(fused_adam(1e-3), policy,
+                                   pipeline=pipeline)
+            assert not isinstance(opt.init(params).master_params,
+                                  fp.PackedMasters)
+        opt = amp.AmpOptimizer(fused_adam(1e-3), policy, pipeline=True)
+        assert isinstance(opt.init(params).master_params,
+                          fp.PackedMasters)
 
-    def test_pack_min_bytes_small_tree_routes_direct(self, monkeypatch):
-        # the 0.73x small-tree residue fix: below the packed-size
-        # cutoff the AUTO decision builds staged (per-leaf) state;
-        # explicit pipeline=True still packs
+    def test_pack_min_bytes_small_tree_routes_direct(self):
+        # the rule: the AUTO decision steps every tree per leaf, small
+        # or large (the packed-size cutoff and its flag are gone);
+        # explicit pipeline=True packs
         policy = amp.get_policy("O5")
         small = {"w": jnp.ones((64, 64), jnp.float32)}  # 8 KiB bf16
+        large = {"w": jnp.zeros((4096, 8192), jnp.float32)}  # 64 MiB
         opt = amp.AmpOptimizer(fused_adam(1e-2), policy)
-        assert opt.use_pipeline  # capability/flag decision unchanged
-        # default cutoff (128 MiB) routes the tiny tree to staged
-        assert not isinstance(opt.init(small).master_params,
-                              fp.PackedMasters)
-        # cutoff 0 = pack everything (the pre-cutoff behavior)
-        monkeypatch.setenv("APEX_TPU_PIPELINE_PACK_MIN_BYTES", "0")
-        assert isinstance(opt.init(small).master_params,
-                          fp.PackedMasters)
-        # at/above the cutoff packs (8 KiB tree vs 4 KiB cutoff)
-        monkeypatch.setenv("APEX_TPU_PIPELINE_PACK_MIN_BYTES", "4096")
-        assert isinstance(opt.init(small).master_params,
-                          fp.PackedMasters)
-        # explicit pipeline=True bypasses any cutoff
-        monkeypatch.setenv("APEX_TPU_PIPELINE_PACK_MIN_BYTES",
-                           str(1 << 30))
+        for tree in (small, large):
+            state = jax.eval_shape(opt.init, tree)
+            assert not isinstance(state.master_params, fp.PackedMasters)
         forced = amp.AmpOptimizer(fused_adam(1e-2), policy,
                                   pipeline=True)
         assert isinstance(forced.init(small).master_params,
                           fp.PackedMasters)
 
     def test_pack_min_bytes_staged_state_steps(self):
-        # a cutoff-routed (staged) state must step through the staged
-        # path even though the optimizer is pipeline-capable — the
-        # dispatch is on the state's layout, and the result matches a
-        # pipeline=False optimizer bitwise
+        # an auto-routed (per-leaf) state must step through the
+        # per-leaf path even though the optimizer is pipeline-capable —
+        # the dispatch is on the state's layout, and the result matches
+        # a pipeline=False optimizer bitwise
         policy = amp.get_policy("O5", loss_scale=256.0)
         params = {"w": jnp.linspace(-1.0, 1.0, 96,
                                     dtype=jnp.float32).reshape(8, 12)}
@@ -578,7 +569,7 @@ class TestWiring:
         grads = jax.tree_util.tree_map(
             lambda x: (x * 0.01 * 256.0).astype(jnp.bfloat16), params)
         auto = amp.AmpOptimizer(fused_adam(1e-2), policy,
-                                check_finite=True)   # default cutoff
+                                check_finite=True)
         staged = amp.AmpOptimizer(fused_adam(1e-2), policy,
                                   check_finite=True, pipeline=False)
         s_a, s_s = auto.init(params), staged.init(params)
@@ -591,10 +582,12 @@ class TestWiring:
 
     def test_non_pipeline_tx_falls_back(self):
         # plain optax has no pipeline form; no masters -> no pipeline
-        assert not amp.AmpOptimizer(optax.sgd(0.1),
-                                    amp.get_policy("O5")).use_pipeline
-        assert not amp.AmpOptimizer(fused_adam(1e-3),
-                                    amp.get_policy("O3")).use_pipeline
+        params = {"w": jnp.ones((8, 128), jnp.float32)}
+        state = amp.AmpOptimizer(optax.sgd(0.1),
+                                 amp.get_policy("O5")).init(params)
+        assert not isinstance(state.master_params, fp.PackedMasters)
+        assert amp.AmpOptimizer(fused_adam(1e-3), amp.get_policy(
+            "O3")).init(params).master_params is None
 
     def test_explicit_pipeline_true_rejects_incapable_setups(self):
         # an explicit request must raise, not silently degrade to the
@@ -626,11 +619,17 @@ class TestWiring:
 
     def test_train_smoke_same_loss_with_and_without_pipeline(
             self, monkeypatch):
+        import functools
+
+        from apex_tpu.testing import standalone_gpt
         from apex_tpu.testing.standalone_gpt import train_smoke
 
-        loss_on = train_smoke(steps=4)
-        monkeypatch.setenv("APEX_TPU_FUSED_PIPELINE", "0")
         loss_off = train_smoke(steps=4)
+        monkeypatch.setattr(
+            standalone_gpt, "make_smoke_setup",
+            functools.partial(standalone_gpt.make_smoke_setup,
+                              pipeline=True))
+        loss_on = train_smoke(steps=4)
         np.testing.assert_allclose(loss_on, loss_off, rtol=1e-5)
 
 
@@ -710,7 +709,7 @@ class TestPackedCheckpoint:
     def test_mixed_mode_restore_is_a_clear_error_not_quarantine(
             self, tmp_path, monkeypatch):
         """A checkpoint saved in one master layout restored under the
-        other must raise CheckpointFormatMismatch naming the flag —
+        other must raise CheckpointFormatMismatch naming the mode —
         and must NOT be quarantined as a torn payload by the
         integrity fallback."""
         import os
@@ -724,23 +723,21 @@ class TestPackedCheckpoint:
         d = str(tmp_path / "ck")
         with CheckpointManager(d) as mgr:
             mgr.save(1, model, opt, state)
-        # staged-mode templates against the packed-mode checkpoint
-        monkeypatch.setenv("APEX_TPU_FUSED_PIPELINE", "0")
+        # per-leaf templates against the packed-mode checkpoint
         policy = amp.get_policy("O2")
         opt0 = amp.AmpOptimizer(fused_adam(1e-2, weight_decay=0.01),
                                 policy)
-        assert not opt0.use_pipeline
         state0 = opt0.init(params)
+        assert not isinstance(state0.master_params, fp.PackedMasters)
         model0 = jax.tree_util.tree_map(
             lambda x: x.astype(jnp.float16), params)
         with CheckpointManager(d) as mgr:
             with pytest.raises(CheckpointFormatMismatch,
-                               match="APEX_TPU_FUSED_PIPELINE"):
+                               match=r"pipeline=True\) packs"):
                 mgr.restore(model0, opt0, state0)
         # the good checkpoint survived untouched (no .corrupt rename)
         assert sorted(os.listdir(d)) == ["1"]
         # and the matching mode still restores it
-        monkeypatch.delenv("APEX_TPU_FUSED_PIPELINE")
         opt1, state1, model1 = self._make(params)
         with CheckpointManager(d) as mgr:
             _, state_r, _, step = mgr.restore(model1, opt1, state1)
@@ -751,15 +748,20 @@ class TestPackedCheckpoint:
                                                      monkeypatch):
         """The tier-1 resilience claim extended to the packed-state
         mode: kill@3 + resume == uninterrupted, bitwise on the packed
-        masters.  The smoke tree is tiny, so the auto routing would
-        send it to the staged path (APEX_TPU_PIPELINE_PACK_MIN_BYTES
-        small-tree cutoff) — pin the cutoff to 0 so the loop runs the
-        persistent pipeline this test exists to checkpoint."""
+        masters.  The auto route steps every leaf where it lies, so
+        the smoke's setup is built with pipeline=True: the loop runs
+        the persistent pipeline this test exists to checkpoint."""
+        import functools
+
         from apex_tpu.monitor import MemorySink
         from apex_tpu.resilience import parse_fault, run_resumable
+        from apex_tpu.testing import standalone_gpt
         from apex_tpu.testing.standalone_gpt import train_smoke
 
-        monkeypatch.setenv("APEX_TPU_PIPELINE_PACK_MIN_BYTES", "0")
+        monkeypatch.setattr(
+            standalone_gpt, "make_smoke_setup",
+            functools.partial(standalone_gpt.make_smoke_setup,
+                              pipeline=True))
         _, ref_params, ref_state, _ = train_smoke(steps=5,
                                                   return_state=True)
         assert isinstance(ref_state.master_params, fp.PackedMasters)
